@@ -7,8 +7,8 @@ migrations of the same nodename) can serve kernels compiled with, say,
 AVX-512 to a host without it, which dies with SIGILL/SIGSEGV at load. Keying
 the directory by a hash of the actual CPU feature flags makes any
 feature-set change land in a fresh cache instead of replaying stale code
-(docs/perf_notes_r03.md; the r5/r6 slow-lane SIGSEGVs were this — nodename
-stayed stable across hosts with different microarchitectures).
+(the r5/r6 slow-lane SIGSEGVs were this — nodename stayed stable across
+hosts with different microarchitectures).
 
 Standalone on purpose: tests/conftest.py must call this BEFORE ``import
 jax``, so it cannot live under ``spark_rapids_tpu`` (whose package init

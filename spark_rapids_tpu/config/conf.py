@@ -584,8 +584,11 @@ HASHTBL_PALLAS_MODE = conf(
     "spark.rapids.tpu.sql.kernel.hashTable.pallasMode", default="auto",
     internal=True,
     doc="Hash-table probe kernel dispatch: 'auto' uses the Pallas kernel "
-        "on TPU backends and pure XLA elsewhere; 'on'/'off' force a side. "
-        "Any Pallas lowering failure falls back to XLA permanently.",
+        "only on a backend whose compiler is shown to accept it "
+        "(tests/test_tpu_compile.py) - none today, the TPU v5e compiler "
+        "refuses it - and pure XLA elsewhere; 'on'/'off' force a side. "
+        "A Pallas lowering failure under 'on' falls back to XLA "
+        "permanently.",
     check=lambda v: None if v in ("auto", "on", "off")
     else "must be auto|on|off")
 
@@ -593,8 +596,10 @@ SORTWIN_PALLAS_MODE = conf(
     "spark.rapids.tpu.sql.kernel.sortWindow.pallasMode", default="auto",
     internal=True,
     doc="Segmented-scan kernel dispatch for sort/window primitives: "
-        "'auto' uses the Pallas kernel on TPU backends and pure XLA "
-        "elsewhere; 'on'/'off' force a side. The kernel is probed with an "
+        "'auto' uses the Pallas kernel only on a backend whose compiler "
+        "is shown to accept it (tests/test_tpu_compile.py) - the TPU, for "
+        "float32/int32 lanes - and pure XLA elsewhere; "
+        "'on'/'off' force a side. The kernel is probed with an "
         "eager lowering test before any traced program commits to it; any "
         "failure falls back to XLA permanently (reset by switching this "
         "conf to 'on').",

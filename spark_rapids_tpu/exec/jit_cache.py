@@ -4,8 +4,8 @@ Operators bind per-instance ``@jax.jit`` closures; two instances of the
 same operator with an IDENTICAL bound program (common: the TPC-DS tracker
 re-plans every query, CTE reuse, both engines of a differential test)
 would each re-trace and re-load the compiled executable from the
-persistent cache — measured ~0.3–1s per kernel through this platform's
-disk cache, dominating small-scale queries (docs/perf_notes_r05.md).
+persistent cache, which dominates small-scale queries (the per-kernel
+cost is not measured on the current machine).
 
 ``shared_jit(key, make)`` returns ONE jit per semantic key per process:
 the key must capture everything that changes the traced program. Bound

@@ -16,7 +16,7 @@ via mem.retry, exactly like the reference's GpuRetryOOM path.
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 from spark_rapids_tpu import faults
 
@@ -91,10 +91,11 @@ class HbmPool:
     (mirroring OOMRetryState escalation in DeviceMemoryEventHandler:53-105).
     """
 
-    def __init__(self, limit_bytes: int):
+    def __init__(self, limit_bytes: int, limit_source: str = "caller"):
         from spark_rapids_tpu.mem import cleaner
         cleaner.register_pool(self)
         self.limit = int(limit_bytes)
+        self.limit_source = limit_source  # who chose the limit (reports)
         self._used = 0
         self._lock = threading.Lock()
         self._spill_fn: Optional[Callable[[int], int]] = None
@@ -206,18 +207,19 @@ _default_pool: Optional[HbmPool] = None
 _pool_lock = threading.Lock()
 
 
-def _detect_hbm_bytes() -> int:
-    """Best-effort per-chip HBM size; defaults to 16 GiB (v5e class)."""
-    try:
-        import jax
+def _detect_hbm_bytes() -> Tuple[int, str]:
+    """(per-chip HBM bytes, where the figure came from). On a TPU the
+    device is asked and the answer is required: a size assumed for a chip
+    that did not give one would mis-size every admission and spill
+    decision. Other backends (the CPU test lane reports no limit) keep a
+    16 GiB stand-in."""
+    import jax
 
-        d = jax.devices()[0]
-        stats = d.memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return 16 << 30
+    d = jax.devices()[0]
+    if d.platform == "tpu":
+        return (int(d.memory_stats()["bytes_limit"]),
+                "memory_stats.bytes_limit")
+    return 16 << 30, f"default-16GiB({d.platform})"
 
 
 def get_pool(conf=None) -> HbmPool:
@@ -232,10 +234,11 @@ def get_pool(conf=None) -> HbmPool:
                 conf = C.RapidsConf()
             max_bytes = C.HBM_POOL_BYTES.get(conf)
             if max_bytes:
-                limit = int(max_bytes)
+                limit, source = int(max_bytes), C.HBM_POOL_BYTES.key
             else:
-                limit = int(_detect_hbm_bytes() * C.HBM_POOL_FRACTION.get(conf))
-            _default_pool = HbmPool(limit)
+                hbm, source = _detect_hbm_bytes()
+                limit = int(hbm * C.HBM_POOL_FRACTION.get(conf))
+            _default_pool = HbmPool(limit, source)
         return _default_pool
 
 

@@ -31,8 +31,9 @@ def _build(out_path: str) -> bool:
     # processes must never dlopen a half-written .so or interleave linker
     # output on the shared cache path
     tmp = f"{out_path}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           "-o", tmp] + srcs
+    # no -march=native: the tree (and this build product, were it not
+    # rebuilt) travels to machines with a different host CPU
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp] + srcs
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, out_path)

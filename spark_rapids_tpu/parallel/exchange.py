@@ -24,17 +24,8 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental path, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map(f, **kw)
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch

@@ -26,7 +26,7 @@ if not TPU_LANE:
     # not its nodename: a nodename-keyed cache survives container moves
     # across different microarchitectures, and AOT kernels compiled under
     # other feature flags SIGILL/SIGSEGV when loaded here
-    # (docs/perf_notes_r03.md; the r5/r6 slow-lane segfaults were this)
+    # (_xla_cpu_cache.py; the r5/r6 slow-lane segfaults were this)
     _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if _ROOT not in sys.path:
         sys.path.insert(0, _ROOT)

@@ -245,3 +245,28 @@ def test_memory_cleaner_sweep():
     leaks2 = [l for l in cleaner.sweep() if l not in base]
     assert not any("srtpu_cleaner_test" in l for l in leaks2)
     assert not any("HbmPool: 4096" in l for l in leaks2), leaks2
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("tpu", {"bytes_limit": 123 << 20}, (123 << 20, "memory_stats.bytes_limit")),
+    ("cpu", None, (16 << 30, "default-16GiB(cpu)")),
+    ("tpu", None, TypeError),   # a TPU that gives no size: raise, never guess
+    ("tpu", {}, KeyError),
+])
+def test_pool_size_asks_the_tpu_or_raises(monkeypatch, platform, stats, want):
+    """On a TPU the pool is sized from what the device reports, or not at
+    all; the 16 GiB stand-in is for backends that report no limit."""
+    import types
+
+    import jax
+
+    from spark_rapids_tpu.mem import pool as P
+
+    dev = types.SimpleNamespace(platform=platform,
+                                memory_stats=lambda: stats)
+    monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+    if isinstance(want, tuple):
+        assert P._detect_hbm_bytes() == want
+    else:
+        with pytest.raises(want):
+            P._detect_hbm_bytes()

@@ -10,6 +10,7 @@ kernels run under ``interpret=True`` on this lane (reference: the
 hash-table probe suite in test_hash_table.py).
 """
 
+import functools
 import math
 
 import numpy as np
@@ -277,16 +278,17 @@ def test_segmented_scan_xla_matches_numpy(rng, name, op, dt):
                                   _np_segscan(vals, starts, op))
 
 
+@pytest.mark.parametrize("n", [512, 40000])  # one tile; two, with a carry
 @pytest.mark.parametrize("name", ["add", "min", "max"])
-def test_segmented_scan_pallas_interpret_matches_xla(rng, name):
-    n = 512
+def test_segmented_scan_pallas_interpret_matches_xla(rng, name, n):
     # int32 for add: float running sums associate differently between the
     # blocked kernel and the XLA tree scan (last-ulp), ints are exact
     if name == "add":
         vals = rng.integers(-9, 9, n).astype(np.int32)
     else:
         vals = rng.normal(size=n).astype(np.float32)
-    starts = (rng.random(n) < 0.15)
+    # sparse heads in the long case, so segments cross rows and the tile
+    starts = (rng.random(n) < (0.15 if n == 512 else 0.0005))
     ref = K.segmented_scan_xla(jnp.asarray(vals), jnp.asarray(starts), name)
     got = K.segmented_scan_pallas(jnp.asarray(vals), jnp.asarray(starts),
                                   name, interpret=True)
@@ -389,6 +391,47 @@ def test_window_pallas_mode_results_stable(rng, mode):
     assert got == _win_rows(t, frame)
     if mode == "on" and jax.default_backend() != "tpu":
         assert K.counters()["sortwin_pallas_fallback_total"] > 0
+    K.reset_sortwin_pallas_fallback()
+
+
+@pytest.mark.parametrize("dt", [np.int32, np.float32])
+@pytest.mark.parametrize("name", ["add", "min", "max"])
+def test_segmented_scan_pallas_on_the_chip_matches_xla(rng, name, dt):
+    """The compiled kernel against the XLA scan on real hardware (1, 2 and 4
+    tiles). Needs the chip: `chiprun -- env SRTPU_TPU_LANE=1 python -m
+    pytest tests/test_sortwin_kernels.py -k on_the_chip`; skips elsewhere."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs a TPU: interpret mode is covered above")
+    for n in (512, 40000, 100000):
+        # integer-valued, so float32 sums are exact in any association
+        vals = rng.integers(-1000, 1000, n).astype(dt)
+        starts = rng.random(n) < (0.15 if n == 512 else 0.0005)
+        ref = K.segmented_scan_xla(jnp.asarray(vals), jnp.asarray(starts),
+                                   name)
+        got = K.segmented_scan_pallas(jnp.asarray(vals), jnp.asarray(starts),
+                                      name)
+        np.testing.assert_array_equal(jax.device_get(got),
+                                      jax.device_get(ref))
+
+
+@pytest.mark.parametrize("kernel_is_right", [True, False])
+def test_segscan_probe_holds_the_kernel_to_the_xla_answer(monkeypatch,
+                                                          kernel_is_right):
+    """The eager probe latches the XLA fallback not only when the kernel
+    fails to lower but when it lowers and answers wrongly (what interpret
+    mode cannot show about the hardware)."""
+    interp = functools.partial(K.segmented_scan_pallas, interpret=True)
+    wrong = lambda v, s, op: interp(v, s, op) + 1  # noqa: E731
+    monkeypatch.setattr(K, "segmented_scan_pallas",
+                        interp if kernel_is_right else wrong)
+    K.reset_sortwin_pallas_fallback()
+    before = K.counters()["sortwin_pallas_fallback_total"]
+    # asked from inside a trace, as window programs ask: it still runs now
+    seen = []
+    jax.jit(lambda x: seen.append(K._segscan_pallas_ok()) or x)(jnp.int32(0))
+    assert seen == [kernel_is_right]
+    assert (K.counters()["sortwin_pallas_fallback_total"] - before
+            == (0 if kernel_is_right else 1))
     K.reset_sortwin_pallas_fallback()
 
 

@@ -1,0 +1,185 @@
+"""Compile guard: the smoke path's kernels, AOT-compiled for a described v5e.
+
+The TPU's compiler is installed in the CPU-only sandbox and compiles for a
+chip that is described, not attached (`on-chip-measurement` guide, section
+2). These tests hand it the kernels `chip_smoke.py` runs, at the column
+types the smoke uses (int64 keys, float64 money, date32, dict-coded
+strings), so that a kernel the chip's compiler refuses is found here at no
+chip time. A compile that passes is not a chip run and says nothing about
+results or times.
+
+The topology is described inside a module-scoped fixture of this file
+only (never at import, in a skipif, a parametrize argument or
+conftest.py): only one process may hold libtpu, and every xdist worker
+imports every test file.
+
+Sizes: the scan-shaped kernels compile at 2^16-2^17 rows (2^20 is the slow
+size, ROADMAP S2). The sort-bearing ones (grouping, join build, ORDER BY)
+compile at 2^12: for the v5e a variadic sort's compile cost is set by its
+operand count and jumps with size (q3's ORDER BY measured here: 2.6 s at
+2^12 rows, 55 s at 2^14, 294 s at 2^16; one u32 key: 19 s / 22 s / 28 s at
+2^16 / 2^18 / 2^20), so at a size this suite can afford the guard holds
+their column types and operand sets, not their capacity. A Pallas kernel
+is here if and only if ``auto`` selects it on a TPU (exec/kernels.py).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import spark_rapids_tpu  # noqa: F401  (x64 on)
+from spark_rapids_tpu.columnar.batch import batch_from_arrow
+from spark_rapids_tpu.exec import kernels as K
+
+CAP = 1 << 16       # scan-shaped kernels
+SORT_CAP = 1 << 12  # sort-bearing kernels (see the module docstring)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep these out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+def _lineitem(sf, capacity):
+    """(batch, column index by name) of a TPC-H lineitem batch as the scan
+    makes it: int64 keys, float64 money, date32, dict-coded string flags."""
+    from spark_rapids_tpu.bench import tpch
+    table = tpch.gen_lineitem(sf, seed=7)
+    batch = batch_from_arrow(table)
+    assert batch.capacity == capacity
+    return batch, {n: i for i, n in enumerate(table.schema.names)}
+
+
+@pytest.fixture(scope="module")
+def lineitem():
+    return _lineitem(0.01, CAP)
+
+
+@pytest.fixture(scope="module")
+def lineitem_small():
+    return _lineitem(0.0005, SORT_CAP)
+
+
+def _compile(fn, one_chip, *args):
+    """Lower ``fn`` over the shapes of ``args`` for the described chip and
+    compile it: raises what the chip's compiler would raise."""
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def test_graft_entry_fused_step(one_chip):
+    """filter -> compact -> gather -> group -> segment_agg over int64 keys
+    and float64 values: the engine's core XLA step."""
+    import __graft_entry__ as g
+    step, (batch,) = g.entry()
+    assert _compile(step, one_chip, batch) is not None
+
+
+def test_filter_indices(one_chip):
+    keep = jnp.zeros(1 << 17, jnp.bool_)
+    assert _compile(K.filter_indices, one_chip, keep, keep) is not None
+
+
+def test_packed_gather(one_chip, lineitem):
+    batch, _ = lineitem
+    idx = jnp.zeros(CAP, jnp.int32)
+    assert _compile(K.gather_batch, one_chip, batch, idx,
+                    jnp.int32(0)) is not None
+
+
+def test_group_rows_segment_agg(one_chip, lineitem_small):
+    """q1's shape: group on two dict-coded strings, sum float64 money."""
+    batch, col = lineitem_small
+    keys = [col["l_returnflag"], col["l_linestatus"]]
+    price = col["l_extendedprice"]
+
+    def step(b):
+        gi = K.group_rows(b, keys)
+        c = b.columns[price]
+        contributing = b.active_mask()[gi.perm]
+        ends = K.segment_ends(gi.group_starts, gi.num_groups, b.capacity)
+        return K.segment_agg(c.data[gi.perm], c.validity[gi.perm],
+                             contributing, gi.segment_ids, b.capacity, "sum",
+                             ends=ends, starts=gi.group_starts)
+
+    assert _compile(step, one_chip, batch) is not None
+
+
+def test_hash_table_build_probe_xla(one_chip):
+    h = jnp.zeros(CAP, jnp.uint64)
+    valid = jnp.zeros(CAP, jnp.bool_)
+    cap = K.hashtbl_capacity(CAP)
+
+    def step(h1, h2, v):
+        tbl, overflow = K.build_hash_table(h1, h2, v, cap, 0,
+                                           K.HASHTBL_MAX_PROBES)
+        slot, hit = K.probe_hash_table(tbl, h1, h2, cap, 0,
+                                       K.HASHTBL_MAX_PROBES)
+        return slot, hit, overflow
+
+    assert _compile(step, one_chip, h, h, valid) is not None
+
+
+def test_dense_join_build_probe(one_chip, lineitem_small):
+    """q3's join: a direct-address table on an int64 key, probed by the
+    fact side."""
+    batch, col = lineitem_small
+    key = (col["l_orderkey"],)
+
+    def step(b):
+        tbl, dup_any, max_bucket = K.build_join_table(b, key)
+        bi, hit = K.probe_join_table_unique(b, tbl, key, b, key, 4,
+                                            K._join_lg_b(b.capacity))
+        return bi, hit, dup_any, max_bucket
+
+    assert _compile(step, one_chip, batch) is not None
+
+
+def test_variadic_sort(one_chip, lineitem_small):
+    """q3's top-N order: float64 descending, date32 ascending."""
+    batch, col = lineitem_small
+    specs = (K.SortSpec(col["l_extendedprice"], ascending=False),
+             K.SortSpec(col["l_shipdate"]))
+    assert _compile(lambda b: K.sort_indices(b, specs), one_chip,
+                    batch) is not None
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float64])
+def test_segmented_scan_xla(one_chip, dtype):
+    v = jnp.zeros(CAP, dtype)
+    s = jnp.zeros(CAP, jnp.bool_)
+    assert _compile(lambda a, b: K.segmented_scan_xla(a, b, "add"),
+                    one_chip, v, s) is not None
+
+
+@pytest.mark.parametrize("op", ["add", "min", "max"])
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+def test_segmented_scan_pallas(one_chip, op, dtype):
+    """The Pallas kernel ``auto`` selects on a TPU, at the smoke's batch
+    size (32 sequential tiles): every op and lane type the dispatch routes
+    to it."""
+    v = jnp.zeros(1 << 20, dtype)
+    s = jnp.zeros(1 << 20, jnp.bool_)
+    compiled = _compile(lambda a, b: K.segmented_scan_pallas(a, b, op),
+                        one_chip, v, s)
+    assert "tpu_custom_call" in compiled.as_text()

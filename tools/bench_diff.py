@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
 """Perf-trajectory sentinel: gate every bench round against its history.
 
-Five ``BENCH_r*.json`` / ``MULTICHIP_r*.json`` rounds exist on disk and
-until this tool nothing had ever compared two of them — regressions (and
-whole-round failures like r05's rc=124 ``parsed: null``) were only
-caught by a human reading JSON. ``bench_diff`` parses every round,
+Until this tool nothing had ever compared two ``BENCH_r*.json`` /
+``MULTICHIP_r*.json`` rounds — regressions (and whole-round failures
+like an rc=124 ``parsed: null`` round) were only caught by a human
+reading JSON. ``bench_diff`` parses every round,
 normalizes metric lines across the schema drift between rounds
 (``parsed`` dicts, suite lines, per-query roofline lines, trailing
 driver-metric JSON in the tail), and exits nonzero when any tracked

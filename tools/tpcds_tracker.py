@@ -82,7 +82,7 @@ def main():
                                    + " --xla_force_host_platform_device_count=8")
         # isolated per-run compile cache: the shared persistent cache can
         # serve CPU AOT kernels compiled under other host-feature flags and
-        # segfault hours into a run (docs/perf_notes_r03.md)
+        # segfault hours into a run (_xla_cpu_cache.py)
         import tempfile
         os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                               tempfile.mkdtemp(prefix="srtpu_xla_run_"))
