@@ -73,7 +73,8 @@ class ColumnarBatch:
 
     def row_count(self) -> int:
         """Host-side row count (blocks on device value)."""
-        return int(self.num_rows)
+        from spark_rapids_tpu.utils.sync import host_get
+        return int(host_get(self.num_rows, "row_count"))
 
     def active_mask(self) -> jax.Array:
         """Boolean mask of live rows (True for i < num_rows)."""
@@ -474,7 +475,8 @@ def shrink_to_live(batch: ColumnarBatch, min_capacity: int = 1 << 20
     for c in batch.columns:
         if c.offsets is not None:
             scalars.append(c.offsets[jnp.clip(batch.num_rows, 0, cap)])
-    vals = jax.device_get(scalars)
+    from spark_rapids_tpu.utils.sync import host_get
+    vals = host_get(scalars, "shrink_to_live")
     n = int(vals[0])
     newcap = bucket_capacity(max(n, 1024))
     if newcap * 2 > cap:
@@ -492,11 +494,12 @@ def shrink_to_live(batch: ColumnarBatch, min_capacity: int = 1 << 20
 
 def batch_to_arrow(batch: ColumnarBatch, schema: T.Schema) -> pa.Table:
     """Device batch -> host Arrow table (slices away padding)."""
-    n = batch.row_count()
-    # pull every device buffer in ONE batched transfer: per-array readbacks
-    # pay a round trip each (utils/sync.py; cost not measured on the
-    # current machine)
-    host = jax.device_get(batch.columns)
+    # pull the row count and every device buffer in ONE batched transfer:
+    # per-array readbacks pay a round trip each (utils/sync.py; cost not
+    # measured on the current machine)
+    from spark_rapids_tpu.utils.sync import host_get
+    n, host = host_get((batch.num_rows, batch.columns), "batch_to_arrow")
+    n = int(n)
     arrays = [_host_column_to_arrow(col, field.dtype, n)
               for col, field in zip(host, schema)]
     return pa.table(arrays, schema=schema.to_arrow())

@@ -310,8 +310,9 @@ METRICS_SPANS_ENABLED = conf(
         "the cluster ctrl pipe, shuffle fetches/writes, and mesh dispatch, "
         "so one query's cross-process timeline reassembles into a single "
         "merged trace. Span events ride the existing trace-capture window "
-        "(profile.traceCapture) and the journal; with capture off the "
-        "per-span cost is one journal append (docs/observability.md).")
+        "(profile.traceCapture) and a jax.profiler annotation; with capture "
+        "off and no profiler running a span takes no lock "
+        "(docs/observability.md).")
 
 MEM_TRACK_ENABLED = conf(
     "spark.rapids.tpu.memory.track.enabled", default=True,

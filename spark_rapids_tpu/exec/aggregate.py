@@ -37,6 +37,7 @@ from spark_rapids_tpu.exec.base import TpuExec, UnaryExec
 from spark_rapids_tpu.exec import kernels as K
 from spark_rapids_tpu.exprs import expr as E
 from spark_rapids_tpu.exprs import eval as EV
+from spark_rapids_tpu.utils.sync import host_get
 
 
 @dataclasses.dataclass
@@ -391,6 +392,7 @@ class HashAggregateExec(UnaryExec):
             return K.GroupInfo(perm, seg, num_groups, group_starts)
         return K.group_rows(pre, list(range(self._n_keys)), active)
 
+    @jax.named_scope("agg.first_pass")
     def _first_pass(self, batch: ColumnarBatch) -> ColumnarBatch:
         """pre-project + (fused filter) + group + per-buffer aggregations."""
         ctx = EV.EvalContext(batch)
@@ -726,6 +728,7 @@ class HashAggregateExec(UnaryExec):
         idx, n = K.filter_indices(exists, jnp.ones(Gc, jnp.bool_))
         return K.gather_batch(table, idx, n)
 
+    @jax.named_scope("agg.merge_pass")
     def _merge_pass(self, buffers: ColumnarBatch) -> ColumnarBatch:
         """re-group partial buffers and combine with merge ops."""
         merge_ops = [[_MERGE_OP[op] for op in s.ops] for s in self._specs]
@@ -974,6 +977,7 @@ class HashAggregateExec(UnaryExec):
         col = K.gather_column(src, rows, row_valid)
         return col, any_valid
 
+    @jax.named_scope("agg.final_project")
     def _final_project(self, buffers: ColumnarBatch) -> ColumnarBatch:
         """buffers -> final values (Average division etc.)."""
         cap = buffers.capacity
@@ -1358,7 +1362,7 @@ class HashAggregateExec(UnaryExec):
         """Split one materialized batch across the bucket lists."""
         from spark_rapids_tpu.mem import spill as S
 
-        counts = jax.device_get(counts_fn(batch, salt))
+        counts = host_get(counts_fn(batch, salt), "agg.repartition_counts")
         for b, n in enumerate(counts):
             n = int(n)
             if n == 0:

@@ -169,7 +169,14 @@ class TpuExec:
         batch_histo = (_histo.get("batch_op_ns")
                        if _histo.enabled() else None)
         while True:
-            t0 = time.perf_counter_ns()
+            # per-batch operator range (utils/tracing.TraceRange): a
+            # profiler annotation for the life of this next(), so a device
+            # trace shows which operator the host was in, and at close an
+            # event for the Chrome trace exporter while a capture window
+            # (Profiler / QueryProfile with trace capture) is open; the
+            # steady state pays the TraceMe's flag check and one flag read
+            rng = tracing.TraceRange(name).open()
+            t0 = rng.start_ns
             # HBM attribution context: pool allocations made while this
             # operator's iterator runs tag to (query, operator, site).
             # Nested execute() frames re-push, so the innermost active
@@ -178,8 +185,12 @@ class TpuExec:
             try:
                 batch = next(it)
             except StopIteration:
+                rng.close(record=False)
                 op_time.add(time.perf_counter_ns() - t0)
                 return
+            except BaseException:
+                rng.close(record=False)
+                raise
             finally:
                 _mt.pop_op(mem_tok)
             if SYNC_METRICS:
@@ -196,11 +207,7 @@ class TpuExec:
             op_time.add(t1 - t0)
             if batch_histo is not None:
                 batch_histo.record(t1 - t0)
-            # per-batch operator span for the Chrome trace exporter; only
-            # recorded while a capture window (Profiler / QueryProfile with
-            # trace capture) is open, so the steady state pays one flag read
-            tracing.record_event(name, t0, t1 - t0,
-                                 args={"partition": partition})
+            rng.close(args={"partition": partition}, end_ns=t1)
             self.metrics["numOutputBatches"].add(1)
             with self._rows_lock:
                 self._pending_rows.append(batch.num_rows)
@@ -211,9 +218,9 @@ class TpuExec:
             if fold is not None:
                 # fold into the host counter; the early scalars are long done
                 # by now so this rarely blocks, and it bounds retained buffers
+                from spark_rapids_tpu.utils.sync import host_get
                 self.metrics["numOutputRows"].add(
-                    sum(int(n) for n in fold)
-                )
+                    sum(int(n) for n in host_get(fold, "metrics.rows")))
             yield batch
 
     def execute_all(self) -> Iterator[ColumnarBatch]:
@@ -272,9 +279,9 @@ class TpuExec:
             pending = list(self._pending_rows)
             self._pending_rows.clear()
         if pending:
+            from spark_rapids_tpu.utils.sync import host_get
             self.metrics["numOutputRows"].add(
-                sum(int(n) for n in pending)
-            )
+                sum(int(n) for n in host_get(pending, "metrics.rows")))
         return {m.name: m.value for m in self.metrics.values() if m.enabled}
 
     def collect_metrics(self) -> Dict[str, int]:

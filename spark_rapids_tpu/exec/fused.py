@@ -64,6 +64,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch, bucket_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.base import TpuExec, UnaryExec
 from spark_rapids_tpu.exec.jit_cache import shared_jit
+from spark_rapids_tpu.utils.sync import host_get
 
 
 # ---------------------------------------------------------------------------
@@ -166,9 +167,13 @@ def _make_step(fns, agg, carry_cap: int, bc_targets: Tuple[int, ...]):
             out, counts = body(batch, consts)
             firsts.append(agg._first_pass(out))
             counts_all.append(counts)
-        cat = concat_jit([carry] + firsts)
-        merged = agg._merge_pass(cat)
-        carry2, over = _truncate_buffers(merged, carry_cap, bc_targets)
+        # named scopes are HLO metadata only (docs/observability.md): the
+        # window's concatenate-and-reduce reads as aggwin/* in an op profile
+        with jax.named_scope("aggwin.concat"):
+            cat = concat_jit([carry] + firsts)
+        with jax.named_scope("aggwin.merge"):
+            merged = agg._merge_pass(cat)
+            carry2, over = _truncate_buffers(merged, carry_cap, bc_targets)
         return carry2, over, tuple(counts_all)
     return step
 
@@ -291,7 +296,8 @@ class TpuFusedStageExec(UnaryExec):
             op._pending_rows.append(n)
             if len(op._pending_rows) >= 64:
                 op.metrics["numOutputRows"].add(
-                    sum(int(x) for x in op._pending_rows))
+                    sum(int(x) for x in host_get(list(op._pending_rows),
+                                                 "metrics.rows")))
                 op._pending_rows.clear()
 
     def do_execute(self, partition: int) -> Iterator[ColumnarBatch]:
@@ -394,7 +400,8 @@ class TpuFusedStageExec(UnaryExec):
                 window, runs, flags)
         # ONE host sync per partition resolves every overflow flag; on
         # overflow the carry holds truncated garbage -> re-run unfused
-        if flags and any(bool(v) for v in jax.device_get(flags)):
+        if flags and any(bool(v) for v in
+                         host_get(flags, "fused.overflow_flags")):
             yield from self._fall_back(partition)
             AT.record_decision(self, "aggwin", str(window_n), source, shape,
                                ns=_time.perf_counter_ns() - t0, rows=rows_in)

@@ -31,20 +31,24 @@ _MISSES = 0
 _COMPILE_NS = 0
 
 
-def _timed_first_call(jfn: Callable) -> Callable:
+def _timed_first_call(jfn: Callable, program: str) -> Callable:
     """jax.jit is lazy: trace+compile happens on the first invocation, not
     at jit() time. Time that first call and bank it as compile cost so
     QueryProfile can attribute compile-vs-execute (the first call also
     runs the first batch, so this is an upper bound — dominated by
-    compilation for anything the disk cache misses). Later calls pay one
-    flag check."""
+    compilation for anything the disk cache misses). Under a trace it is
+    also the ``query:compile`` span, a real interval; a query that binds
+    no new program records none. Later calls pay one flag check."""
     state = {"first": True}
 
     def wrapper(*args, **kwargs):
         global _COMPILE_NS
         if state["first"]:
+            from spark_rapids_tpu.obs import span as _span
             t0 = time.perf_counter_ns()
-            out = jfn(*args, **kwargs)
+            with _span.task_span("query:compile",
+                                 attrs={"program": program}):
+                out = jfn(*args, **kwargs)
             dt = time.perf_counter_ns() - t0
             state["first"] = False
             with _LOCK:
@@ -69,7 +73,7 @@ def shared_jit(key: tuple, make: Callable[[], Callable]) -> Callable:
                 # just a much cheaper "compile").
                 from spark_rapids_tpu.exec import jit_persist
                 fn = _CACHE[key] = _timed_first_call(
-                    jit_persist.bind(key, make))
+                    jit_persist.bind(key, make), str(key[0]))
                 return fn
     _HITS += 1
     return fn

@@ -40,6 +40,7 @@ from spark_rapids_tpu.exec import kernels as K
 from spark_rapids_tpu.exec.aggregate import concat_jit
 from spark_rapids_tpu.exprs import expr as E
 from spark_rapids_tpu.exprs import eval as EV
+from spark_rapids_tpu.utils.sync import host_get
 
 JOIN_TYPES = ("inner", "left", "right", "full", "left_semi", "left_anti")
 
@@ -268,7 +269,7 @@ class HashJoinExec(BinaryExec):
             return None  # table sort beyond the slot budget: general path
         tbl, dup_any, max_bucket = K.build_join_table(
             build, tuple(self._rkeys))
-        dup, mb = jax.device_get((dup_any, max_bucket))
+        dup, mb = host_get((dup_any, max_bucket), "join.table_stats")
         slots = 1
         while slots < max(int(mb), 1):
             slots *= 2
@@ -296,8 +297,7 @@ class HashJoinExec(BinaryExec):
             caps = {}
             for i, c in enumerate(build.columns):
                 if c.offsets is not None:
-                    ml = int(jax.device_get(
-                        jnp.max(c.offsets[1:] - c.offsets[:-1])))
+                    ml = _max_row_bytes(c)
                     caps[i] = bucket_capacity(max(out_cap * max(ml, 1), 8), 8)
             cache[ckey] = caps
         self._pcaps, self._bcaps = pcaps, cache[ckey]
@@ -345,13 +345,14 @@ class HashJoinExec(BinaryExec):
                 or kcol.dtype not in (T.INT, T.LONG)
                 or pdt not in (T.INT, T.LONG)):
             return None
-        stats = jax.device_get(_dense_key_stats(build, self._rkeys[0]))
+        stats = host_get(_dense_key_stats(build, self._rkeys[0]),
+                         "join.dense_key_stats")
         kmin, kmax, n_valid = (int(stats[0]), int(stats[1]), int(stats[2]))
         if n_valid == 0 or kmin < 0 or kmax >= self._dense_max_domain:
             return None
         size = bucket_capacity(kmax + 1, 16)
         tbl, dup_any = _dense_build_table(build, self._rkeys[0], size)
-        if bool(jax.device_get(dup_any)):
+        if bool(host_get(dup_any, "join.dense_dup")):
             return None  # non-unique build keys: general path
         return tbl
 
@@ -373,8 +374,7 @@ class HashJoinExec(BinaryExec):
             caps = {}
             for i, c in enumerate(build.columns):
                 if c.offsets is not None:
-                    ml = int(jax.device_get(
-                        jnp.max(c.offsets[1:] - c.offsets[:-1])))
+                    ml = _max_row_bytes(c)
                     caps[i] = bucket_capacity(max(out_cap * max(ml, 1), 8), 8)
             cache[ckey] = caps
         self._pcaps, self._bcaps = pcaps, cache[ckey]
@@ -494,7 +494,8 @@ class HashJoinExec(BinaryExec):
                                                 seed, K.HASHTBL_MAX_PROBES)
         lo, cnt, total_dev, ends, pml_dev = _ht_candidate_stats(
             tbl, slot, hit & pvalid, probe, pstr)
-        got = jax.device_get((total_dev,) + tuple(pml_dev))
+        got = host_get((total_dev,) + tuple(pml_dev),
+                       "join.candidate_stats")
         total = int(got[0])
         pml = {i: int(m) for i, m in zip(pstr, got[1:])}
         self.metrics["numCandidatePairs"].add(total)
@@ -515,8 +516,7 @@ class HashJoinExec(BinaryExec):
         ckey = ("ht", partition)
         if ckey not in cache:
             cache[ckey] = {
-                i: int(jax.device_get(
-                    jnp.max(c.offsets[1:] - c.offsets[:-1])))
+                i: _max_row_bytes(c)
                 for i, c in enumerate(build.columns)
                 if c.offsets is not None}
         bml = cache[ckey]
@@ -527,7 +527,7 @@ class HashJoinExec(BinaryExec):
         if total <= chunk_target:
             ranges = [(0, cap_rows, total)]
         else:
-            ends_h = np.asarray(jax.device_get(ends))
+            ends_h = np.asarray(host_get(ends, "join.candidate_ends"))
             ranges = []
             r0, done = 0, 0
             while r0 < cap_rows and done < total:
@@ -572,7 +572,7 @@ class HashJoinExec(BinaryExec):
         if jt in ("left", "full"):
             # unmatched probe rows ride as their own (final) chunk
             unmatched = ~pmatch_acc & probe.active_mask()
-            n = int(jnp.sum(unmatched))
+            n = int(host_get(jnp.sum(unmatched), "join.unmatched_probe"))
             if n > 0:
                 out_cap = bucket_capacity(n, 16)
                 uidx, un = K.filter_indices(unmatched, probe.active_mask())
@@ -624,8 +624,7 @@ class HashJoinExec(BinaryExec):
                     return None  # duplicate keys: per-batch host sync path
         # longest build row per string column, read ONCE per build; byte
         # bounds for any probe capacity are then pure host arithmetic
-        mls = {i: int(jax.device_get(
-                   jnp.max(c.offsets[1:] - c.offsets[:-1])))
+        mls = {i: _max_row_bytes(c)
                for i, c in enumerate(build.columns) if c.offsets is not None}
         # fused probes have no per-operator timing to feed the store, but
         # the decision is still surfaced in explain_analyze/dispatch_paths
@@ -664,7 +663,7 @@ class HashJoinExec(BinaryExec):
 
     def _unmatched_build(self, build: ColumnarBatch, matched) -> Optional[ColumnarBatch]:
         want = ~matched & build.active_mask()
-        n = int(jnp.sum(want))
+        n = int(host_get(jnp.sum(want), "join.unmatched_build"))
         if n == 0:
             return None
         out_cap = bucket_capacity(n, 16)
@@ -756,6 +755,12 @@ class _FusedJoinProbe:
                 probe, build, idx, jnp.clip(bi_c, 0, None),
                 jnp.arange(cap, dtype=jnp.int32) < n, n, cap)
         return run
+
+
+def _max_row_bytes(c: DeviceColumn) -> int:
+    """Longest row of a string column, in bytes (one host sync)."""
+    return int(host_get(jnp.max(c.offsets[1:] - c.offsets[:-1]),
+                        "join.max_row_bytes"))
 
 
 def _pad_idx(idx: jax.Array, out_cap: int) -> jax.Array:

@@ -36,6 +36,7 @@ from spark_rapids_tpu.exec.aggregate import concat_jit
 from spark_rapids_tpu.exec.join import HashJoinExec, _null_column, _pad_idx
 from spark_rapids_tpu.exprs import expr as E
 from spark_rapids_tpu.exprs import eval as EV
+from spark_rapids_tpu.utils.sync import host_get
 
 
 class BroadcastHashJoinExec(HashJoinExec):
@@ -190,7 +191,7 @@ class BroadcastHashJoinExec(HashJoinExec):
         # inherited partition-local materialization would silently drop
         # every match whose build row lives in another partition's slice
         build, _jh, _ht = self._build_broadcast()
-        if not bool(jax.device_get(build.num_rows > 0)):
+        if not bool(host_get(build.num_rows > 0, "join.build_empty")):
             return None
         return build
 
@@ -322,7 +323,7 @@ class BroadcastNestedLoopJoinExec(BinaryExec):
             return
         if jt == "left":
             unmatched = ~pmatch & probe.active_mask()
-            n = int(jnp.sum(unmatched))
+            n = int(host_get(jnp.sum(unmatched), "join.unmatched_probe"))
             if n:
                 idx, nn = K.filter_indices(unmatched, probe.active_mask())
                 left_out = K.gather_batch(probe, idx, nn)

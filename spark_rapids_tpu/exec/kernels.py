@@ -20,6 +20,12 @@ Key design decisions (TPU-first):
   see `hash_keys`).
 - Variable-width (string) columns ride along as offsets+bytes; gathers
   recompute offsets with a cumsum and move bytes with one flat gather.
+- The gather, sort, aggregate, join and concat entry points carry a
+  ``jax.named_scope`` (gather, sort, agg.group, agg.reduce, join.build,
+  join.probe, concat): HLO metadata only, so no op changes and the
+  persistent compilation cache's key is untouched; a device op profile
+  then ranks by kernel and not by ``concatenate.18``
+  (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -35,6 +41,7 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, bucket_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
+from spark_rapids_tpu.utils.sync import host_get
 
 
 
@@ -55,6 +62,7 @@ def _string_row_ids(offsets: jax.Array, nbytes: int) -> jax.Array:
     return jnp.cumsum(marks[:nbytes]) - 1
 
 
+@jax.named_scope("gather")
 def gather_column(
     col: DeviceColumn,
     indices: jax.Array,
@@ -214,6 +222,7 @@ def gather_lanes(lanes: Sequence[jax.Array], idx: jax.Array) -> List[jax.Array]:
                                    lanes[k].dtype)
     return out  # type: ignore[return-value]
 
+@jax.named_scope("gather")
 def gather_columns(
     cols: Sequence[DeviceColumn],
     indices: jax.Array,
@@ -280,6 +289,7 @@ def gather_columns(
     return out  # type: ignore[return-value]
 
 
+@jax.named_scope("gather")
 def gather_batch(
     batch: ColumnarBatch,
     indices: jax.Array,
@@ -477,6 +487,7 @@ def _lexsort_variadic_max() -> int:
     return _C.LEXSORT_VARIADIC_MAX.get(_C.get_active())
 
 
+@jax.named_scope("sort.lexsort")
 def lexsort_chain(keys: Sequence[jax.Array]) -> jax.Array:
     """Stable lexicographic argsort. Semantics match ``jnp.lexsort(keys)``
     (last key primary).
@@ -528,6 +539,7 @@ class SortSpec(NamedTuple):
     str_words: int = 2
 
 
+@jax.named_scope("sort")
 def sort_indices(
     batch: ColumnarBatch, specs: Sequence[SortSpec], path: str = "lex"
 ) -> jax.Array:
@@ -571,8 +583,8 @@ def str_key_words(batch: ColumnarBatch, specs: Sequence[SortSpec],
         c = batch.columns[spec.column]
         w = 2
         if c.offsets is not None and not c.is_dict and c.data.shape[0] > 0:
-            ml = int(jax.device_get(
-                jnp.max(c.offsets[1:] - c.offsets[:-1])))
+            ml = int(host_get(jnp.max(c.offsets[1:] - c.offsets[:-1]),
+                              "sort.str_key_words"))
             need = (ml + 7) // 8
             while w < need:
                 w *= 2
@@ -834,6 +846,7 @@ class GroupInfo(NamedTuple):
     group_starts: jax.Array  # (cap,) int32 — permuted index of each group head
 
 
+@jax.named_scope("agg.group")
 def group_rows(batch: ColumnarBatch, key_cols: Sequence[int],
                active: Optional[jax.Array] = None) -> GroupInfo:
     """Cluster live rows by key equality.
@@ -926,6 +939,7 @@ def _agg_hashtbl_enabled() -> bool:
     return _C.AGG_HASHTBL_ENABLED.get(_C.get_active())
 
 
+@jax.named_scope("agg.group")
 def group_rows_prehashed(h1: jax.Array, h2: jax.Array,
                          active: jax.Array) -> GroupInfo:
     """Cluster rows whose 128-bit (h1, h2) hash pair matches. Used for
@@ -1010,6 +1024,7 @@ def _sorted_segment_reducers(seg: jax.Array, starts: jax.Array,
     return (seg_sum, seg_min, seg_max)
 
 
+@jax.named_scope("agg.reduce")
 def segment_agg(
     values: jax.Array,
     validity: jax.Array,
@@ -1106,6 +1121,7 @@ def segment_agg(
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("agg.reduce")
 def dense_segment_sums(rows: jax.Array, ids: jax.Array, num_ids: int
                        ) -> jax.Array:
     """Sum each of R value rows per dense id: (R, n) f64 -> (R, num_ids) f64.
@@ -1139,6 +1155,7 @@ _INT8_LIMB = 7
 _INT8_NLIMBS = 10  # 10 x 7 = 70 bits >= 64: full two's-complement coverage
 
 
+@jax.named_scope("agg.reduce")
 def dense_segment_sums_int(rows: Sequence[jax.Array], ids: jax.Array,
                            num_ids: int) -> jax.Array:
     """Exact int64 per-id sums on the MXU: (R x (n,) int64) -> (R, num_ids).
@@ -1180,6 +1197,7 @@ def _limb_matmul(rows: Sequence[jax.Array], ids: jax.Array,
     return s.astype(jnp.uint64).reshape(len(rows), _INT8_NLIMBS, num_ids)
 
 
+@jax.named_scope("agg.reduce")
 def dense_segment_sums_int128(rows: Sequence[jax.Array], ids: jax.Array,
                               num_ids: int, neg_counts: jax.Array):
     """Exact 128-bit per-id sums of int64 rows: -> (hi, lo) (R, num_ids).
@@ -1206,6 +1224,7 @@ def dense_segment_sums_int128(rows: Sequence[jax.Array], ids: jax.Array,
     return hi, lo
 
 
+@jax.named_scope("agg.reduce")
 def segment_sum_int128(hi: jax.Array, lo: jax.Array, seg_ids: jax.Array,
                        num_segments: int):
     """Scatter-based exact 128-bit segment sums for (hi, lo) columns
@@ -1232,6 +1251,7 @@ def segment_sum_int128(hi: jax.Array, lo: jax.Array, seg_ids: jax.Array,
     return h3, l2
 
 
+@jax.named_scope("agg.reduce")
 def dense_segment_counts(flags: Sequence[jax.Array], ids: jax.Array,
                          num_ids: int) -> jax.Array:
     """Per-id counts of boolean flag rows via one int8 matmul:
@@ -1248,6 +1268,7 @@ def dense_segment_counts(flags: Sequence[jax.Array], ids: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("concat")
 def concat_device(
     batches: Sequence[ColumnarBatch],
     out_capacity: int,
@@ -1337,6 +1358,7 @@ class JoinHashes(NamedTuple):
     valid: jax.Array  # (cap_b,) bool in sorted order
 
 
+@jax.named_scope("join.hash")
 def prepare_join_side(batch: ColumnarBatch, key_cols: Sequence[int]) -> JoinHashes:
     h = hash_keys(batch, key_cols)
     valid = batch.active_mask()
@@ -1350,6 +1372,7 @@ def prepare_join_side(batch: ColumnarBatch, key_cols: Sequence[int]) -> JoinHash
     return JoinHashes(hh[order], order, valid[order])
 
 
+@jax.named_scope("join.probe")
 def join_candidate_counts(
     probe: ColumnarBatch, probe_keys: Sequence[int], build: JoinHashes
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -1369,6 +1392,7 @@ def join_candidate_counts(
     return lo, cnt, pvalid
 
 
+@jax.named_scope("join.probe")
 def expand_candidates(
     lo: jax.Array, cnt: jax.Array, out_capacity: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -1428,6 +1452,7 @@ def _join_lg_b(capacity: int) -> int:
 
 
 @partial(jax.jit, static_argnums=(1,))
+@jax.named_scope("join.build")
 def build_join_table(batch: ColumnarBatch, key_cols: Tuple[int, ...]):
     """Build the table + per-build stats in ONE traced program.
 
@@ -1469,6 +1494,7 @@ def build_join_table(batch: ColumnarBatch, key_cols: Tuple[int, ...]):
 
 
 @partial(jax.jit, static_argnums=(2, 4, 5, 6))
+@jax.named_scope("join.probe")
 def probe_join_table_unique(probe: ColumnarBatch, tbl: JoinTable,
                             probe_keys: Tuple[int, ...],
                             build: ColumnarBatch,
@@ -1641,6 +1667,7 @@ def _hashtbl_insert_rounds(h1, h2, valid, capacity: int, seed: int,
 
 
 @partial(jax.jit, static_argnums=(3, 4, 5))
+@jax.named_scope("join.build")
 def build_hash_table(h1: jax.Array, h2: jax.Array, valid: jax.Array,
                      capacity: int, seed: int, max_probes: int):
     """Build the table + duplicate layout in one traced program.
@@ -1674,7 +1701,7 @@ def build_batch_hash_table(batch: ColumnarBatch, key_cols: Tuple[int, ...]):
     for seed in range(HASHTBL_MAX_REHASH):
         tbl, overflow = build_hash_table(h1, h2, valid, capacity, seed,
                                          HASHTBL_MAX_PROBES)
-        if not bool(jax.device_get(overflow)):
+        if not bool(host_get(overflow, "hashtbl.build_overflow")):
             _note_hashtbl("hashtbl_build_total")
             return tbl, capacity, seed
         _note_hashtbl("hashtbl_rehash_total")
@@ -1683,6 +1710,7 @@ def build_batch_hash_table(batch: ColumnarBatch, key_cols: Tuple[int, ...]):
 
 
 @partial(jax.jit, static_argnums=(3, 4, 5))
+@jax.named_scope("join.probe")
 def probe_hash_table(tbl: HashTable, h1: jax.Array, h2: jax.Array,
                      capacity: int, seed: int, max_probes: int):
     """Find each probe key's slot: bounded linear scan of pure gathers.
@@ -1853,6 +1881,7 @@ def _group_rows_prehashed_sort(h1: jax.Array, h2: jax.Array,
     return _group_from_boundaries(perm, neq, active, cap)
 
 
+@jax.named_scope("agg.group")
 def group_rows_table(h1: jax.Array, h2: jax.Array,
                      active: jax.Array) -> GroupInfo:
     """Cluster rows by 128-bit hash pair via the open-addressing table.

@@ -25,6 +25,7 @@ from spark_rapids_tpu.exec.base import TpuExec, UnaryExec
 from spark_rapids_tpu.exec import kernels as K
 from spark_rapids_tpu.exec.aggregate import concat_jit
 from spark_rapids_tpu.exprs import expr as E
+from spark_rapids_tpu.utils.sync import host_get
 
 
 @partial(jax.jit, static_argnums=(1, 2))
@@ -243,7 +244,7 @@ class _SortRun:
 
     def __init__(self, batch: ColumnarBatch, keys, framework):
         self.offset = 0
-        self.n = int(batch.num_rows)
+        self.n = int(host_get(batch.num_rows, "sort.run_rows"))
         self.keys = keys  # boundary key triple, most significant first
         if framework is not None:
             from spark_rapids_tpu.mem.spill import SpillableBatch
@@ -361,23 +362,30 @@ class OutOfCoreSortIterator:
             # scalars stay on device so comparisons are exact even where the
             # device float representation (double-double on real TPU) does
             # not round-trip through host float64
-            bounds = []
-            for r in runs:
-                j = min(r.offset + t - 1, r.n - 1)
-                bounds.append((tuple(k[j].item() for k in r.keys), r, j))
+            picks = [(r, min(r.offset + t - 1, r.n - 1)) for r in runs]
+            # one sync for every run's boundary keys, one for the counts
+            keys_h = host_get([[k[j] for k in r.keys] for r, j in picks],
+                              "sort.boundary_keys")
+            bounds = [(tuple(v.item() for v in ks), r, j)
+                      for ks, (r, j) in zip(keys_h, picks)]
             _, rb, jb = min(bounds, key=lambda x: x[0])
             bvals = tuple(k[jb] for k in rb.keys)
+            counts = host_get([_count_le(r.keys, r.offset, r.n, bvals)
+                               for r in runs], "sort.boundary_counts")
             pieces = []
-            for r in runs:
-                c = int(_count_le(r.keys, r.offset, r.n, bvals))
+            for r, c in zip(runs, counts):
+                c = int(c)
                 if c > 0:
                     batch = r.get()
                     # exact byte needs per string column keep emitted pieces
                     # truly bounded (no full-run byte buffers riding along)
+                    spans = [col.offsets[r.offset + c] - col.offsets[r.offset]
+                             for col in batch.columns
+                             if col.offsets is not None]
+                    nbytes = iter(host_get(spans, "sort.piece_bytes")
+                                  if spans else ())
                     bcaps = tuple(
-                        bucket_capacity(
-                            max(int(col.offsets[r.offset + c]
-                                    - col.offsets[r.offset]), 8), 8)
+                        bucket_capacity(max(int(next(nbytes)), 8), 8)
                         if col.offsets is not None else 0
                         for col in batch.columns)
                     pieces.append(_slice_rows(batch, jnp.int32(r.offset),

@@ -103,55 +103,80 @@ class NetClient:
         """Run ``df`` remotely; returns a pa.Table byte-identical to the
         in-process ``df.to_arrow()``. Raises the same typed exceptions as
         ``QueryServer.submit``/``Ticket.result``."""
+        from spark_rapids_tpu.obs import span as _span
+
+        # the request's root span: its id is what the wire hands the
+        # server, so every server span parents on a span that is recorded
+        with _span.span("net:request",
+                        attrs={"query": name} if name else None) as root:
+            with _span.span("net:client-send"):
+                with self._lock:
+                    refs = dict(self._refs)
+                plan = P.strip_tables(df.plan, refs)
+                conf = df.conf if df.conf is not None else self.conf
+                conf_items = (dict(conf._values) if conf is not None
+                              else None)
+                payload = P.dump_obj({
+                    "plan": plan,
+                    "conf_items": conf_items,
+                    "shuffle_partitions": df.shuffle_partitions,
+                    "priority": priority,
+                    "deadline_ms": deadline_ms,
+                    "memory_budget": memory_budget,
+                    "name": name,
+                    "trace": (root.context().to_wire()
+                              if root is not None else None),
+                })
+                if timeout_s is not None:
+                    self._sock.settimeout(timeout_s)
+                self._send(P.SUBMIT, payload)
+            return self._recv_result()
+
+    def _recv_result(self):
+        """The result stream as one pa.Table. ``net:client-recv`` opens
+        when the first frame has arrived (the wait before it is the
+        server's) and closes on the decoded table."""
         import pyarrow as pa
         from spark_rapids_tpu.obs import span as _span
 
-        trace = _span.new_trace()
-        with self._lock:
-            refs = dict(self._refs)
-        plan = P.strip_tables(df.plan, refs)
-        conf = df.conf if df.conf is not None else self.conf
-        conf_items = dict(conf._values) if conf is not None else None
-        payload = P.dump_obj({
-            "plan": plan,
-            "conf_items": conf_items,
-            "shuffle_partitions": df.shuffle_partitions,
-            "priority": priority,
-            "deadline_ms": deadline_ms,
-            "memory_budget": memory_budget,
-            "name": name,
-            "trace": trace.to_wire(),
-        })
-        if timeout_s is not None:
-            self._sock.settimeout(timeout_s)
-        self._send(P.SUBMIT, payload)
         schema = None
         batches = []
         expected = None
-        while True:
-            ftype, data = self._recv()
-            if ftype == P.ERROR:
-                P.raise_typed(P.load_obj(data))
-            elif ftype == P.RESULT_START:
-                start = P.load_obj(data)
-                schema = P.decode_schema(start["schema"])
-                expected = start.get("batches")
-            elif ftype == P.RESULT_BATCH:
-                if schema is None:
-                    raise P.ProtocolError("RESULT_BATCH before RESULT_START")
-                batches.append(P.decode_batch(data, schema))
-            elif ftype == P.RESULT_END:
-                end = P.load_obj(data)
-                if expected is not None and end.get("batches") not in (
-                        None, len(batches)):
+        recv = None
+        try:
+            while True:
+                ftype, data = self._recv()
+                if recv is None and _span.enabled():
+                    recv = _span.Span("net:client-recv")
+                if ftype == P.ERROR:
+                    P.raise_typed(P.load_obj(data))
+                elif ftype == P.RESULT_START:
+                    start = P.load_obj(data)
+                    schema = P.decode_schema(start["schema"])
+                    expected = start.get("batches")
+                elif ftype == P.RESULT_BATCH:
+                    if schema is None:
+                        raise P.ProtocolError(
+                            "RESULT_BATCH before RESULT_START")
+                    batches.append(P.decode_batch(data, schema))
+                elif ftype == P.RESULT_END:
+                    end = P.load_obj(data)
+                    if expected is not None and end.get("batches") not in (
+                            None, len(batches)):
+                        raise P.ProtocolError(
+                            f"stream truncated: {len(batches)} of "
+                            f"{end.get('batches')} batches")
+                    table = pa.Table.from_batches(batches, schema=schema)
+                    if recv is not None:
+                        recv.attrs["rows"] = table.num_rows
+                    return table
+                else:
                     raise P.ProtocolError(
-                        f"stream truncated: {len(batches)} of "
-                        f"{end.get('batches')} batches")
-                return pa.Table.from_batches(batches, schema=schema)
-            else:
-                raise P.ProtocolError(
-                    f"unexpected {P.TYPE_NAMES.get(ftype, ftype)} frame "
-                    f"in result stream")
+                        f"unexpected {P.TYPE_NAMES.get(ftype, ftype)} frame "
+                        f"in result stream")
+        finally:
+            if recv is not None:
+                recv.finish()
 
     def cancel(self) -> None:
         """Best-effort cancel of the in-flight query (sent async; the

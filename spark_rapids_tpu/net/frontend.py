@@ -214,42 +214,26 @@ class QueryFrontend:
 
     # -- submit + result streaming ----------------------------------------
     def _handle_submit(self, conn, session: Session, payload: bytes) -> None:
-        from spark_rapids_tpu.config.conf import RapidsConf
         from spark_rapids_tpu.obs import span as _span
-        from spark_rapids_tpu.plan.dataframe import DataFrame
         from spark_rapids_tpu.serve import AdmissionRejected
-        from spark_rapids_tpu.serve import lowering as _low
-        from spark_rapids_tpu.serve import metrics as _sm
 
-        accept_t0 = time.perf_counter_ns()
         _m.bump("net_submit_total")
-        doc = P.load_obj(payload)  # post-auth only
-        trace = _span.TraceContext.from_wire(doc.get("trace"))
-        name = doc.get("name")
+        # net:accept opens before the payload is unpickled and joins the
+        # client's trace once the payload has named it
+        sp = (_span.Span("net:accept", attrs={"tenant": session.tenant})
+              if _span.enabled() else None)
         try:
-            with self._lock:
-                catalog = dict(self._catalog)
-            plan = P.resolve_tables(doc["plan"], catalog)
-            conf = (RapidsConf(doc["conf_items"])
-                    if doc.get("conf_items") is not None else None)
-            df = DataFrame(plan, conf,
-                           int(doc.get("shuffle_partitions", 4)))
-            if self._gate:
-                cells = _low.unsupported_cells(
-                    df, conf if conf is not None else self.conf)
-                if cells:
-                    _sm.bump("admission_unsupported_plan_total")
-                    _sm.note_outcome(session.tenant, doc.get("priority", 0),
-                                     "rejected:unsupported-plan")
-                    raise P.NetError(
-                        "unsupported-plan",
-                        f"plan will not lower: {cells[0][0]}: "
-                        f"{cells[0][1]}", detail=cells)
-            ticket = self.server.submit(
-                df, priority=int(doc.get("priority", 0)),
-                deadline_ms=doc.get("deadline_ms"),
-                memory_budget=doc.get("memory_budget"),
-                name=name, tenant=session.tenant, trace=trace)
+            doc = P.load_obj(payload)  # post-auth only
+            # a client that sent no context still gets one trace
+            trace = (_span.TraceContext.from_wire(doc.get("trace"))
+                     or _span.new_trace())
+            name = doc.get("name")
+            if sp is not None:
+                sp.adopt(trace)
+                if name:
+                    sp.attrs["query"] = name
+            with _span.activate(sp.context() if sp is not None else None):
+                ticket = self._admit(doc, session, name, trace)
         except AdmissionRejected as e:
             _m.bump("net_submit_rejected_total")
             self._try_error(conn, e.reason, str(e))
@@ -258,40 +242,80 @@ class QueryFrontend:
             _m.bump("net_submit_rejected_total")
             self._try_error(conn, e.code, str(e), e.detail)
             return
-        _span.record_span("net:accept", accept_t0,
-                          time.perf_counter_ns() - accept_t0, ctx=trace,
-                          attrs={"query": name, "tenant": session.tenant})
+        finally:
+            if sp is not None:
+                sp.finish()
         session.queries += 1
-        self._await_and_stream(conn, session, ticket, trace)
+        self._await_and_stream(conn, session, ticket)
+
+    def _admit(self, doc, session: Session, name, trace):
+        """Resolve the plan against the catalog, gate it, hand it to the
+        query server; returns the Ticket or raises the typed rejection."""
+        from spark_rapids_tpu.config.conf import RapidsConf
+        from spark_rapids_tpu.plan.dataframe import DataFrame
+        from spark_rapids_tpu.serve import lowering as _low
+        from spark_rapids_tpu.serve import metrics as _sm
+
+        with self._lock:
+            catalog = dict(self._catalog)
+        plan = P.resolve_tables(doc["plan"], catalog)
+        conf = (RapidsConf(doc["conf_items"])
+                if doc.get("conf_items") is not None else None)
+        df = DataFrame(plan, conf, int(doc.get("shuffle_partitions", 4)))
+        if self._gate:
+            cells = _low.unsupported_cells(
+                df, conf if conf is not None else self.conf)
+            if cells:
+                _sm.bump("admission_unsupported_plan_total")
+                _sm.note_outcome(session.tenant, doc.get("priority", 0),
+                                 "rejected:unsupported-plan")
+                raise P.NetError(
+                    "unsupported-plan",
+                    f"plan will not lower: {cells[0][0]}: "
+                    f"{cells[0][1]}", detail=cells)
+        return self.server.submit(
+            df, priority=int(doc.get("priority", 0)),
+            deadline_ms=doc.get("deadline_ms"),
+            memory_budget=doc.get("memory_budget"),
+            name=name, tenant=session.tenant, trace=trace)
 
     def _await_result(self, conn, ticket):
         """Block until the ticket resolves, servicing CANCEL frames and
         cancelling on client disconnect. Returns the result table or
         raises the query's typed failure."""
-        while not ticket.done():
-            ready, _, _ = select.select([conn], [], [], _POLL_S)
-            if self._closing:
-                ticket.cancel("frontend shutdown")
-            if not ready:
-                continue
-            try:
-                ftype, _payload = self._recv(conn)
-            except (P.ConnectionClosed, ConnectionError, OSError):
-                _m.bump("net_disconnect_cancel_total")
-                ticket.cancel("client-disconnect")
-                raise
-            if ftype == P.CANCEL:
-                _m.bump("net_cancel_total")
-                ticket.cancel("client-cancel")
-            else:
-                raise P.ProtocolError(
-                    f"unexpected {P.TYPE_NAMES.get(ftype, ftype)} "
-                    f"frame while a query is in flight")
+        from spark_rapids_tpu.obs import span as _span
+        try:
+            while not ticket.done():
+                ready, _, _ = select.select([conn], [], [], _POLL_S)
+                if self._closing:
+                    ticket.cancel("frontend shutdown")
+                if not ready:
+                    continue
+                try:
+                    ftype, _payload = self._recv(conn)
+                except (P.ConnectionClosed, ConnectionError, OSError):
+                    _m.bump("net_disconnect_cancel_total")
+                    ticket.cancel("client-disconnect")
+                    raise
+                if ftype == P.CANCEL:
+                    _m.bump("net_cancel_total")
+                    ticket.cancel("client-cancel")
+                else:
+                    raise P.ProtocolError(
+                        f"unexpected {P.TYPE_NAMES.get(ftype, ftype)} "
+                        f"frame while a query is in flight")
+        finally:
+            # how long the resolved ticket sat before this loop saw it: the
+            # poll's share of the request, measured and not inferred
+            done_ns = ticket.done_ns
+            if done_ns is not None:
+                _span.record_span(
+                    "net:wake-lag", done_ns,
+                    time.perf_counter_ns() - done_ns, ctx=ticket.ctx.trace,
+                    attrs={"query": ticket.ctx.name})
         return ticket.result()
 
-    def _await_and_stream(self, conn, session: Session, ticket,
-                          trace) -> None:
-        from spark_rapids_tpu import faults
+    def _await_and_stream(self, conn, session: Session, ticket) -> None:
         from spark_rapids_tpu.obs import histo as _h
         from spark_rapids_tpu.obs import span as _span
         from spark_rapids_tpu.serve import (QueryCancelled,
@@ -312,7 +336,20 @@ class QueryFrontend:
             self._try_error(conn, "failed", f"{type(e).__name__}: {e}")
             return
 
-        stream_t0 = time.perf_counter_ns()
+        with _span.span("net:stream", ctx=ticket.ctx.trace,
+                        attrs={"query": ticket.ctx.name,
+                               "tenant": session.tenant}):
+            stream_t0 = time.perf_counter_ns()
+            try:
+                self._stream(conn, ticket, table)
+            finally:
+                _h.record_labeled("net_stream_ns",
+                                  time.perf_counter_ns() - stream_t0,
+                                  tenant=session.tenant,
+                                  priority=ticket.ctx.priority)
+
+    def _stream(self, conn, ticket, table) -> None:
+        from spark_rapids_tpu import faults
         batches = table.combine_chunks().to_batches(
             max_chunksize=self.stream_batch_rows)
         try:
@@ -335,14 +372,6 @@ class QueryFrontend:
         except (BrokenPipeError, ConnectionError, OSError):
             _m.bump("net_disconnect_cancel_total")
             raise P.ConnectionClosed("client vanished mid-stream")
-        finally:
-            dur_ns = time.perf_counter_ns() - stream_t0
-            _h.record_labeled("net_stream_ns", dur_ns,
-                              tenant=session.tenant,
-                              priority=ticket.ctx.priority)
-            _span.record_span("net:stream", stream_t0, dur_ns, ctx=trace,
-                              attrs={"query": ticket.ctx.name,
-                                     "tenant": session.tenant})
 
     # -- shutdown ----------------------------------------------------------
     def close(self) -> None:

@@ -100,6 +100,13 @@ CATALOG: "List[Tuple[str, str, str]]" = [
      "Nanoseconds spent in first calls of newly-traced programs "
      "(compile-cost attribution for QueryProfile phases)"),
     ("jit_cache_size", "gauge", "Distinct jitted programs currently cached"),
+    ("exec_host_sync_total", "counter",
+     "Blocking device->host reads on the query path "
+     "(utils/sync.host_get); syncs per query = its rate over "
+     "sched_completed_total"),
+    ("exec_host_sync_ns_total", "counter",
+     "Nanoseconds host threads spent blocked in those reads "
+     "(waiting for the device, then copying; not dispatching)"),
     ("jit_persist_hit_total", "counter",
      "Jitted programs reloaded from the on-disk cross-process cache "
      "(exec/jit_persist.py) instead of being re-traced"),
@@ -296,6 +303,8 @@ def snapshot() -> Dict[str, int]:
         out["filecache_cached_bytes"] += fc.cached_bytes
     from spark_rapids_tpu.exec import jit_cache as _jc
     out.update(_jc.cache_stats())
+    from spark_rapids_tpu.utils import sync as _sync
+    out.update(_sync.counters())
     from spark_rapids_tpu.exec import jit_persist as _jp
     out.update(_jp.counters())
     from spark_rapids_tpu.plan import plan_cache as _pc

@@ -157,18 +157,9 @@ class QueryProfile:
             _histo.record("compile_phase_ns", compile_ns)
             _histo.record("execute_phase_ns",
                           max(0, self.wall_ns - compile_ns))
-            # phase spans: when this query runs under a trace (serving or
-            # cluster), plan/compile attribution joins the distributed
-            # timeline. Starts are synthetic-sequential inside the wall
-            # window — attribution, not wall truth.
-            from spark_rapids_tpu.obs import span as _span
-            if _span.current() is not None:
-                plan_ns = int(plan_ms * 1e6)
-                _span.record_span("query:plan", self._t0, plan_ns,
-                                  attrs={"profile": self.query_id})
-                _span.record_span("query:compile", self._t0 + plan_ns,
-                                  compile_ns,
-                                  attrs={"profile": self.query_id})
+            # (the query:plan and query:compile spans are real intervals,
+            # opened where planning and compilation happen: plan/
+            # dataframe.py and exec/jit_cache.py)
             # serving attribution for the explain_analyze tenant-slo line
             from spark_rapids_tpu.serve import context as _qc
             qc = _qc.current()

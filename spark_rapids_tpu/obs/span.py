@@ -4,11 +4,21 @@ The reference plugin attributes time with NVTX ranges and a
 driver-coordinated profiler; both stop at the process boundary. This
 module is the standalone analog for the serving + mesh/cluster path: a
 ``Span`` names one timed region, carries ``trace_id``/``span_id``/
-``parent_id``, and records into the *existing* observability machinery —
-``utils/tracing.record_event`` (so spans land in per-process Chrome
-traces and survive the multi-worker merge in obs/trace_export.py) and
-the bounded lifecycle journal (obs/events.py) — rather than inventing a
-third event stream.
+``parent_id``, and records into the *existing* observability machinery:
+a live span is one ``utils/tracing.TraceRange``, i.e. an event in the
+in-process log (``tracing.record_event``, so spans land in per-process
+Chrome traces and survive the multi-worker merge in obs/trace_export.py)
+and a ``jax.profiler.TraceAnnotation`` of the same name for the span's
+life (so a jax profiler trace carries the program's spans on the device
+trace's own clock). Nothing else is written: the lifecycle journal
+(obs/events.py) holds lifecycle events, not spans.
+
+Cost with capture off and no profiler running: no lock, no
+``os.urandom``. ``record_event`` reads the capture flag before its lock,
+the annotation is the native TraceMe's flag check, and ids are a
+per-process random prefix (drawn once, again in a forked child) plus a
+counter, so they are unique across the processes of a trace without a
+system call per span.
 
 Cross-process propagation uses ``TraceContext``: a two-field value
 (``trace_id``, ``span_id`` of the would-be parent) whose ``to_wire()``
@@ -31,11 +41,13 @@ name.
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
-import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
+
+from spark_rapids_tpu.utils import tracing
 
 # name -> help; the closed set of span names. Parsed statically by
 # tools/lint/span_catalog.py (keep this a literal list of 2-tuples).
@@ -44,17 +56,36 @@ from typing import Dict, List, Optional, Tuple
 CATALOG: "List[Tuple[str, str]]" = [
     ("query:submit", "QueryServer.submit window (validate + admit + enqueue)"),
     ("query:admit", "Admission-control decision inside submit"),
-    ("query:queue-wait", "Admitted-to-scheduled wait on the priority queue"),
-    ("query:plan", "Planning phase attributed by QueryProfile"),
-    ("query:compile", "Trace+compile phase attributed by the jit timer"),
+    ("query:queue-wait", "Admitted-to-scheduled wait on the priority queue; "
+     "stamped afterwards (its start is in the past), so it has no "
+     "profiler annotation"),
+    ("query:plan", "DataFrame.to_arrow's physical_plan(): plan-cache "
+     "lookup or the full Overrides.apply (attrs: query, cache_hit)"),
+    ("query:compile", "First call of a newly bound jitted program: trace + "
+     "compile or cache load (attrs: program); absent when nothing compiled"),
     ("query:execute", "Execute window on the serving executor thread"),
+    ("query:readback", "One batch_to_arrow of the result: device->host copy "
+     "(its exec:host-sync child) plus host-side Arrow assembly (attrs: rows)"),
+    ("query:finish", "Query close-out on the executor thread: profile "
+     "finish, autotune feedback and its file write, cleanup walk, leak audit"),
+    ("exec:host-sync", "One blocking device->host read on the query path, "
+     "through utils/sync.host_get (attrs: site)"),
     ("cluster:map", "Map task executed by a cluster executor process"),
     ("cluster:reduce", "Reduce task executed by a cluster executor process"),
     ("shuffle:fetch", "One shuffle block fetch round-trip (client side)"),
     ("shuffle:write", "Map-output partition/serialize/spill on the write path"),
     ("mesh:dispatch", "One SPMD dispatch by the mesh executor"),
+    ("net:request", "NetClient.submit, whole call: the root span of a "
+     "served request, whose id the server's spans parent on (attrs: query)"),
+    ("net:client-send", "Client side of SUBMIT: strip tables, pickle the "
+     "plan, send the frame"),
+    ("net:client-recv", "Client side of the result: first result frame "
+     "received through the decoded pa.Table (attrs: rows)"),
     ("net:accept", "Wire SUBMIT intake: decode + table resolve + lowering "
-     "gate, before QueryServer.submit"),
+     "gate + QueryServer.submit"),
+    ("net:wake-lag", "Ticket resolved -> the front-end's _await_result "
+     "noticed; stamped afterwards (its start is in the past), so it has no "
+     "profiler annotation"),
     ("net:stream", "Result streaming window: Arrow IPC batches over the "
      "wire, RESULT_START through RESULT_END"),
 ]
@@ -73,8 +104,24 @@ def enabled() -> bool:
     return _enabled
 
 
+_id_prefix = ""
+_id_counter = itertools.count(1)
+
+
+def _reseed_ids() -> None:
+    global _id_prefix, _id_counter
+    _id_prefix = os.urandom(4).hex()
+    _id_counter = itertools.count(1)
+
+
+_reseed_ids()
+os.register_at_fork(after_in_child=_reseed_ids)
+
+
 def _new_id() -> str:
-    return os.urandom(8).hex()
+    """This process's random prefix + a counter (``next`` on
+    ``itertools.count`` is atomic under the GIL: no lock)."""
+    return f"{_id_prefix}{next(_id_counter):010x}"
 
 
 class TraceContext:
@@ -129,16 +176,15 @@ def activate(ctx: Optional[TraceContext]):
 
 
 class Span:
-    """One timed, named region of a trace.
-
-    ``finish()`` records the span as a Chrome-trace event (name = span
-    name, args carry the ids + attrs) and a journal ``span`` event, then
-    becomes inert. Parentage comes from the explicit ``ctx`` or the
-    thread's current context; with neither, the span starts a new trace.
-    """
+    """One timed, named region of a trace, live from construction to
+    ``finish()``: a ``tracing.TraceRange`` (profiler annotation now, event
+    in the in-process log at finish, args carrying the ids + attrs).
+    ``attrs`` may be filled in until ``finish()``. Parentage comes from the
+    explicit ``ctx`` or the thread's current context; with neither, the
+    span is the root of a new trace. Open and finish on one thread."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "attrs",
-                 "start_ns", "_finished")
+                 "_range")
 
     def __init__(self, name: str, ctx: Optional[TraceContext] = None,
                  attrs: Optional[Dict] = None):
@@ -147,42 +193,45 @@ class Span:
                            "obs/span.CATALOG")
         ctx = ctx if ctx is not None else current()
         if ctx is None:
-            ctx = new_trace()
-            self.parent_id = None
+            self.trace_id, self.parent_id = _new_id(), None
         else:
-            self.parent_id = ctx.span_id
-        self.trace_id = ctx.trace_id
+            self.trace_id, self.parent_id = ctx.trace_id, ctx.span_id
         self.span_id = _new_id()
         self.name = name
         self.attrs = dict(attrs) if attrs else {}
-        self.start_ns = time.perf_counter_ns()
-        self._finished = False
+        self._range = tracing.TraceRange(name).open()
+
+    @property
+    def start_ns(self) -> int:
+        return self._range.start_ns
+
+    def adopt(self, ctx: Optional[TraceContext]) -> None:
+        """Move this span under ``ctx``: for a server whose span has to
+        open before the payload that names its trace is decoded. Call it
+        before ``context()`` is handed to anyone."""
+        if ctx is not None:
+            self.trace_id, self.parent_id = ctx.trace_id, ctx.span_id
 
     def context(self) -> TraceContext:
         """Child context: propagate this to parent sub-spans on me."""
         return TraceContext(self.trace_id, self.span_id)
 
     def finish(self, end_ns: Optional[int] = None) -> None:
-        if self._finished:
+        rng, self._range = self._range, None
+        if rng is None:
             return
-        self._finished = True
-        end = end_ns if end_ns is not None else time.perf_counter_ns()
-        _record(self.name, self.start_ns, max(0, end - self.start_ns),
-                self.trace_id, self.span_id, self.parent_id, self.attrs)
+        args = (_args(self.trace_id, self.span_id, self.parent_id,
+                      self.attrs) if tracing.capturing() else None)
+        rng.close(args=args, end_ns=end_ns, record=args is not None)
 
 
-def _record(name: str, start_ns: int, dur_ns: int, trace_id: str,
-            span_id: str, parent_id: Optional[str], attrs: Dict) -> None:
-    from spark_rapids_tpu.obs import events as journal
-    from spark_rapids_tpu.utils import tracing
-
+def _args(trace_id: str, span_id: str, parent_id: Optional[str],
+          attrs: Optional[Dict]) -> Dict:
     args = {"trace_id": trace_id, "span_id": span_id,
             "parent_id": parent_id}
     if attrs:
         args.update(attrs)
-    tracing.record_event(name, start_ns, dur_ns, args=args)
-    journal.emit("span", name=name, trace_id=trace_id, span_id=span_id,
-                 parent_id=parent_id, dur_ms=round(dur_ns / 1e6, 3))
+    return args
 
 
 def record_span(name: str, start_ns: int, dur_ns: int,
@@ -190,22 +239,25 @@ def record_span(name: str, start_ns: int, dur_ns: int,
                 attrs: Optional[Dict] = None) -> Optional[str]:
     """Record an already-timed region as a completed span.
 
-    For sites that measured a window themselves (shuffle fetch retry
-    loop, profile phase attribution) and only need the span stamped.
-    Returns the new span_id, or None when tracing is disabled / no
-    context is active and ``ctx`` was not given.
+    For sites whose region began before anything could open it (a wait
+    measured from a stamp another thread left: query:queue-wait,
+    net:wake-lag) or that timed a window themselves (shuffle fetch retry
+    loop). Such a span has no profiler annotation. Returns the new
+    span_id, or None when tracing is disabled, no capture window is open,
+    or no context is active and ``ctx`` was not given.
     """
-    if not _enabled:
-        return None
     if name not in _NAMES:
         raise KeyError(f"span name {name!r} is not declared in "
                        "obs/span.CATALOG")
+    if not _enabled or not tracing.capturing():
+        return None
     ctx = ctx if ctx is not None else current()
     if ctx is None:
         return None
     span_id = _new_id()
-    _record(name, start_ns, max(0, int(dur_ns)), ctx.trace_id, span_id,
-            ctx.span_id, dict(attrs) if attrs else {})
+    tracing.record_event(name, start_ns, max(0, int(dur_ns)),
+                         args=_args(ctx.trace_id, span_id, ctx.span_id,
+                                    attrs))
     return span_id
 
 

@@ -120,7 +120,26 @@ class DataFrame:
         if cached is not None and cached[0] == (self.conf,
                                                 self.shuffle_partitions):
             return cached[1]
-        return Overrides(self.conf, self.shuffle_partitions).apply(self.plan)
+        ov = Overrides(self.conf, self.shuffle_partitions)
+        node = ov.apply(self.plan)
+        self._plan_cache_hit = ov.cache_hit
+        return node
+
+    def _plan_for_execution(self):
+        """``physical_plan()`` as the ``query:plan`` span of the running
+        trace: a real interval on the executor thread, carrying the
+        request's name and whether the plan memo answered."""
+        from spark_rapids_tpu.obs import span as _span
+        from spark_rapids_tpu.serve import context as _sctx
+
+        qc = _sctx.current()
+        with _span.task_span("query:plan", attrs=(
+                {"query": qc.name} if qc is not None else None)) as sp:
+            self._plan_cache_hit = None  # a handed-off plan: not looked up
+            node = self.physical_plan()
+            if sp is not None:
+                sp.attrs["cache_hit"] = self._plan_cache_hit
+        return node
 
     def explain(self) -> str:
         from spark_rapids_tpu.plan.overrides import Overrides, explain
@@ -200,7 +219,7 @@ class DataFrame:
             from spark_rapids_tpu.serve import context as _sctx
             _sctx.check_cancel()  # no whole-query retry for a dead query
             try:
-                out = self._execute_plan(self.physical_plan())
+                out = self._execute_plan(self._plan_for_execution())
                 if attempt > 1:
                     faults.note_recovered("query")
                 return out
@@ -237,8 +256,8 @@ class DataFrame:
         from spark_rapids_tpu.columnar.batch import batch_to_arrow
         from spark_rapids_tpu.obs import memtrack as _mt
         from spark_rapids_tpu.obs import profile_for
+        from spark_rapids_tpu.obs import span as _span
         from spark_rapids_tpu.plan.cpu import CpuExec
-        from spark_rapids_tpu.shuffle import ShuffleExchangeExec
 
         schema = node.output_schema
         tables = []
@@ -299,7 +318,10 @@ class DataFrame:
                             # CBO's measured xfer ns/row (plan/autotune.py;
                             # buffered, flushed at prof.finish below)
                             t0 = time.perf_counter_ns()
-                            t = batch_to_arrow(b, schema)
+                            with _span.task_span("query:readback") as sp:
+                                t = batch_to_arrow(b, schema)
+                                if sp is not None:
+                                    sp.attrs["rows"] = t.num_rows
                             tables.append(t)
                             if t.num_rows:
                                 from spark_rapids_tpu.plan import (
@@ -314,48 +336,60 @@ class DataFrame:
                                         else (ctx.ctx_id, p))
             had_error = False
         finally:
-            # close out the per-query profile (plan/overrides.py installed
-            # it at plan time) before shuffle state is released
-            if prof is not None:
-                prof.finish(node)
-            self._last_profile = prof
-
-            # release shuffle files/blocks now that output is materialized
-            from spark_rapids_tpu.exec.reuse import ReusedExchangeExec
-
-            def walk(n):
-                if isinstance(n, (ShuffleExchangeExec, ReusedExchangeExec)):
-                    n.cleanup()
-                # a fused stage's constituents are not structural children,
-                # but an absorbed join's build subtree hangs off the
-                # constituent (exec/fused.py) and can contain exchanges
-                # whose files would otherwise never be released
-                for op in getattr(n, "fused_ops", ()):
-                    if len(op.children) == 2:
-                        walk(op.children[1])
-                for c in n.children:
-                    walk(c)
-
-            walk(node)
-
-            # query-end leak audit (MemoryCleaner analog): everything this
-            # query allocated must be freed by now — cached materialization
-            # entries are exempt (retained by design). Runs AFTER the
-            # cleanup walk so legitimate releases have happened.
-            try:
-                audit = _mt.audit_query(qid, had_error=had_error)
-                if prof is not None and not audit.get("skipped"):
-                    prof.memory["leak_audit"] = {
-                        "leaked_bytes": audit["leaked_bytes"],
-                        "retained_bytes": audit["retained_bytes"],
-                    }
-            finally:
-                if pool is not None:
-                    pool.clear_query_budget(qid)
-                _mt.end_query(qid)
+            # the query's close-out runs on the executor thread while the
+            # device idles and the client waits: one span names it
+            with _span.task_span("query:finish"):
+                self._finish_query(node, prof, qid, pool, had_error)
         if not tables:
             return schema.to_arrow().empty_table()
         return pa.concat_tables(tables)
+
+    def _finish_query(self, node, prof, qid, pool, had_error) -> None:
+        """Everything after the last batch: profile close-out (gauge
+        snapshots, node stats, autotune feedback and its file write), the
+        shuffle cleanup walk, the leak audit."""
+        from spark_rapids_tpu.obs import memtrack as _mt
+        from spark_rapids_tpu.shuffle import ShuffleExchangeExec
+
+        # close out the per-query profile (plan/overrides.py installed
+        # it at plan time) before shuffle state is released
+        if prof is not None:
+            prof.finish(node)
+        self._last_profile = prof
+
+        # release shuffle files/blocks now that output is materialized
+        from spark_rapids_tpu.exec.reuse import ReusedExchangeExec
+
+        def walk(n):
+            if isinstance(n, (ShuffleExchangeExec, ReusedExchangeExec)):
+                n.cleanup()
+            # a fused stage's constituents are not structural children,
+            # but an absorbed join's build subtree hangs off the
+            # constituent (exec/fused.py) and can contain exchanges
+            # whose files would otherwise never be released
+            for op in getattr(n, "fused_ops", ()):
+                if len(op.children) == 2:
+                    walk(op.children[1])
+            for c in n.children:
+                walk(c)
+
+        walk(node)
+
+        # query-end leak audit (MemoryCleaner analog): everything this
+        # query allocated must be freed by now — cached materialization
+        # entries are exempt (retained by design). Runs AFTER the
+        # cleanup walk so legitimate releases have happened.
+        try:
+            audit = _mt.audit_query(qid, had_error=had_error)
+            if prof is not None and not audit.get("skipped"):
+                prof.memory["leak_audit"] = {
+                    "leaked_bytes": audit["leaked_bytes"],
+                    "retained_bytes": audit["retained_bytes"],
+                }
+        finally:
+            if pool is not None:
+                pool.clear_query_budget(qid)
+            _mt.end_query(qid)
 
     def collect(self) -> List[dict]:
         return self.to_arrow().to_pylist()

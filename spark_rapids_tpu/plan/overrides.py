@@ -380,6 +380,7 @@ class Overrides:
                  shuffle_partitions: int = 4):
         self.conf = conf or C.RapidsConf()
         self.shuffle_partitions = shuffle_partitions
+        self.cache_hit = False  # whether apply() was answered by the memo
 
     def _apply_path_rules(self, plan: L.LogicalPlan) -> None:
         """Rewrite scan paths per the configured replacement rules before
@@ -702,6 +703,7 @@ class Overrides:
                                      self.shuffle_partitions, pinned)
             entry = _pc.lookup(memo_key) if memo_key is not None else None
             if entry is not None:
+                self.cache_hit = True
                 lookup_ns = _time.perf_counter_ns() - t_lk
                 if C.EXPLAIN.get(self.conf) != "NONE":
                     print("[plan-cache hit]\n" + entry.explain)

@@ -61,9 +61,16 @@ class Ticket:
         self._result = None
         self._error: Optional[BaseException] = None
         self.enqueued_ns = time.perf_counter_ns()
+        self._done_ns: Optional[int] = None
 
     def done(self) -> bool:
         return self._done.is_set()
+
+    @property
+    def done_ns(self) -> Optional[int]:
+        """perf_counter_ns at which the query resolved (either way), or
+        None while it has not: the start of the front-end's net:wake-lag."""
+        return self._done_ns
 
     def cancel(self, reason: str = "cancelled") -> None:
         self.ctx.cancel(reason)
@@ -78,10 +85,12 @@ class Ticket:
 
     def _fulfill(self, table) -> None:
         self._result = table
+        self._done_ns = time.perf_counter_ns()
         self._done.set()
 
     def _fail(self, exc: BaseException) -> None:
         self._error = exc
+        self._done_ns = time.perf_counter_ns()
         self._done.set()
 
 
@@ -96,6 +105,10 @@ class _FollowerTicket(Ticket):
 
     def done(self) -> bool:
         return self.ctx.cancelled() or self._primary.done()
+
+    @property
+    def done_ns(self) -> Optional[int]:
+        return self._primary.done_ns
 
     def result(self, timeout_s: Optional[float] = None):
         deadline = (None if timeout_s is None
@@ -181,12 +194,30 @@ class QueryServer:
         that already opened a trace (the network front-end propagating a
         client's TraceContext) keep the query's spans under it; None
         starts a fresh trace."""
+        from spark_rapids_tpu.obs import span as _span
+
+        trace = trace if trace is not None else _span.new_trace()
+        # under the caller's span when it belongs to this trace (the
+        # front-end's net:accept), else directly under the trace's root
+        cur = _span.current()
+        parent = (cur if cur is not None and cur.trace_id == trace.trace_id
+                  else trace)
+        with _span.span("query:submit", ctx=parent,
+                        attrs={"query": name,
+                               "tenant": tenant or _m.DEFAULT_TENANT,
+                               "priority": priority}) as sp:
+            ticket = self._submit(df, priority, deadline_ms, memory_budget,
+                                  name, tenant, trace)
+            if sp is not None:
+                sp.attrs["query"] = ticket.ctx.name  # the default, if unnamed
+            return ticket
+
+    def _submit(self, df, priority, deadline_ms, memory_budget, name,
+                tenant, trace) -> Ticket:
         from spark_rapids_tpu import faults
         from spark_rapids_tpu.obs import events as _ev
         from spark_rapids_tpu.obs import span as _span
 
-        submit_t0 = time.perf_counter_ns()
-        trace = trace if trace is not None else _span.new_trace()
         _m.bump("admission_submitted_total")
         try:
             faults.check("serve.admit", op=name or "query")
@@ -219,15 +250,12 @@ class QueryServer:
                     ctx.state = "deduped"
                     return _FollowerTicket(primary, ctx)
             # admission gates raise AdmissionRejected (counted inside)
-            admit_t0 = time.perf_counter_ns()
-            try:
-                self.admission.admit(ctx)
-            except AdmissionRejected as e:
-                _m.note_outcome(tenant, priority, f"rejected:{e.reason}")
-                raise
-            _span.record_span("query:admit", admit_t0,
-                              time.perf_counter_ns() - admit_t0, ctx=trace,
-                              attrs={"query": ctx.name})
+            with _span.span("query:admit", attrs={"query": ctx.name}):
+                try:
+                    self.admission.admit(ctx)
+                except AdmissionRejected as e:
+                    _m.note_outcome(tenant, priority, f"rejected:{e.reason}")
+                    raise
             ticket = Ticket(df, ctx, key)
             if key is not None:
                 self._inflight[key] = ticket
@@ -242,11 +270,6 @@ class QueryServer:
                                       next(_seq), ticket))
             self._cv.notify()
         _m.note_outcome(tenant, priority, "admitted")
-        _span.record_span("query:submit", submit_t0,
-                          time.perf_counter_ns() - submit_t0, ctx=trace,
-                          attrs={"query": ctx.name,
-                                 "tenant": tenant or _m.DEFAULT_TENANT,
-                                 "priority": priority})
         _ev.emit("serve-admit", query_id=ctx.ctx_id, name=ctx.name,
                  priority=ctx.priority, budget=ctx.memory_budget,
                  deadline_ms=deadline_ms, tenant=tenant)

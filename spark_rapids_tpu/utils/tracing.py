@@ -50,8 +50,10 @@ def trace_events(clear: bool = False) -> List[Dict]:
 
 
 def capturing() -> bool:
-    with _events_lock:
-        return _capture_events
+    """Whether a capture window is open. A plain read of the flag: callers
+    use it to skip building what ``record_event`` would drop, and
+    ``record_event`` itself looks again under the lock."""
+    return _capture_events
 
 
 def set_capture(enabled: bool, clear: bool = False) -> None:
@@ -67,7 +69,12 @@ def set_capture(enabled: bool, clear: bool = False) -> None:
 def record_event(name: str, start_ns: int, dur_ns: int,
                  args: Optional[Dict] = None) -> None:
     """Append one event if a capture window is open (span-shaped; the
-    Chrome exporter renders it as a 'ph: X' complete event)."""
+    Chrome exporter renders it as a 'ph: X' complete event). The flag is
+    read before the lock is taken, so with capture off this costs one
+    global read and no lock; it is read again under the lock, so a window
+    still never tears."""
+    if not _capture_events:
+        return
     with _events_lock:
         if not _capture_events:
             return
@@ -91,6 +98,8 @@ def record_counter(name: str, values: Dict,
     """Append one counter sample if a capture window is open (the Chrome
     exporter renders it as a 'ph: C' counter track — obs/memtrack.py uses
     this for memory watermark timelines)."""
+    if not _capture_events:
+        return
     with _events_lock:
         if not _capture_events:
             return
@@ -109,28 +118,45 @@ def record_counter(name: str, values: Dict,
 
 
 class TraceRange:
-    """NvtxRange analog: annotates the jax profiler timeline and (during a
-    Profiler window or when event capture is on) records an event."""
+    """NvtxRange analog and the one primitive of "event + annotation": a
+    ``jax.profiler.TraceAnnotation`` of the same name for the range's life
+    (so a running jax profiler writes it to the ``.xplane.pb`` host plane,
+    on the device trace's own clock) and, at close, one event in the
+    in-process log when a capture window is open. ``obs/span.Span`` and the
+    per-batch operator event of ``exec/base.py`` are both built on it.
+    With no profiler running and capture off a range costs the native
+    TraceMe's flag check and two clock reads: no lock."""
+
+    __slots__ = ("name", "start_ns", "_ann")
 
     def __init__(self, name: str):
         self.name = name
+        self.start_ns = 0
         self._ann = None
-        self._t0 = 0
 
-    def __enter__(self):
-        self._t0 = time.perf_counter_ns()
-        try:
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
+    def open(self) -> "TraceRange":
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
         return self
 
+    def close(self, args: Optional[Dict] = None,
+              end_ns: Optional[int] = None, record: bool = True) -> None:
+        """End the annotation and (unless ``record`` is False) log the
+        event; ``args`` may be built late, from what the range learned."""
+        end = end_ns if end_ns is not None else time.perf_counter_ns()
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if record:
+            record_event(self.name, self.start_ns,
+                         max(0, end - self.start_ns), args=args)
+
+    def __enter__(self):
+        return self.open()
+
     def __exit__(self, *exc):
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-        record_event(self.name, self._t0,
-                     time.perf_counter_ns() - self._t0)
+        self.close()
         return False
 
 

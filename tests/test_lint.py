@@ -342,6 +342,22 @@ def test_span_catalog_flags_fstring_span_name(repo_copy):
     assert any("f-string" in x for x in v)
 
 
+def test_span_catalog_flags_bare_device_get_in_exec(repo_copy):
+    """A device->host read under exec/ that goes round
+    utils/sync.host_get is a sync no exec:host-sync span or counter
+    sees; the same read through the door is clean."""
+    _append(repo_copy, "spark_rapids_tpu/exec/misc.py",
+            "\n\ndef _fixture_sync(x):\n"
+            "    import jax\n"
+            "    return jax.device_get(x)\n")
+    _append(repo_copy, "spark_rapids_tpu/exec/expand.py",
+            "\n\ndef _fixture_sync(x):\n"
+            "    from spark_rapids_tpu.utils.sync import host_get\n"
+            '    return host_get(x, "fixture")\n')
+    v = [x for x in span_catalog.run_pass(repo_copy) if "device_get" in x]
+    assert len(v) == 1 and "exec/misc.py" in v[0] and "host_get" in v[0]
+
+
 def test_cache_keys_clean_at_head():
     assert cache_keys.run_pass(REPO) == []
 

@@ -277,6 +277,116 @@ def test_remote_query_reassembles_into_one_trace():
             "query:execute"} <= names
 
 
+def _captured_request(name, partitions=2):
+    """One request over the wire with capture on: its assembled trace (the
+    one whose net:request carries ``name``) and the raw events."""
+    from spark_rapids_tpu.obs import span as sp
+    from spark_rapids_tpu.utils import tracing
+
+    with _Serving({"t": _table()}) as srv:
+        with srv.client() as cl:
+            df = _query(cl.table("t", partitions=partitions))
+            cl.submit(df, name="warm-" + name)  # compile outside the capture
+            tracing.set_capture(True, clear=True)
+            try:
+                cl.submit(df, name=name)
+                events = tracing.trace_events(clear=True)
+            finally:
+                tracing.set_capture(False)
+                tracing.trace_events(clear=True)
+    traces = sp.assemble_traces({"driver": events})
+    mine = [spans for spans in traces.values()
+            if any(s["name"] == "net:request"
+                   and s["attrs"].get("query") == name for s in spans)]
+    assert len(mine) == 1, "the request did not land in exactly one trace"
+    return mine[0], events
+
+
+def test_served_request_is_one_tree_under_a_recorded_net_request():
+    """The client's net:request is recorded and owns the id the server's
+    spans parent on: every span of the trace descends from it, and the
+    request is tiled from the client's send to the decoded table."""
+    spans, _ = _captured_request("tiled")
+    by_id = {s["span_id"]: s for s in spans}
+    roots = [s for s in spans if s["parent_id"] is None]
+    assert [r["name"] for r in roots] == ["net:request"]
+    for s in spans:
+        hops, cur = 0, s
+        while cur["parent_id"] is not None:
+            assert cur["parent_id"] in by_id, (
+                f"{cur['name']} parents on an id nothing recorded")
+            cur = by_id[cur["parent_id"]]
+            hops += 1
+            assert hops < 16
+        assert cur is roots[0]
+    names = {s["name"] for s in spans}
+    assert {"net:request", "net:client-send", "net:accept", "query:submit",
+            "query:admit", "query:queue-wait", "query:execute", "query:plan",
+            "query:readback", "query:finish", "exec:host-sync",
+            "net:wake-lag", "net:stream", "net:client-recv"} <= names
+    parent_of = {s["name"]: by_id[s["parent_id"]]["name"]
+                 for s in spans if s["parent_id"]}
+    assert parent_of["query:submit"] == "net:accept"
+    assert parent_of["query:admit"] == "query:submit"
+    assert parent_of["query:plan"] == "query:execute"
+    assert parent_of["query:finish"] == "query:execute"
+    recv = [s for s in spans if s["name"] == "net:client-recv"][0]
+    assert recv["attrs"]["rows"] > 0
+
+
+def test_every_span_lies_inside_its_parent():
+    """Real intervals: a child starts and ends within its parent. Spans of
+    one thread nest exactly; a child stamped by another thread (the
+    server's, under the client's net:request) may close a moment after
+    the parent's thread moved on, so it gets a scheduling allowance."""
+    spans, events = _captured_request("nested")
+    by_id = {s["span_id"]: s for s in spans}
+    thread_of = {e["args"]["span_id"]: e["thread"] for e in events
+                 if "span_id" in (e.get("args") or {})}
+    checked = 0
+    for s in spans:
+        if s["parent_id"] is None:
+            continue
+        p = by_id[s["parent_id"]]
+        same = thread_of[s["span_id"]] == thread_of[p["span_id"]]
+        slack = 0 if same else 5_000_000
+        assert s["start_ns"] >= p["start_ns"] - slack, (s["name"], p["name"])
+        assert (s["start_ns"] + s["dur_ns"]
+                <= p["start_ns"] + p["dur_ns"] + slack), (s["name"],
+                                                           p["name"])
+        checked += 1
+    assert checked >= 12
+
+
+def test_wake_lag_is_measured_from_the_ticket_to_await_result(monkeypatch):
+    """net:wake-lag starts where Ticket._fulfill stamped done_ns and ends
+    when _await_result returns: never negative, never past the stream
+    that follows it, and at most one poll long."""
+    from spark_rapids_tpu.net import frontend as fe_mod
+
+    returned = []
+    orig = fe_mod.QueryFrontend._await_result
+
+    def spy(self, conn, ticket):
+        try:
+            return orig(self, conn, ticket)
+        finally:
+            returned.append((ticket.done_ns, time.perf_counter_ns()))
+    monkeypatch.setattr(fe_mod.QueryFrontend, "_await_result", spy)
+    spans, _ = _captured_request("lagged")
+    [lag] = [s for s in spans if s["name"] == "net:wake-lag"]
+    [stream] = [s for s in spans if s["name"] == "net:stream"]
+    [execute] = [s for s in spans if s["name"] == "query:execute"]
+    done_ns, back_ns = returned[-1]
+    assert lag["start_ns"] == done_ns
+    assert lag["dur_ns"] >= 0
+    assert lag["start_ns"] >= execute["start_ns"] + execute["dur_ns"]
+    end = lag["start_ns"] + lag["dur_ns"]
+    assert end <= back_ns and end <= stream["start_ns"]
+    assert lag["dur_ns"] <= (fe_mod._POLL_S + 0.5) * 1e9
+    assert lag["attrs"]["query"] == "lagged"
+
+
 def test_unsupported_plan_rejected_at_the_wire():
     t = pa.table({"s": ["a", "b", "c"], "v": [1.0, 2.0, 3.0]})
     with _Serving({"t": t}) as srv:

@@ -666,6 +666,291 @@ def test_span_catalog_lint_shape():
     assert all(isinstance(n, str) and isinstance(h, str) for n, h in lit)
 
 
+# -- one span model: event + profiler annotation, real intervals -----------
+
+class _CountingLock:
+    """Stands in for a threading.Lock and counts how often it is taken."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.acquired = 0
+
+    def __enter__(self):
+        self.acquired += 1
+        return self._lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self._lock.__exit__(*exc)
+
+
+def test_span_with_capture_off_takes_no_lock_and_draws_no_ids(monkeypatch):
+    """With capture off and no profiler running, spans that stay in their
+    process cost no lock and no os.urandom; the same spans with capture on
+    take the event log's lock once each and still draw nothing."""
+    import os as _os
+    from spark_rapids_tpu.obs import span as sp
+
+    lock = _CountingLock()
+    monkeypatch.setattr(tracing, "_events_lock", lock)
+    drawn = []
+    real = _os.urandom
+    monkeypatch.setattr(sp.os, "urandom",
+                        lambda n: drawn.append(n) or real(n))
+
+    def work():
+        with sp.span("query:execute", attrs={"query": "q"}) as outer:
+            with sp.span("query:plan"):
+                sp.record_span("query:queue-wait", 0, 10)
+            with sp.task_span("query:finish"):
+                pass
+            with tracing.TraceRange("SomeExec"):
+                pass
+        return outer
+
+    tracing.set_capture(False)
+    base = lock.acquired
+    outer = work()
+    assert lock.acquired == base and drawn == []
+    # ids exist all the same, unique and ready for a wire that asks
+    assert outer.span_id and outer.trace_id != outer.span_id
+    assert sp.TraceContext.from_wire(outer.context().to_wire()).span_id \
+        == outer.span_id
+    tracing.set_capture(True, clear=True)
+    try:
+        base = lock.acquired
+        work()
+        assert lock.acquired - base == 5  # four spans and the plain range
+        assert drawn == []
+        assert len(sp.span_events(tracing.trace_events(clear=True))) == 4
+    finally:
+        tracing.set_capture(False)
+        tracing.trace_events(clear=True)
+
+
+def test_span_ids_are_unique_across_threads_and_reseeded_processes():
+    from spark_rapids_tpu.obs import span as sp
+
+    ids, lock = set(), threading.Lock()
+
+    def draw():
+        mine = [sp._new_id() for _ in range(2000)]
+        with lock:
+            ids.update(mine)
+    ts = [threading.Thread(target=draw) for _ in range(8)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(timeout=30)
+    assert len(ids) == 16000
+    prefix = sp._id_prefix
+    sp._reseed_ids()  # what a forked child runs
+    try:
+        assert sp._id_prefix != prefix
+        assert sp._new_id() not in ids
+    finally:
+        sp._reseed_ids()
+
+
+def test_jax_profiler_trace_holds_an_annotation_per_live_span(tmp_path):
+    """Every live span (and the per-batch operator range) is also a
+    jax.profiler.TraceAnnotation of the same name: a profiler trace taken
+    on the CPU carries them on its own clock, each as long as the event the
+    program logged. The two spans stamped after the fact carry none."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from spark_rapids_tpu.obs import span as sp
+    from spark_rapids_tpu.serve import QueryServer
+
+    conf = RapidsConf()
+    df = (from_arrow(sample_table(), conf, partitions=1)
+          .group_by("k").agg(Sum(col("v")).alias("s")).sort("k"))
+    srv = QueryServer(conf)
+    try:
+        srv.submit(df, name="unprofiled").result(timeout_s=120)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        tracing.set_capture(True, clear=True)
+        try:
+            with sp.span("net:request"):
+                with sp.span("net:client-send"):
+                    pass
+                srv.submit(df, name="profiled",
+                           trace=sp.current()).result(timeout_s=120)
+            events = tracing.trace_events(clear=True)
+        finally:
+            tracing.set_capture(False)
+            tracing.trace_events(clear=True)
+            jax.profiler.stop_trace()
+    finally:
+        srv.close()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    annotated = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    annotated.setdefault(e.name, []).append(e.duration_ns)
+    logged = {}
+    for e in events:
+        logged.setdefault(e["name"], []).append(e["dur_ns"])
+    stamped = {"query:queue-wait", "net:wake-lag"}
+    live = set(logged) - stamped
+    assert {"net:request", "net:client-send", "query:submit", "query:admit",
+            "query:execute", "query:plan", "query:readback", "query:finish",
+            "exec:host-sync", "SortExec"} <= live
+    assert "query:queue-wait" in logged
+    declared = {name for name, _ in sp.CATALOG}
+    for name in live:
+        got = sorted(annotated.get(name, ()), reverse=True)
+        want = sorted(logged[name], reverse=True)
+        if name in declared:
+            assert len(got) == len(want), name
+        else:
+            # an operator's last next() (StopIteration) is annotated too,
+            # and logs no event
+            assert len(got) >= len(want), name
+        # the annotation encloses the logged interval, by microseconds
+        for a, e in zip(got, want):
+            assert 0 <= a - e < 2_000_000, (name, a, e)
+    assert not stamped & set(annotated)
+
+
+def test_known_plan_yields_its_host_syncs_as_spans_and_counts():
+    """A plan whose blocking device->host reads are known by site yields
+    exactly those exec:host-sync spans under a trace, and the always-on
+    counter pair rises by the same number with tracing on or off."""
+    from spark_rapids_tpu.obs import span as sp
+
+    conf = RapidsConf()
+    df = (from_arrow(sample_table(), conf, partitions=1)
+          .group_by("k").agg(Sum(col("v")).alias("s")).sort("k"))
+    df.to_arrow()  # bind the programs
+
+    def run(traced):
+        before = gauge_snapshot()
+        tracing.set_capture(traced, clear=True)
+        try:
+            with sp.activate(sp.new_trace() if traced else None):
+                df.to_arrow()
+            events = tracing.trace_events(clear=True)
+        finally:
+            tracing.set_capture(False)
+            tracing.trace_events(clear=True)
+        after = gauge_snapshot()
+        return (events,
+                after["exec_host_sync_total"] - before["exec_host_sync_total"],
+                after["exec_host_sync_ns_total"]
+                - before["exec_host_sync_ns_total"])
+
+    def operators(n):
+        return (1 + len(getattr(n, "fused_ops", ()))
+                + sum(operators(c) for c in n.children))
+
+    n_ops = operators(df.physical_plan())  # the memo's tree: what ran
+    assert n_ops == 4  # sort, fused stage, its aggregate, batch source
+    events, rose, ns = run(traced=True)
+    syncs = [e for e in sp.span_events(events) if e["name"] == "exec:host-sync"]
+    sites = sorted(e["args"]["site"] for e in syncs)
+    # one result batch read back, and at finish one fold of the pending
+    # device row counts per operator of the plan: nothing else blocks
+    assert sites == ["batch_to_arrow"] + ["metrics.rows"] * n_ops
+    assert rose == len(syncs)
+    assert ns == sum(e["dur_ns"] for e in syncs) > 0
+    _, rose_off, ns_off = run(traced=False)
+    assert rose_off == rose and ns_off > 0
+
+
+def test_spans_stay_out_of_the_lifecycle_journal():
+    """Spans go to the event log and the profiler; the bounded journal
+    keeps its room for lifecycle events."""
+    from spark_rapids_tpu.obs import span as sp
+
+    journal.clear()
+    tracing.set_capture(True, clear=True)
+    try:
+        with sp.span("query:execute"):
+            sp.record_span("query:queue-wait", 0, 5)
+        assert len(sp.span_events(tracing.trace_events(clear=True))) == 2
+    finally:
+        tracing.set_capture(False)
+        tracing.trace_events(clear=True)
+    assert [e for e in journal.recent() if e["kind"] == "span"] == []
+
+
+def test_trace_range_is_the_one_primitive():
+    """TraceRange: args may come late, a dropped range logs nothing, and
+    Span is built on it (same start, same name)."""
+    from spark_rapids_tpu.obs import span as sp
+
+    tracing.set_capture(True, clear=True)
+    try:
+        rng = tracing.TraceRange("SomeExec").open()
+        rng.close(args={"partition": 3})
+        tracing.TraceRange("Dropped").open().close(record=False)
+        s = sp.Span("query:plan")
+        assert isinstance(s._range, tracing.TraceRange)
+        assert s.start_ns == s._range.start_ns > rng.start_ns
+        s.finish()
+        s.finish()  # inert after the first
+        events = tracing.trace_events(clear=True)
+    finally:
+        tracing.set_capture(False)
+        tracing.trace_events(clear=True)
+    assert [e["name"] for e in events] == ["SomeExec", "query:plan"]
+    assert events[0]["args"] == {"partition": 3}
+
+
+def test_span_clock_matches_each_event_to_its_nearest_annotation():
+    """tools/span_clock.py: an event's shifted start against the nearest
+    annotation of its name; a name with no annotation is missing unless it
+    is one of the two stamped spans."""
+    from tools import span_clock
+
+    annotations = {"query:plan": [1_000, 5_000, 9_000],
+                   "SortExec": [2_000]}
+    events = [{"name": "query:plan", "start_ns": 40},     # -> 1040: +(-40)
+              {"name": "query:plan", "start_ns": 8_100},  # -> 9100: -100
+              {"name": "SortExec", "start_ns": 1_500},    # -> 2500: -500
+              {"name": "net:wake-lag", "start_ns": 7},
+              {"name": "query:finish", "start_ns": 9}]
+    diffs = span_clock.match(annotations, events, shift=1_000)
+    assert diffs["query:plan"] == [-40, -100]
+    assert diffs["SortExec"] == [-500]
+    rep = span_clock.summarize(diffs)
+    assert rep["missing"] == ["query:finish"]
+    assert rep["stamped_absent"] == ["net:wake-lag"]
+    assert (rep["n"], rep["median_ns"], rep["max_abs_ns"]) == (3, -100, 500)
+    assert rep["names"]["query:plan"] == {"n": 2, "median_ns": -70.0,
+                                          "max_abs_ns": 100}
+
+
+def test_named_scopes_are_metadata_only():
+    """The kernels' jax.named_scope shows in the lowered program's debug
+    info (what an op profile groups by) and changes no op: the program
+    text without locations equals the unscoped function's."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.batch import batch_from_arrow
+    from spark_rapids_tpu.exec import kernels as K
+
+    b = batch_from_arrow(pa.table({"a": pa.array(np.arange(10), pa.int64())}))
+    keys = [jnp.arange(64, dtype=jnp.uint64), jnp.ones(64, jnp.uint64)]
+    for fn, args, static, scope in [
+            (K.concat_device, ([b, b], 2 * b.capacity, (0,)), (1, 2),
+             "jit(concat_device)/concat/"),
+            (K.lexsort_chain, (keys,), (),
+             "jit(lexsort_chain)/sort.lexsort/")]:
+        scoped = jax.jit(fn, static_argnums=static).lower(*args)
+        assert scope in scoped.as_text(debug_info=True)
+        bare = jax.jit(fn.__wrapped__, static_argnums=static).lower(*args)
+        assert scope not in bare.as_text(debug_info=True)
+        assert scoped.as_text() == bare.as_text()
+
+
 # -- labeled histogram families (per-tenant SLOs) --------------------------
 
 def test_histo_labeled_families_and_reset():

@@ -614,6 +614,111 @@ def test_submit_records_query_lifecycle_spans():
     execute = [s for s in mine[0] if s["name"] == "query:execute"][0]
     assert execute["attrs"]["tenant"] == "acme"
 
+
+def _traced_submit(srv, df, name):
+    """Submit with capture on; (the query's spans, the raw events)."""
+    from spark_rapids_tpu.obs import span as sp
+    from spark_rapids_tpu.utils import tracing
+
+    tracing.set_capture(True, clear=True)
+    try:
+        srv.submit(df, name=name).result(timeout_s=120)
+        events = tracing.trace_events(clear=True)
+    finally:
+        tracing.set_capture(False)
+        tracing.trace_events(clear=True)
+    traces = sp.assemble_traces({"driver": events})
+    mine = [spans for spans in traces.values()
+            if any(s["name"] == "query:submit"
+                   and s["attrs"].get("query") == name for s in spans)]
+    assert len(mine) == 1
+    return mine[0], events
+
+
+def _inside(child, parent):
+    return (child["start_ns"] >= parent["start_ns"]
+            and child["start_ns"] + child["dur_ns"]
+            <= parent["start_ns"] + parent["dur_ns"])
+
+
+def test_query_plan_is_a_real_interval_before_the_first_operator():
+    """query:plan is opened around physical_plan() on the executor thread:
+    it starts and ends inside query:execute, carries the request's name and
+    whether the plan memo answered, and is over before any operator runs."""
+    conf = C.RapidsConf()
+    t = _table()
+    df = (from_arrow(t, conf, partitions=2)
+          .filter(E.col("a") > E.lit(4243)).group_by("b")
+          .agg(E.Alias(E.Sum(E.col("a")), "s")).sort("b"))
+    srv = QueryServer(conf)
+    try:
+        first, _ = _traced_submit(srv, df, "plan-cold")
+        spans, events = _traced_submit(srv, df, "plan-warm")
+    finally:
+        srv.close()
+    [plan] = [s for s in spans if s["name"] == "query:plan"]
+    [execute] = [s for s in spans if s["name"] == "query:execute"]
+    assert _inside(plan, execute) and plan["dur_ns"] > 0
+    assert plan["parent_id"] == execute["span_id"]
+    assert plan["attrs"] == {"query": "plan-warm", "cache_hit": True}
+    [cold] = [s for s in first if s["name"] == "query:plan"]
+    assert cold["attrs"]["cache_hit"] is False
+    ops = [e for e in events if e["name"].endswith("Exec")
+           and e["start_ns"] >= execute["start_ns"]]
+    assert ops, "no operator event captured"
+    assert plan["start_ns"] + plan["dur_ns"] <= min(
+        e["start_ns"] for e in ops)
+
+
+def test_query_compile_span_only_where_a_program_was_first_called():
+    """The first run of a plan with a literal nothing has bound before
+    records query:compile spans, each a real interval inside query:execute
+    naming its program; the second run compiles nothing and records none."""
+    conf = C.RapidsConf()
+    t = _table()
+    df = (from_arrow(t, conf, partitions=2)
+          .filter(E.col("a") > E.lit(77123)).group_by("b")
+          .agg(E.Alias(E.Sum(E.col("a")), "s")).sort("b"))
+    srv = QueryServer(conf)
+    try:
+        cold, _ = _traced_submit(srv, df, "compile-cold")
+        warm, _ = _traced_submit(srv, df, "compile-warm")
+    finally:
+        srv.close()
+    [execute] = [s for s in cold if s["name"] == "query:execute"]
+    compiles = [s for s in cold if s["name"] == "query:compile"]
+    assert compiles, "a new program was bound but no query:compile recorded"
+    for c in compiles:
+        assert _inside(c, execute) and c["dur_ns"] > 0
+        assert c["attrs"]["program"]
+    assert [s for s in warm if s["name"] == "query:compile"] == []
+
+
+def test_readback_and_finish_tile_the_tail_of_execute():
+    """After the last operator event: query:readback (with its
+    exec:host-sync child) per result batch, then query:finish, both inside
+    query:execute and in that order."""
+    conf = C.RapidsConf()
+    [df] = _queries(conf, n=1)
+    srv = QueryServer(conf)
+    try:
+        _traced_submit(srv, df, "tail-warm")
+        spans, _ = _traced_submit(srv, df, "tail")
+    finally:
+        srv.close()
+    [execute] = [s for s in spans if s["name"] == "query:execute"]
+    [finish] = [s for s in spans if s["name"] == "query:finish"]
+    reads = [s for s in spans if s["name"] == "query:readback"]
+    assert reads and all(_inside(r, execute) for r in reads)
+    assert _inside(finish, execute)
+    assert max(r["start_ns"] + r["dur_ns"] for r in reads) \
+        <= finish["start_ns"]
+    syncs = [s for s in spans if s["name"] == "exec:host-sync"
+             and s["attrs"]["site"] == "batch_to_arrow"]
+    assert len(syncs) == len(reads)
+    assert {s["parent_id"] for s in syncs} == {r["span_id"] for r in reads}
+    assert sum(r["attrs"]["rows"] for r in reads) > 0
+
 # -- deadline-aware (EDF) scheduling + fair-share admission -----------------
 
 

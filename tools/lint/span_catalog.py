@@ -9,7 +9,13 @@ pass flags any string constant passed as the first argument (or
 ``name=`` keyword) of those calls that the CATALOG does not declare, so
 the default lane catches the mistake without executing the span site.
 Dynamic detail belongs in ``attrs``, never interpolated into the name —
-an f-string first argument is flagged outright. Pure AST, no imports.
+an f-string first argument is flagged outright.
+
+The same pass keeps the ``exec:host-sync`` span and its counter pair
+whole: a bare ``jax.device_get(...)`` under ``spark_rapids_tpu/exec/``
+goes round the one door (``utils/sync.host_get``) that records them, and
+is flagged unless its file is allowlisted as off a query's path.
+Pure AST, no imports.
 """
 
 from __future__ import annotations
@@ -22,6 +28,11 @@ from tools.lint.core import register
 
 #: the call names whose first argument is a span name
 _SPAN_FUNCS = ("Span", "span", "task_span", "record_span")
+
+#: where a device->host read has to go through utils/sync.host_get, and
+#: the files under it whose reads are off every query's path (none today)
+_SYNC_DIR = os.path.join("spark_rapids_tpu", "exec")
+_SYNC_ALLOW: tuple = ()
 
 
 def catalog_names(root: str) -> set:
@@ -52,9 +63,21 @@ def check_file(path: str, declared: set, violations: list,
         violations.append(f"{path}: not parseable: {e}")
         return
     rel = os.path.relpath(path, root) if root else path
+    guard_syncs = (rel.startswith(_SYNC_DIR + os.sep)
+                   and rel not in _SYNC_ALLOW)
 
     for node in ast.walk(tree):
         if not isinstance(node, ast.Call):
+            continue
+        if (guard_syncs and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "device_get"
+                and isinstance(node.func.value, ast.Name)
+                and node.func.value.id == "jax"):
+            violations.append(
+                f"{rel}:{node.lineno}: bare jax.device_get(...) on the "
+                f"query path — read through utils/sync.host_get(x, site) "
+                f"so the sync is an exec:host-sync span and is counted in "
+                f"exec_host_sync_total")
             continue
         fname = (node.func.id if isinstance(node.func, ast.Name)
                  else node.func.attr if isinstance(node.func, ast.Attribute)
