@@ -22,6 +22,12 @@ Failure containment, in order of blast radius:
   promises (chaos-tested in tests/test_net.py);
 - ``net.accept`` faults drop the incoming connection pre-handshake.
 
+Waiting for a result: the handler selects on the client's socket (for a
+CANCEL frame or a disconnect) and on the connection's wake channel, which
+the ticket's resolution pokes (``Ticket.add_done_callback``). The select's
+timeout is a backstop for a front-end closed under a running query; no
+request waits on it. ``net_await_wake_*_total`` count what ended each wait.
+
 Tracing: the client ships its ``TraceContext`` wire tuple in SUBMIT, the
 front-end passes it to ``QueryServer.submit(trace=...)`` and records its
 own ``net:accept`` / ``net:stream`` spans under the same trace — a remote
@@ -41,6 +47,45 @@ from spark_rapids_tpu.net import protocol as P
 from spark_rapids_tpu.net.session import Session, SessionManager, parse_tokens
 
 _POLL_S = 0.05
+# _await_result's select returns on the ticket's wake or on a frame; this
+# only bounds how long a frontend closed under a running query takes to
+# cancel it
+_AWAIT_BACKSTOP_S = 1.0
+
+
+class _WakeChannel:
+    """A connection's selectable wake-up: ``poke()`` from any thread makes
+    ``select`` on the channel return in the connection's thread. A socket
+    pair and not an ``os.eventfd``: a poke that arrives after ``close()``
+    (a cancelled follower's primary resolving later) finds a closed socket
+    object, never a descriptor number that something else now owns."""
+
+    def __init__(self):
+        self._r, self._w = socket.socketpair()
+        self._r.setblocking(False)
+        self._w.setblocking(False)
+        self._lock = threading.Lock()  # poke against close
+
+    def fileno(self) -> int:
+        return self._r.fileno()
+
+    def poke(self) -> None:
+        with self._lock:
+            try:
+                self._w.send(b"\0")
+            except OSError:
+                pass  # closed, or full of wake-ups nobody drained yet
+
+    def drain(self) -> None:
+        try:
+            self._r.recv(4096)
+        except BlockingIOError:
+            pass
+
+    def close(self) -> None:
+        with self._lock:
+            self._r.close()
+            self._w.close()
 
 
 class QueryFrontend:
@@ -120,10 +165,13 @@ class QueryFrontend:
     def _serve_conn(self, conn: socket.socket) -> None:
         fileno = conn.fileno()
         session: Optional[Session] = None
+        wake: Optional[_WakeChannel] = None
         try:
+            # one per connection, not per request: a request pays no socket()
+            wake = _WakeChannel()
             session = self._handshake(conn)
             if session is not None:
-                self._serve_session(conn, session)
+                self._serve_session(conn, wake, session)
         except (P.ConnectionClosed, BrokenPipeError, ConnectionError,
                 OSError):
             pass  # peer gone; nothing left to tell it
@@ -135,6 +183,8 @@ class QueryFrontend:
         finally:
             if session is not None:
                 self.sessions.drop(session)
+            if wake is not None:
+                wake.close()
             try:
                 conn.close()
             except OSError:
@@ -193,7 +243,8 @@ class QueryFrontend:
             "session_id": session.session_id, "tenant": session.tenant}))
         return session
 
-    def _serve_session(self, conn, session: Session) -> None:
+    def _serve_session(self, conn, wake: _WakeChannel,
+                       session: Session) -> None:
         while not self._closing and not session.closed:
             ready, _, _ = select.select([conn], [], [], _POLL_S)
             if session.closed or self._closing:
@@ -203,7 +254,7 @@ class QueryFrontend:
             ftype, payload = self._recv(conn)
             session.touch()
             if ftype == P.SUBMIT:
-                self._handle_submit(conn, session, payload)
+                self._handle_submit(conn, wake, session, payload)
             elif ftype == P.CANCEL:
                 # no query in flight at this point; ack idempotently
                 _m.bump("net_cancel_total")
@@ -213,7 +264,8 @@ class QueryFrontend:
                     f"unexpected {P.TYPE_NAMES.get(ftype, ftype)} frame")
 
     # -- submit + result streaming ----------------------------------------
-    def _handle_submit(self, conn, session: Session, payload: bytes) -> None:
+    def _handle_submit(self, conn, wake: _WakeChannel, session: Session,
+                       payload: bytes) -> None:
         from spark_rapids_tpu.obs import span as _span
         from spark_rapids_tpu.serve import AdmissionRejected
 
@@ -246,7 +298,7 @@ class QueryFrontend:
             if sp is not None:
                 sp.finish()
         session.queries += 1
-        self._await_and_stream(conn, session, ticket)
+        self._await_and_stream(conn, wake, session, ticket)
 
     def _admit(self, doc, session: Session, name, trace):
         """Resolve the plan against the catalog, gate it, hand it to the
@@ -279,18 +331,28 @@ class QueryFrontend:
             memory_budget=doc.get("memory_budget"),
             name=name, tenant=session.tenant, trace=trace)
 
-    def _await_result(self, conn, ticket):
+    def _await_result(self, conn, wake: _WakeChannel, ticket):
         """Block until the ticket resolves, servicing CANCEL frames and
         cancelling on client disconnect. Returns the result table or
-        raises the query's typed failure."""
+        raises the query's typed failure. The ticket's resolution wakes
+        the select through ``wake``; a byte left there by an earlier
+        request only costs one more turn of the loop."""
         from spark_rapids_tpu.obs import span as _span
+        ticket.add_done_callback(wake.poke)
         try:
             while not ticket.done():
-                ready, _, _ = select.select([conn], [], [], _POLL_S)
+                ready, _, _ = select.select([conn, wake], [], [],
+                                            _AWAIT_BACKSTOP_S)
                 if self._closing:
                     ticket.cancel("frontend shutdown")
-                if not ready:
+                if wake in ready:
+                    _m.bump("net_await_wake_ticket_total")
+                    wake.drain()
                     continue
+                if not ready:
+                    _m.bump("net_await_wake_timeout_total")
+                    continue
+                _m.bump("net_await_wake_frame_total")
                 try:
                     ftype, _payload = self._recv(conn)
                 except (P.ConnectionClosed, ConnectionError, OSError):
@@ -306,7 +368,7 @@ class QueryFrontend:
                         f"frame while a query is in flight")
         finally:
             # how long the resolved ticket sat before this loop saw it: the
-            # poll's share of the request, measured and not inferred
+            # hand-off's share of the request, measured and not inferred
             done_ns = ticket.done_ns
             if done_ns is not None:
                 _span.record_span(
@@ -315,13 +377,14 @@ class QueryFrontend:
                     attrs={"query": ticket.ctx.name})
         return ticket.result()
 
-    def _await_and_stream(self, conn, session: Session, ticket) -> None:
+    def _await_and_stream(self, conn, wake: _WakeChannel, session: Session,
+                          ticket) -> None:
         from spark_rapids_tpu.obs import histo as _h
         from spark_rapids_tpu.obs import span as _span
         from spark_rapids_tpu.serve import (QueryCancelled,
                                             QueryDeadlineExceeded)
         try:
-            table = self._await_result(conn, ticket)
+            table = self._await_result(conn, wake, ticket)
         except QueryDeadlineExceeded as e:
             self._try_error(conn, "deadline", str(e))
             return

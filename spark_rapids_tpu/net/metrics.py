@@ -25,6 +25,9 @@ _COUNTERS: Dict[str, int] = {
     "net_submit_total": 0,
     "net_submit_rejected_total": 0,
     "net_cancel_total": 0,
+    "net_await_wake_ticket_total": 0,
+    "net_await_wake_frame_total": 0,
+    "net_await_wake_timeout_total": 0,
     "net_stream_batches_total": 0,
     "net_protocol_error_total": 0,
     "net_disconnect_cancel_total": 0,

@@ -242,6 +242,15 @@ CATALOG: "List[Tuple[str, str, str]]" = [
      "Wire submissions answered with a typed ERROR before execution"),
     ("net_cancel_total", "counter",
      "CANCEL frames honored by the front-end"),
+    ("net_await_wake_ticket_total", "counter",
+     "Waits of _await_result ended by a ticket's resolution (the wake "
+     "channel): in a healthy run, one per submission that was admitted"),
+    ("net_await_wake_frame_total", "counter",
+     "Waits of _await_result ended by the client's socket: a CANCEL "
+     "frame or a disconnect while the query was in flight"),
+    ("net_await_wake_timeout_total", "counter",
+     "Waits of _await_result ended by the backstop timeout; rising with "
+     "the request count means the wake was lost and the poll is back"),
     ("net_stream_batches_total", "counter",
      "Arrow IPC record batches streamed to clients"),
     ("net_protocol_error_total", "counter",

@@ -83,9 +83,10 @@ CATALOG: "List[Tuple[str, str]]" = [
      "received through the decoded pa.Table (attrs: rows)"),
     ("net:accept", "Wire SUBMIT intake: decode + table resolve + lowering "
      "gate + QueryServer.submit"),
-    ("net:wake-lag", "Ticket resolved -> the front-end's _await_result "
-     "noticed; stamped afterwards (its start is in the past), so it has no "
-     "profiler annotation"),
+    ("net:wake-lag", "Ticket resolved -> the front-end's _await_result, "
+     "woken through the connection's wake channel, returned: the hand-off "
+     "between two threads; stamped afterwards (its start is in the past), "
+     "so it has no profiler annotation"),
     ("net:stream", "Result streaming window: Arrow IPC batches over the "
      "wire, RESULT_START through RESULT_END"),
 ]

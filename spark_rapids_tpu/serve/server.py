@@ -31,9 +31,10 @@ from __future__ import annotations
 
 import heapq
 import itertools
+import logging
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from spark_rapids_tpu.serve import admission as _adm
 from spark_rapids_tpu.serve import context as _ctx
@@ -62,6 +63,10 @@ class Ticket:
         self._error: Optional[BaseException] = None
         self.enqueued_ns = time.perf_counter_ns()
         self._done_ns: Optional[int] = None
+        # guards the hand-over of _callbacks at resolution, so a callback
+        # added while the ticket resolves runs exactly once
+        self._cb_lock = threading.Lock()
+        self._callbacks: List[Callable[[], None]] = []
 
     def done(self) -> bool:
         return self._done.is_set()
@@ -83,15 +88,43 @@ class Ticket:
             raise self._error
         return self._result
 
+    def add_done_callback(self, fn: Callable[[], None]) -> None:
+        """Call ``fn()`` once when the query resolves (either way), on the
+        resolving thread, after ``done_ns`` is stamped and ``done()`` is
+        true; at once, on this thread, when it already has. What lets a
+        waiter that cannot block on the event (the wire front-end, which
+        must keep reading its socket) be woken by the resolution."""
+        with self._cb_lock:
+            if not self._done.is_set():
+                self._callbacks.append(fn)
+                return
+        _run_done_callback(fn)
+
     def _fulfill(self, table) -> None:
         self._result = table
-        self._done_ns = time.perf_counter_ns()
-        self._done.set()
+        self._resolve()
 
     def _fail(self, exc: BaseException) -> None:
         self._error = exc
+        self._resolve()
+
+    def _resolve(self) -> None:
         self._done_ns = time.perf_counter_ns()
-        self._done.set()
+        with self._cb_lock:
+            self._done.set()
+            callbacks, self._callbacks = self._callbacks, []
+        for fn in callbacks:
+            _run_done_callback(fn)
+
+
+def _run_done_callback(fn: Callable[[], None]) -> None:
+    # the outcome is already stored: a waiter's broken callback must not
+    # reach the executor thread, which still owes the query its release
+    try:
+        fn()
+    except Exception:  # noqa: BLE001 — the callback is the waiter's code
+        logging.getLogger(__name__).exception(
+            "ticket done-callback %r raised", fn)
 
 
 class _FollowerTicket(Ticket):
@@ -109,6 +142,12 @@ class _FollowerTicket(Ticket):
     @property
     def done_ns(self) -> Optional[int]:
         return self._primary.done_ns
+
+    def add_done_callback(self, fn: Callable[[], None]) -> None:
+        # the primary's resolution is the one event a follower's waiter
+        # cannot see for itself; the follower's own cancellation is made
+        # by that waiter, which re-checks done() after it
+        self._primary.add_done_callback(fn)
 
     def result(self, timeout_s: Optional[float] = None):
         deadline = (None if timeout_s is None
@@ -300,6 +339,7 @@ class QueryServer:
                           ctx=ctx.trace, attrs={"query": ctx.name})
         _m.bump("sched_active_queries")
         ctx.state = "running"
+        out = error = None
         try:
             ctx.check()  # cancelled/deadlined while queued: never start
             with _ctx.activate(ctx), _span.activate(ctx.trace):
@@ -315,26 +355,32 @@ class QueryServer:
             if slack_ms is not None:
                 _m.observe_deadline_slack(ctx.tenant, ctx.priority,
                                           int(slack_ms * 1e6))
-            ticket._fulfill(out)
         except QueryDeadlineExceeded as e:
             ctx.state = "deadline"
             _m.bump("sched_deadline_exceeded_total")
             _m.note_outcome(ctx.tenant, ctx.priority, "deadline")
             _m.observe_deadline_slack(ctx.tenant, ctx.priority, 0)
-            ticket._fail(e)
+            error = e
         except QueryCancelled as e:
             ctx.state = "cancelled"
             _m.bump("sched_cancelled_total")
             _m.note_outcome(ctx.tenant, ctx.priority, "cancelled")
-            ticket._fail(e)
+            error = e
         except BaseException as e:  # noqa: BLE001 — must reach the caller
             ctx.state = "failed"
             _m.bump("sched_failed_total")
             _m.note_outcome(ctx.tenant, ctx.priority, "failed")
-            ticket._fail(e)
+            error = e
         finally:
             _m.bump("sched_active_queries", -1)
             self.admission.release(ctx)
+            # resolved only now: a waiter woken by the ticket may submit
+            # its next query at once, and that one must not be shed for
+            # memory against this one's reservation
+            if error is not None:
+                ticket._fail(error)
+            else:
+                ticket._fulfill(out)
             if ticket.key is not None:
                 with self._lock:
                     if self._inflight.get(ticket.key) is ticket:

@@ -188,6 +188,133 @@ def test_close_cancels_pending_typed():
     assert ei.value.reason == "shutdown"
 
 
+# -- Ticket.add_done_callback ------------------------------------------------
+
+
+def _bare_ticket():
+    from spark_rapids_tpu.serve.server import Ticket
+    return Ticket(None, QueryContext(name="bare"), None)
+
+
+@pytest.mark.parametrize("how", ["fulfill", "fail"])
+def test_done_callback_runs_once_after_done_ns_is_set(how):
+    """The callback sees a ticket that is done, stamped and readable, and
+    runs exactly once however often the ticket is looked at later."""
+    tk = _bare_ticket()
+    seen = []
+    tk.add_done_callback(
+        lambda: seen.append((tk.done(), tk.done_ns, time.perf_counter_ns())))
+    assert seen == [] and tk.done_ns is None
+    if how == "fulfill":
+        tk._fulfill(pa.table({"x": [1]}))
+        assert tk.result(1).num_rows == 1
+    else:
+        tk._fail(QueryCancelled("bare cancelled"))
+        with pytest.raises(QueryCancelled):
+            tk.result(1)
+    assert len(seen) == 1
+    done, done_ns, at_ns = seen[0]
+    assert done and done_ns is not None and done_ns <= at_ns
+    assert done_ns == tk.done_ns
+
+
+def test_done_callback_on_a_done_ticket_runs_at_once():
+    tk = _bare_ticket()
+    tk._fulfill(pa.table({"x": [1]}))
+    seen = []
+    tk.add_done_callback(lambda: seen.append(threading.get_ident()))
+    assert seen == [threading.get_ident()]
+
+
+def test_done_callback_that_raises_does_not_lose_the_result(caplog):
+    tk = _bare_ticket()
+    after = []
+
+    def broken():
+        raise RuntimeError("waiter's bug")
+
+    tk.add_done_callback(broken)
+    tk.add_done_callback(lambda: after.append(1))
+    tk._fulfill(pa.table({"x": [1, 2]}))
+    assert tk.result(1).num_rows == 2
+    assert after == [1], "a broken callback starved the one behind it"
+    assert "waiter's bug" in caplog.text
+    tk.add_done_callback(broken)  # at once on a done ticket: still contained
+    assert tk.result(1).num_rows == 2
+
+
+def test_done_callback_racing_the_resolution_runs_exactly_once():
+    """add_done_callback against _fulfill from another thread, many times
+    under a short switch interval: never lost, never twice."""
+    import sys
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        deadline = time.monotonic() + 20
+        for i in range(400):
+            assert time.monotonic() < deadline
+            tk = _bare_ticket()
+            hits = []
+            go = threading.Barrier(3)
+
+            def resolve():
+                go.wait(5)
+                tk._fulfill(i)
+
+            def add():
+                go.wait(5)
+                tk.add_done_callback(lambda: hits.append(1))
+
+            threads = [threading.Thread(target=resolve),
+                       threading.Thread(target=add)]
+            for th in threads:
+                th.start()
+            go.wait(5)
+            for th in threads:
+                th.join(5)
+                assert not th.is_alive()
+            assert hits == [1], f"round {i}: callback ran {len(hits)} times"
+    finally:
+        sys.setswitchinterval(old)
+
+
+def test_follower_ticket_forwards_its_callback_to_the_primary():
+    from spark_rapids_tpu.serve.server import _FollowerTicket
+    primary = _bare_ticket()
+    follower = _FollowerTicket(primary, QueryContext(name="follower"))
+    seen = []
+    follower.add_done_callback(lambda: seen.append(follower.done()))
+    assert seen == []
+    primary._fulfill(pa.table({"x": [1]}))
+    assert seen == [True]
+    assert follower.result(1).num_rows == 1
+
+
+def test_ticket_resolves_after_the_reservation_is_released():
+    """A waiter woken by the ticket submits its next query at once: by then
+    the finished query's reservation and active slot are gone, so a budget
+    that fits the server only once is never shed against itself."""
+    conf = C.RapidsConf({C.SERVE_SINGLEFLIGHT.key: False})
+    srv = QueryServer(conf, max_concurrent=1)
+    try:
+        budget = srv.admission.reservable_bytes * 3 // 4
+        seen = []
+        for i in range(20):
+            tk = srv.submit(_RecordingDF(i, []), name=f"loop-{i}",
+                            memory_budget=budget)
+            tk.add_done_callback(lambda: seen.append(
+                srv.admission.snapshot()["reserved_bytes"]))
+            tk.result(30)  # returns the moment the event is set
+        # callbacks run on the resolving thread before it moves on; the
+        # last one may still be running when result() returns
+        deadline = time.monotonic() + 5
+        while len(seen) < 20 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert seen == [0] * 20
+    finally:
+        srv.close()
+
+
 # -- admission --------------------------------------------------------------
 
 

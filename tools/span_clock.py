@@ -21,7 +21,9 @@ program's spans against the run's own ``.xplane.pb``:
     python3 tools/span_clock.py --workload sf1_q1_agg1 --seed 7 --seconds 30
 
 The last line of standard output is one JSON object: ``clock``, ``tiles``,
-``sync_sites`` and the run's ordinary ``result``. Needs the chip, as benchmark/run.py
+``sync_sites``, ``await_wakes`` (the front-end's submit counters and, on a
+program that has them, its counters of what ended each wait for a result,
+over the whole run) and the run's ordinary ``result``. Needs the chip, as benchmark/run.py
 does (``--rehearse-sf`` runs every step on the CPU and prints no device
 number). It wraps one private function of the harness to see the offset
 and the spans, and edits nothing there."""
@@ -194,6 +196,10 @@ def main() -> int:
     report = {"clock": summarize(match(annotations, inside, shift)),
               "tiles": tiles(seen["spans"], seen["requests"]),
               "sync_sites": sync_sites(seen["spans"], seen["requests"])}
+    from spark_rapids_tpu.net import metrics as net_metrics
+    report["await_wakes"] = {
+        k: v for k, v in net_metrics.counters().items()
+        if k.startswith(("net_await_wake_", "net_submit_"))}
     if rehearsal:  # no device number from a rehearsal
         out = {"rehearsal": True, "correct": out["correct"],
                "metrics_read": sorted(out["metrics"])}
