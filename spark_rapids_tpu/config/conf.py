@@ -487,7 +487,12 @@ FUSION_AGG_WINDOW = conf(
     doc="Number of input batches one fused streaming-aggregate dispatch "
         "consumes (chain+first-pass per batch, then a single carry+firsts "
         "concat/merge). 7 keeps the merge concat 8-wide, matching the "
-        "classic operator's tuned cascade width.")
+        "classic operator's tuned cascade width. A window holds batches "
+        "of one capacity and is a program of that many unrolled bodies: a "
+        "partition of equal batches binds one such program, plus one for "
+        "a shorter last window and one for a last batch in a smaller "
+        "capacity bucket. Capacities that interleave cost a dispatch per "
+        "run of equal capacity.")
 
 SHRINK_TO_LIVE_ENABLED = conf(
     "spark.rapids.tpu.sql.batch.shrinkToLive.enabled", default=True,
@@ -822,8 +827,8 @@ AUTOTUNE_ENABLED = conf(
     "spark.rapids.tpu.autotune.enabled", default=True,
     doc="Measurement-driven dispatch: persist per-(op, shape-class) "
         "operator timings harvested from query profiles and consult them "
-        "when picking join paths (dense/bucketed/ht/sorted), the fused agg "
-        "batch window, and CBO cost constants. Never a correctness "
+        "when picking join paths (dense/bucketed/ht/sorted) "
+        "and CBO cost constants. Never a correctness "
         "dependency — with no sample the static defaults apply, and "
         "candidate paths are restricted to bit-identical alternatives "
         "(plan/autotune.py, docs/adaptive_dispatch.md).")
@@ -1035,6 +1040,38 @@ NET_STREAM_BATCH_ROWS = conf(
         "server finer-grained backpressure (each batch frame is one "
         "blocking send); larger batches amortize framing overhead.",
     check=lambda v: None if v >= 1 else "must be >= 1")
+
+
+# ---------------------------------------------------------------------------
+# What a deployment's configuration may depend on (a guard, not a knob)
+# ---------------------------------------------------------------------------
+
+#: properties of this build that a configuration can name in
+#: spark.rapids.tpu.requires; each is always on and changes nothing when named
+CAPABILITIES = {
+    "agg.boundedStepPrograms":
+        "a streaming aggregate binds a step program per (batch capacity, "
+        "window length), at most three for a partition of equal batches "
+        "whatever their number, each compiled once (exec/fused.py, "
+        "exec/jit_persist.py; docs/fusion.md)",
+}
+
+
+def _check_requires(v: str) -> Optional[str]:
+    lacking = [c for c in (s.strip() for s in v.split(",")) if c
+               and c not in CAPABILITIES]
+    return f"this build lacks {lacking}" if lacking else None
+
+
+REQUIRES = conf(
+    "spark.rapids.tpu.requires", default="",
+    doc="Comma-separated capabilities the deployment depends on; a build "
+        "that lacks one refuses the configuration (RapidsConf raises) "
+        "instead of running it without. Naming a capability changes no "
+        "behaviour. Known: agg.boundedStepPrograms (step programs of a "
+        "streaming aggregate are bounded per partition and compile once, "
+        "so a large resident table's first start fits a deadline).",
+    check=_check_requires)
 
 
 _ACTIVE: "Optional[RapidsConf]" = None

@@ -340,16 +340,6 @@ class HashAggregateExec(UnaryExec):
                 fields.append(T.Field(f"{s.name}#b{bi}", bt, True))
         return T.Schema(fields)
 
-    def window_tunable(self) -> bool:
-        """Whether the fused streaming window may vary for this aggregate
-        (plan/autotune.py): float/double buffers make merge grouping
-        observable through summation order, everything else (int/long/
-        decimal sums, min/max, counts) merges exactly, so window size
-        only moves the throughput/overflow trade-off, never the result."""
-        self._prepare()
-        return all(f.dtype not in (T.FLOAT, T.DOUBLE)
-                   for f in self._buffer_schema())
-
     @property
     def output_schema(self) -> T.Schema:
         self._prepare()

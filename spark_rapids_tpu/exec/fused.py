@@ -20,9 +20,12 @@ Plan-time pass (``fuse_exec``, called from plan/overrides.py behind
   the general sorted-hash path needs a per-batch host sync and bails to
   the unfused fallback, see HashJoinExec.fused_probe);
 - a terminal partial/complete hash aggregate, absorbed in STREAMING form:
-  per batch one dispatch runs chain -> first_pass -> concat(carry, first)
-  -> merge_pass -> truncate-to-carry-capacity, which also deletes the
-  end-of-partition concat/merge cascade the classic operator pays.
+  per window of batches one dispatch runs chain -> first_pass per batch
+  -> concat(carry, firsts) -> merge_pass -> truncate-to-carry-capacity,
+  which also deletes the end-of-partition concat/merge cascade the classic
+  operator pays. A window holds batches of one capacity, so a step
+  program is keyed by (capacity, window length) (``_StepRunner``): a
+  partition of equal batches with a short last one binds at most three.
 
 into a single ``TpuFusedStageExec`` whose per-batch body is one shared_jit
 program. Operators that don't implement the protocol are fusion BARRIERS
@@ -64,6 +67,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch, bucket_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.base import TpuExec, UnaryExec
 from spark_rapids_tpu.exec.jit_cache import shared_jit
+from spark_rapids_tpu.obs import span as _span
 from spark_rapids_tpu.utils.sync import host_get
 
 
@@ -92,7 +96,7 @@ def _truncate_buffers(merged: ColumnarBatch, newcap: int,
     garbage never escapes.
     """
     over = merged.num_rows > newcap
-    nkeep = jnp.clip(merged.num_rows, 0, newcap)
+    nkeep = jnp.clip(merged.num_rows, 0, newcap).astype(jnp.int32)
     cols: List[DeviceColumn] = []
     for c, bc in zip(merged.columns, bc_targets):
         if c.offsets is not None:
@@ -142,28 +146,52 @@ def _make_body(fns):
     return body
 
 
+def _as_carry(first: ColumnarBatch) -> ColumnarBatch:
+    """A first-pass result in the form every step returns its carry in:
+    dictionary-coded keys decoded (the step's concat decodes them) and
+    string buffers fitted to the carry's byte capacities. The seed hands
+    the steps this form so that a step program sees ONE carry signature:
+    given the raw first pass, the first window's step is a second program
+    of the same bodies, compiled and loaded beside the one every later
+    window runs."""
+    from spark_rapids_tpu.exec import kernels as K
+    plain = K.ensure_plain_batch(first)
+    # a batch's own groups always fit its own capacities: no overflow here
+    return _truncate_buffers(plain, first.capacity,
+                             _carry_byte_targets(first))[0]
+
+
+def _carry_shape(carry: ColumnarBatch) -> Tuple[int, Tuple[int, ...]]:
+    """(row capacity, per-column byte capacities) of the seed's carry:
+    every step truncates its merged buffers back to exactly this."""
+    return carry.capacity, tuple(
+        c.byte_capacity if c.offsets is not None else 0
+        for c in carry.columns)
+
+
 def _make_seed(fns, agg):
     body = _make_body(fns)
 
     def seed(batch, consts):
         out, counts = body(batch, consts)
-        return agg._first_pass(out), counts
+        return _as_carry(agg._first_pass(out)), counts
     return seed
 
 
 def _make_step(fns, agg, carry_cap: int, bc_targets: Tuple[int, ...]):
-    """Streaming-aggregate step over a WINDOW of batches: one dispatch runs
-    chain -> first_pass for every batch in the window, then a single
-    (carry + firsts) concat/merge — the fused analog of the classic
-    operator's 8-way merge cascade, without the per-batch first-pass
-    dispatches or the end-of-partition cascade."""
+    """Streaming-aggregate step over a WINDOW of equal-capacity batches:
+    one dispatch runs chain -> first_pass for every batch in the window
+    (unrolled: one body per batch), then a single (carry + firsts)
+    concat/merge — the fused analog of the classic operator's 8-way merge
+    cascade, without the per-batch first-pass dispatches or the
+    end-of-partition cascade."""
     from spark_rapids_tpu.exec.aggregate import concat_jit
-    bodies = [_make_body(f) for f in fns]  # one per window slot (its cap)
+    body = _make_body(fns)
 
     def step(carry, batches, consts):
         firsts = []
         counts_all = []
-        for body, batch in zip(bodies, batches):
+        for batch in batches:
             out, counts = body(batch, consts)
             firsts.append(agg._first_pass(out))
             counts_all.append(counts)
@@ -176,6 +204,14 @@ def _make_step(fns, agg, carry_cap: int, bc_targets: Tuple[int, ...]):
             carry2, over = _truncate_buffers(merged, carry_cap, bc_targets)
         return carry2, over, tuple(counts_all)
     return step
+
+
+_STEP_KEYS: set = set()  # streaming-step program keys bound in this process
+
+
+def counters() -> dict:
+    """For obs/gauges.snapshot()."""
+    return {"fused_step_programs_total": len(_STEP_KEYS)}
 
 
 # ---------------------------------------------------------------------------
@@ -327,112 +363,109 @@ class TpuFusedStageExec(UnaryExec):
             yield out
 
     def _execute_agg(self, partition: int, segs):
-        import time as _time
-        from spark_rapids_tpu.plan import autotune as AT
         agg = self.agg
         agg._prepare()
         consts = tuple(seg.consts for seg in segs)
         akey = ("streaming",) + agg._base_key
         carry = None
-        carry_cap = 0
-        bc_targets = ()
-        flags = []
-        runs = {}
-        n_batches = 0
-        t0 = _time.perf_counter_ns()
-        rows_in = 0
-        shape = None
         it = self.child.execute(partition)
         # seed: the first batch's first-pass output defines the carry's
         # static capacity (its bucket bounds the groups a partition may
         # hold fused — more groups trip the overflow flag -> fallback)
         for batch in it:
-            n_batches += 1
             cap = batch.capacity
-            rows_in += cap
-            shape = AT.shape_class(
-                cap, len(agg.group_exprs),
-                AT.family_of(str(b.dtype) for b in agg._group_bound))
             key = self._stage_key(segs, cap) + akey + ("seed",)
             fns = self._chain_fns(segs, cap)
             run = shared_jit(key, lambda: _make_seed(fns, agg))
-            carry, counts = run(batch, consts)
-            carry_cap = carry.capacity
-            bc_targets = _carry_byte_targets(carry)
+            with _span.task_span("exec:agg-step",
+                                 attrs={"batches": 1, "rows": cap}):
+                carry, counts = run(batch, consts)
             self.metrics["numFusedBatches"].add(1)
             agg.metrics["numAggBatches"].add(1)
             self._attribute(segs, counts)
             break
-        if n_batches == 0:
+        if carry is None:
             yield from self._fall_back(partition)
             return
-        # window size: measured carry-overflow/throughput trade-off per
-        # shape-class when the aggregate merges exactly (no float buffers
-        # — window size then never changes the result, an overflowing
-        # window just re-runs unfused); static agg_window otherwise
-        window_n, source = self.agg_window, "default"
-        if agg.window_tunable():
-            cands = tuple(dict.fromkeys((str(self.agg_window), "3", "15")))
-            pick, source = AT.choose("aggwin", shape, str(self.agg_window),
-                                     cands)
-            try:
-                window_n = max(1, int(pick))
-            except ValueError:
-                window_n = self.agg_window
-        # steps: windows of up to window_n batches, ONE dispatch each —
-        # chain+first_pass per batch then a single (carry+firsts)
-        # concat/merge (the classic operator pays a dispatch per batch
-        # plus an end-of-partition 8-way cascade)
+        # steps: windows of up to agg_window batches of ONE capacity, one
+        # dispatch each — chain+first_pass per batch then a single
+        # (carry+firsts) concat/merge (the classic operator pays a dispatch
+        # per batch plus an end-of-partition 8-way cascade). The window is
+        # the conf's and not tuned at run time: every length merges to the
+        # same buffers, and each length is a program of that many unrolled
+        # bodies to compile
+        step = _StepRunner(self, segs, agg, consts, akey, carry)
         window: List[ColumnarBatch] = []
         for batch in it:
-            n_batches += 1
-            rows_in += batch.capacity
+            if window and batch.capacity != window[0].capacity:
+                step.run(window)
+                window = []
             window.append(batch)
-            if len(window) < window_n:
-                continue
-            carry, flags, counts_all = self._run_step(
-                segs, agg, consts, akey, carry, carry_cap, bc_targets,
-                window, runs, flags)
-            window = []
+            if len(window) == self.agg_window:
+                step.run(window)
+                window = []
         if window:
-            carry, flags, counts_all = self._run_step(
-                segs, agg, consts, akey, carry, carry_cap, bc_targets,
-                window, runs, flags)
+            step.run(window)
         # ONE host sync per partition resolves every overflow flag; on
         # overflow the carry holds truncated garbage -> re-run unfused
-        if flags and any(bool(v) for v in
-                         host_get(flags, "fused.overflow_flags")):
+        if step.flags and any(bool(v) for v in
+                              host_get(step.flags, "fused.overflow_flags")):
             yield from self._fall_back(partition)
-            AT.record_decision(self, "aggwin", str(window_n), source, shape,
-                               ns=_time.perf_counter_ns() - t0, rows=rows_in)
             return
+        carry = step.carry
         out = carry if agg.mode == "partial" else agg._final_project_fn(carry)
         agg.metrics["numOutputBatches"].add(1)
         agg._pending_rows.append(out.num_rows)
         yield out
-        AT.record_decision(self, "aggwin", str(window_n), source, shape,
-                           ns=_time.perf_counter_ns() - t0, rows=rows_in)
 
-    def _run_step(self, segs, agg, consts, akey, carry, carry_cap,
-                  bc_targets, window, runs, flags):
-        caps = tuple(b.capacity for b in window)
-        run = runs.get(caps)
+
+class _StepRunner:
+    """The streaming aggregate's window dispatches over one partition: the
+    carry, the overflow flags, and the step program of each (batch
+    capacity, window length) met.
+
+    A partition of N equal batches with a short last one binds at most
+    three step programs whatever N: the full window's, one for a tail of
+    full batches, one for the short batch if it falls into a smaller
+    capacity bucket. Capacities that interleave cost a dispatch per run
+    of equal capacity, and still one program per (capacity, length)."""
+
+    def __init__(self, stage, segs, agg, consts, akey, carry):
+        self.stage, self.segs, self.agg = stage, segs, agg
+        self.consts, self.akey = consts, akey
+        self.carry = carry
+        self.carry_cap, self.bc_targets = _carry_shape(carry)
+        self.flags: list = []
+        self._runs: dict = {}  # (capacity, window length) -> program
+
+    def _program(self, cap: int, length: int):
+        run = self._runs.get((cap, length))
         if run is None:
-            # join-probe byte bounds are capacity-dependent: each window
-            # slot gets the chain closures for ITS batch capacity
-            fns = [self._chain_fns(segs, c) for c in caps]
-            key = (akey + ("step", carry_cap, bc_targets)
-                   + tuple(self._stage_key(segs, c) for c in caps))
-            run = shared_jit(
-                key, lambda: _make_step(fns, agg, carry_cap, bc_targets))
-            runs[caps] = run
-        carry, over, counts_all = run(carry, tuple(window), consts)
-        flags = flags + [over]
-        self.metrics["numFusedBatches"].add(len(window))
-        agg.metrics["numAggBatches"].add(len(window))
+            stage, segs, agg = self.stage, self.segs, self.agg
+            # join-probe byte bounds are capacity-dependent: the chain
+            # closures are those of the window's batch capacity
+            fns = stage._chain_fns(segs, cap)
+            key = (self.akey + ("step", self.carry_cap, self.bc_targets,
+                                cap, length) + stage._stage_key(segs, cap))
+            carry_cap, bc_targets = self.carry_cap, self.bc_targets
+            run = shared_jit(key, lambda: _make_step(
+                fns, agg, carry_cap, bc_targets))
+            _STEP_KEYS.add(key)
+            self._runs[(cap, length)] = run
+        return run
+
+    def run(self, window: List[ColumnarBatch]) -> None:
+        stage, agg = self.stage, self.agg
+        cap, n = window[0].capacity, len(window)
+        with _span.task_span("exec:agg-step",
+                             attrs={"batches": n, "rows": n * cap}):
+            self.carry, over, counts_all = self._program(cap, n)(
+                self.carry, tuple(window), self.consts)
+        self.flags.append(over)
+        stage.metrics["numFusedBatches"].add(n)
+        agg.metrics["numAggBatches"].add(n)
         for counts in counts_all:
-            self._attribute(segs, counts)
-        return carry, flags, counts_all
+            stage._attribute(self.segs, counts)
 
 
 # ---------------------------------------------------------------------------

@@ -178,15 +178,24 @@ def _load(dir_: str, digest: str):
         return None
 
 
-def _store(dir_: str, digest: str, jfn: Callable, args, kwargs) -> None:
-    """Export the traced program for the given call signature and write it
-    atomically (tmp + rename: concurrent processes race benignly to the
-    same content)."""
+def _export(jfn: Callable, args, kwargs) -> Optional[bytes]:
+    """The traced program for the given call signature as a serialized
+    ``jax.export`` blob, or None (counted) where it cannot be exported."""
     from jax import export as jexport
     try:
         _ensure_registrations()
-        exported = jexport.export(jfn)(*args, **kwargs)
-        blob = exported.serialize()
+        return bytes(jexport.export(jfn)(*args, **kwargs).serialize())
+    except Exception:
+        # Not every program is exportable (callbacks, unusual pytrees);
+        # the in-process jit keeps working.
+        _count("error")
+        return None
+
+
+def _store(dir_: str, digest: str, blob: bytes) -> None:
+    """Write an entry atomically (tmp + rename: concurrent processes race
+    benignly to the same content)."""
+    try:
         os.makedirs(dir_, exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=dir_, suffix=".tmp")
         try:
@@ -202,8 +211,7 @@ def _store(dir_: str, digest: str, jfn: Callable, args, kwargs) -> None:
         _count("store")
         _count("store_bytes", len(blob))
     except Exception:
-        # Not every program is exportable (callbacks, unusual pytrees) and
-        # not every dir is writable; the in-process jit keeps working.
+        # not every dir is writable; the in-process program keeps working
         _count("error")
 
 
@@ -212,53 +220,68 @@ class _PersistentProgram:
 
     First call resolves against the on-disk cache: a hit binds
     ``jax.jit(exported.call)`` (no re-trace of the original function); a
-    miss traces via ``make()``, runs the call, then exports the program
-    for the next process. A loaded program whose call signature drifts
-    from what was exported (different avals/pytree) permanently falls
-    back to a fresh trace — jax raises before running anything wrong.
+    miss traces via ``make()``, exports the program, and binds the export
+    it has just written, read back: the process that traces a program
+    compiles exactly the module every later process will load, so XLA's
+    persistent cache holds it after ONE compile (binding the traced
+    function itself would compile a module that no later process asks
+    for, and leave the loaded export to compile again in the next one).
+    A bound export whose call signature drifts from what was exported
+    (different avals/pytree) permanently falls back to a fresh trace —
+    jax raises before running anything wrong.
     """
 
-    __slots__ = ("_key", "_make", "_fn", "_from_disk")
+    __slots__ = ("_key", "_make", "_fn", "_from_export")
 
     def __init__(self, key: tuple, make: Callable[[], Callable]):
         self._key = key
         self._make = make
         self._fn: Optional[Callable] = None
-        self._from_disk = False
+        self._from_export = False
 
     def _fresh(self) -> Callable:
-        self._from_disk = False
+        self._from_export = False
         self._fn = jax.jit(self._make())
         return self._fn
 
+    def _bind(self, exported) -> Callable:
+        self._from_export = True
+        self._fn = jax.jit(exported.call)
+        return self._fn
+
     def _first_call(self, args, kwargs):
+        from jax import export as jexport
         dir_ = _enabled_dir()
-        digest = _digest(self._key) if dir_ else None
-        if dir_:
-            exported = _load(dir_, digest)
-            if exported is not None:
-                self._fn = jax.jit(exported.call)
-                self._from_disk = True
-                try:
-                    out = self._fn(*args, **kwargs)
-                    _count("hit")
-                    return out
-                except Exception:
-                    # Signature drift (aval/pytree mismatch vs. what was
-                    # exported): recompile, and refresh the entry.
-                    _count("error")
-                    _count("miss")
-        fn = self._fresh()
-        out = fn(*args, **kwargs)
-        if dir_:
-            _store(dir_, digest, fn, args, kwargs)
-        return out
+        if not dir_:
+            return self._fresh()(*args, **kwargs)
+        digest = _digest(self._key)
+        exported = _load(dir_, digest)
+        if exported is not None:
+            try:
+                out = self._bind(exported)(*args, **kwargs)
+                _count("hit")
+                return out
+            except Exception:
+                # Signature drift (aval/pytree mismatch vs. what was
+                # exported): recompile, and refresh the entry.
+                _count("error")
+                _count("miss")
+        blob = _export(jax.jit(self._make()), args, kwargs)
+        if blob is not None:
+            try:
+                out = self._bind(jexport.deserialize(blob))(*args, **kwargs)
+            except Exception:
+                _count("error")
+            else:
+                _store(dir_, digest, blob)
+                return out
+        return self._fresh()(*args, **kwargs)
 
     def __call__(self, *args, **kwargs):
         fn = self._fn
         if fn is None:
             return self._first_call(args, kwargs)
-        if self._from_disk:
+        if self._from_export:
             try:
                 return fn(*args, **kwargs)
             except Exception:

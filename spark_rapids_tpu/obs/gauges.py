@@ -107,6 +107,15 @@ CATALOG: "List[Tuple[str, str, str]]" = [
     ("exec_host_sync_ns_total", "counter",
      "Nanoseconds host threads spent blocked in those reads "
      "(waiting for the device, then copying; not dispatching)"),
+    ("fused_step_programs_total", "counter",
+     "Distinct streaming-aggregate step programs bound by fused stages in "
+     "this process (exec/fused.py: one per batch capacity and window "
+     "length; it must not grow with a partition's batch count)"),
+    ("ingest_upload_ns_total", "counter",
+     "Nanoseconds from the start of an in-memory table's first scan until "
+     "its last batch was on the device: dictionary encoding and the "
+     "host->device copies (plan/overrides._device_source_parts; waited for "
+     "beside the plan, not in it; later scans reuse the device copy)"),
     ("jit_persist_hit_total", "counter",
      "Jitted programs reloaded from the on-disk cross-process cache "
      "(exec/jit_persist.py) instead of being re-traced"),
@@ -332,6 +341,10 @@ def snapshot() -> Dict[str, int]:
     out.update(_mt.counters())
     from spark_rapids_tpu.exec import aggregate as _agg
     out.update(_agg.counters())
+    from spark_rapids_tpu.exec import fused as _fused
+    out.update(_fused.counters())
+    from spark_rapids_tpu.plan import overrides as _ov
+    out.update(_ov.upload_counters())
     from spark_rapids_tpu.exec import kernels as _k
     out.update(_k.counters())
     from spark_rapids_tpu.serve import metrics as _serve_m

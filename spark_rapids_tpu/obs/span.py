@@ -70,6 +70,10 @@ CATALOG: "List[Tuple[str, str]]" = [
      "finish, autotune feedback and its file write, cleanup walk, leak audit"),
     ("exec:host-sync", "One blocking device->host read on the query path, "
      "through utils/sync.host_get (attrs: site)"),
+    ("exec:agg-step", "One dispatch of a fused stage's streaming aggregate: "
+     "the seed (first batch), then one per window: chain + first pass per "
+     "batch, one carry merge (attrs: batches, rows = batches x their "
+     "capacity)"),
     ("cluster:map", "Map task executed by a cluster executor process"),
     ("cluster:reduce", "Reduce task executed by a cluster executor process"),
     ("shuffle:fetch", "One shuffle block fetch round-trip (client side)"),

@@ -1,9 +1,8 @@
 """Measurement-driven dispatch: persistent per-(op, shape-class) timings.
 
 PR 11's join/agg kernels pick among dense / bucketed / general-ht /
-sorted-hash paths with hardcoded thresholds, ``exec/fused.py`` uses a
-fixed agg batch window, and ``plan/cbo.py`` costs placement with made-up
-constants. This module closes the loop from *measured* timings back into
+sorted-hash paths with hardcoded thresholds, and ``plan/cbo.py`` costs
+placement with made-up constants. This module closes the loop from *measured* timings back into
 those decisions, mirroring the reference's ``CostBasedOptimizer``
 bandwidth-flavored model:
 

@@ -54,7 +54,7 @@ def note(q, out):
     if prof is not None:
         for k, n in prof.dispatch_paths().items():
             if k.endswith(":measured") and (
-                    k.startswith("join:") or k.startswith("aggwin:")):
+                    k.startswith("join:")):
                 measured += n
 
 conf = C.RapidsConf(conf_kv)

@@ -183,3 +183,34 @@ def test_segmented_scan_pallas(one_chip, op, dtype):
     compiled = _compile(lambda a, b: K.segmented_scan_pallas(a, b, op),
                         one_chip, v, s)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_agg_step_q1(one_chip):
+    """The benchmark's Q1 (DECIMAL money, 128-bit sums) as the fused stage's
+    streaming step (exec/fused.py ``_make_step``): a window of two batches,
+    one unrolled body each, one carry merge. The program of cell
+    ``sf10_q1_agg1`` at a capacity this suite can afford (its merge pass
+    sorts)."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import datagen
+    import harness
+    from spark_rapids_tpu.exec import fused as F
+    from spark_rapids_tpu.plan import from_arrow
+    table = datagen.arrow(datagen.make(["lineitem"], 0.0005, 7)["lineitem"])
+    df = from_arrow(table, batch_rows=SORT_CAP, partitions=1)
+    plan = harness.load_by_path("queries", "q1").build(
+        {"lineitem": df}).physical_plan()
+    stage = plan.children[0]
+    assert isinstance(stage, F.TpuFusedStageExec)
+    agg = stage.agg
+    batch = next(iter(stage.child.execute(0)))
+    assert batch.capacity == SORT_CAP
+    carry = jax.eval_shape(F._make_seed([], agg), batch, ())[0]
+    carry = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), carry)
+    step = F._make_step([], agg, *F._carry_shape(carry))
+    compiled = _compile(step, one_chip, carry, (batch,) * 2, ())
+    assert "fusion" in compiled.as_text()
