@@ -222,6 +222,7 @@ class HashAggregateExec(UnaryExec):
 
         self._specs: List[_AggSpec] = getattr(self, "_specs", None) or []
         pre_exprs: List[E.Expression] = list(self._group_bound)
+        agg_inputs = {}  # cache_key of an aggregate's input -> its index
         if not self._specs:
             for e in self.agg_exprs:
                 func, name = _strip_alias(e)
@@ -288,8 +289,12 @@ class HashAggregateExec(UnaryExec):
                         bound_child = E.Cast(
                             E.Coalesce(bound_child, E.lit(False)), T.LONG)
                     func = type(func)(bound_child)
-                    idx = len(pre_exprs)
-                    pre_exprs.append(bound_child)
+                    # one pre-projected column per distinct input:
+                    # Sum(x) and Average(x) read the same one
+                    idx = agg_inputs.setdefault(bound_child.cache_key(),
+                                                len(pre_exprs))
+                    if idx == len(pre_exprs):
+                        pre_exprs.append(bound_child)
                 else:
                     idx = None
                 self._specs.append(_lower_agg(func, name, idx))
@@ -439,11 +444,12 @@ class HashAggregateExec(UnaryExec):
 
         Eligible when every group key is a ColumnRef onto a dict-encoded or
         boolean column (static cardinality) and the combined id domain is
-        small: aggregation then runs as ONE f64 matmul against a one-hot id
-        matrix on the MXU (kernels.dense_segment_sums) with no sort, no
-        permutation gather and no scatter. A global aggregate (no keys) is
-        the G=1 case. Int sums use three 21-bit limb rows so results are
-        exact (and wrap like int64) even though the matmul runs in f64."""
+        small: aggregation then runs against a one-hot id matrix with no
+        sort, no permutation gather and no scatter. A global aggregate (no
+        keys) is the G=1 case. Integer and decimal sums and every count are
+        int8 contractions over the values' own bytes on the MXU, exact in
+        128 bits (kernels.dense_segment_reduce); double sums are fused
+        masked reductions in f64 (kernels.dense_segment_sums)."""
         if self.mode != "complete":
             return None
         strides = []
@@ -494,29 +500,40 @@ class HashAggregateExec(UnaryExec):
             if ii is not None and ii not in in_vals:
                 in_vals[ii] = EV.eval_expr(self._pre_bound[ii], ctx)
 
-        # Three reduction lanes, all MXU/streaming — no scatter:
-        #   flag_rows  bool 0/1      -> one int8 matmul (counts, NaN flags)
-        #   int_rows   int64 values  -> 7-bit-limb int8 matmul (exact mod
-        #                               2^64 = Java long-sum wrap semantics)
+        # Two reductions, both streaming, no scatter:
+        #   flag_rows (bool 0/1) and int_rows (int64 values) -> int8
+        #     contractions of each lane's own bytes against one one-hot
+        #     (exact in 128 bits; the low word is Java's long-sum wrap);
+        #     masked rows drop out by their id
         #   f64_rows   double values -> fused masked reductions (exact f64)
-        flag_rows: List[jax.Array] = [active]  # row 0: group-exists count
+        flag_rows: List[jax.Array] = []  # counts[0] is the rows per id
         int_rows: List[jax.Array] = []
         f64_rows: List[jax.Array] = []
-        w_rows: List[jax.Array] = []   # 128-bit sum lanes (DECIMAL128)
-        w_neg: List[Optional[int]] = []  # flag-row index for neg correction
-        w_hi_lane = {}  # w_row index -> int_rows index carrying hi limbs
         plans = []  # per buffer: how to assemble from the lane outputs
-        flag_cache = {"__active__": 0}
-        row_cache = {}  # dedups shared inputs (Sum(x)+Average(x))
+        flag_cache = {}
+        row_cache = {}  # dedups lanes shared by buffers (a sum and its count)
 
         def nullable(ii):
             return self._pre_bound[ii].nullable
 
         def flag_row(key, arr):
             if key not in flag_cache:
-                flag_cache[key] = len(flag_rows)
                 flag_rows.append(arr)
+                flag_cache[key] = len(flag_rows)
             return flag_cache[key]
+
+        def int_row(kind, ii, valid, arr):
+            # rows drop out of the reduce by their id (ids_live), so only a
+            # null has to be zeroed (a computed value's overflow is one): a
+            # column that holds none goes in as it is, and the contraction
+            # reads it where it lies
+            key = (kind, ii)
+            if key not in row_cache:
+                row_cache[key] = len(int_rows)
+                e = _strip_alias(self._pre_bound[ii])[0]
+                plain = isinstance(e, E.ColumnRef) and not e.nullable
+                int_rows.append(arr if plain else jnp.where(valid, arr, 0))
+            return row_cache[key]
 
         for s in self._specs:
             v = in_vals.get(s.input_index)
@@ -549,23 +566,18 @@ class HashAggregateExec(UnaryExec):
                     wide_buf = (isinstance(bt, T.DecimalType)
                                 and bt.precision > T.DecimalType.MAX_LONG_DIGITS)
                     if wide_buf or isinstance(v, EV.WideVal):
-                        wkey = ("wisum", ii)
-                        if wkey not in row_cache:
-                            row_cache[wkey] = len(w_rows)
-                            if isinstance(v, EV.WideVal):
-                                # lo residues ARE the unsigned lo limbs
-                                w_rows.append(jnp.where(live, v.lo, 0))
-                                w_neg.append(None)
-                                w_hi_lane[len(w_rows) - 1] = len(int_rows)
-                                int_rows.append(jnp.where(live, v.hi, 0))
-                            else:
-                                x = v.data.astype(jnp.int64)
-                                w_rows.append(jnp.where(live, x, 0))
-                                w_neg.append(flag_row(("neg", ii),
-                                                      live & (x < 0)))
+                        if isinstance(v, EV.WideVal):
+                            # hi*2^64 + unsigned lo = (hi + [lo<0])*2^64 +
+                            # signed lo: both lanes sum as signed int64
+                            r = int_row("wlo", ii, v.validity, v.lo)
+                            rh = int_row("whi", ii, v.validity,
+                                         v.hi + (v.lo < 0))
+                        else:
+                            r = int_row("int", ii, v.validity, v.data)
+                            rh = None
                         vrow = flag_row(("live", ii), live) \
                             if nullable(ii) else 0
-                        plans.append(("wisum", row_cache[wkey], vrow, bt))
+                        plans.append(("wisum", r, rh, vrow, bt))
                         continue
                     if jnp.issubdtype(v.data.dtype, jnp.floating):
                         key = ("fsum", ii)
@@ -584,14 +596,10 @@ class HashAggregateExec(UnaryExec):
                         plans.append(("fsum", row_cache[key], nan_r, vrow,
                                       bt))
                         continue
-                    key = ("isum", ii)
-                    if key not in row_cache:
-                        row_cache[key] = len(int_rows)
-                        x = v.data.astype(jnp.int64)
-                        int_rows.append(jnp.where(live, x, 0))
+                    r = int_row("int", ii, v.validity, v.data)
                     vrow = flag_row(("live", ii), live) \
                         if nullable(ii) else 0
-                    plans.append(("isum", row_cache[key], vrow, bt))
+                    plans.append(("isum", r, vrow, bt))
                     continue
                 # min/max/first/last: scatter path over the tiny id domain
                 if isinstance(v, EV.WideVal):
@@ -601,24 +609,13 @@ class HashAggregateExec(UnaryExec):
         # barriers sit on the TINY (R, Gc) outputs so XLA cannot re-run a
         # whole reduction per consumer column, while the big row builds
         # still fuse INTO their reductions
-        counts = jax.lax.optimization_barrier(
-            K.dense_segment_counts(flag_rows, ids, Gc))
-        isums = jax.lax.optimization_barrier(
-            K.dense_segment_sums_int(int_rows, ids, Gc)) if int_rows \
-            else None
+        ids_live = jnp.where(active, ids, Gc)  # masked rows -> overflow slot
+        ihi, ilo, counts, n_rows = jax.lax.optimization_barrier(
+            K.dense_segment_reduce(int_rows, flag_rows, ids_live, Gc))
+        counts = jnp.concatenate([n_rows[None], counts])
         fsums = jax.lax.optimization_barrier(
             K.dense_segment_sums(jnp.stack(f64_rows), ids, Gc)) \
             if f64_rows else None
-        wsums = None
-        if w_rows:
-            negc = jnp.stack([
-                counts[r] if r is not None else jnp.zeros(Gc, jnp.int32)
-                for r in w_neg])
-            wh, wl = K.dense_segment_sums_int128(w_rows, ids, Gc, negc)
-            for wi, ir in w_hi_lane.items():
-                wh = wh.at[wi].add(isums[ir])  # + Σhi·2^64 (mod 2^64)
-            wsums = (jax.lax.optimization_barrier(wh),
-                     jax.lax.optimization_barrier(wl))
         exists = counts[0] > 0
         g = jnp.arange(Gc, dtype=jnp.int32)
         in_domain = g < G
@@ -642,7 +639,6 @@ class HashAggregateExec(UnaryExec):
                 key_cols.append(DeviceColumn(
                     T.BOOLEAN, (code == 1) & kvalid, kvalid))
 
-        ids_live = jnp.where(active, ids, Gc)  # masked rows -> overflow slot
         buf_cols: List[DeviceColumn] = []
         for plan in plans:
             if plan[0] == "count":
@@ -660,13 +656,14 @@ class HashAggregateExec(UnaryExec):
             elif plan[0] == "isum":
                 _, r, vrow, bt = plan
                 valid = (counts[vrow] > 0) & exists
-                data = jnp.where(valid, isums[r], 0).astype(T.numpy_dtype(bt))
+                data = jnp.where(valid, ilo[r], 0).astype(T.numpy_dtype(bt))
                 buf_cols.append(DeviceColumn(bt, data, valid))
             elif plan[0] == "wisum":
-                _, r, vrow, bt = plan
+                _, r, rh, vrow, bt = plan
                 valid = (counts[vrow] > 0) & exists
-                lo = jnp.where(valid, wsums[1][r], 0)
-                hi = jnp.where(valid, wsums[0][r], 0)
+                hi = ihi[r] if rh is None else ihi[r] + ilo[rh]
+                lo = jnp.where(valid, ilo[r], 0)
+                hi = jnp.where(valid, hi, 0)
                 buf_cols.append(DeviceColumn(bt, lo, valid, data2=hi))
             elif plan[0] == "wseg":
                 _, op, v, bt = plan

@@ -1151,77 +1151,80 @@ def dense_segment_sums(rows: jax.Array, ids: jax.Array, num_ids: int
     return jnp.stack(outs, axis=1)
 
 
-_INT8_LIMB = 7
-_INT8_NLIMBS = 10  # 10 x 7 = 70 bits >= 64: full two's-complement coverage
+# One int8 contraction may span this many rows: a biased byte is at most 128
+# in magnitude, so 2^23 rows keep every per-id sum inside int32.
+_LIMB_BLOCK_ROWS = 1 << 23
+_FLAGS_PER_WORD = 7  # bytes 0..6 of a flag word; byte 7 of word 0 counts rows
 
 
 @jax.named_scope("agg.reduce")
-def dense_segment_sums_int(rows: Sequence[jax.Array], ids: jax.Array,
-                           num_ids: int) -> jax.Array:
-    """Exact int64 per-id sums on the MXU: (R x (n,) int64) -> (R, num_ids).
+def dense_segment_reduce(rows: Sequence[jax.Array],
+                         flags: Sequence[jax.Array], ids: jax.Array,
+                         num_ids: int, block_rows: int = _LIMB_BLOCK_ROWS):
+    """Exact per-id sums of int64 rows and counts of bool flags by int8
+    contractions against ONE one-hot id matrix (native int8 MXU path).
 
-    TPU-first design with no cuDF analog: each int64 value is decomposed
-    into 10 unsigned 7-bit limbs (via uint64 logical shifts, so negative
-    values are their two's-complement residues), every limb row is summed
-    per id by ONE int8 x int8 -> int32 matmul against the one-hot id matrix
-    (native int8 MXU path, exact), and limb sums are recombined in uint64.
-    All arithmetic is exact mod 2^64 — identical to Java/Spark long-sum
-    wraparound semantics.
+    ``rows``: R x (n,) int64; ``flags``: k x (n,) bool. Returns
+    ``(hi, lo, counts, n_rows)``: the exact signed 128-bit sums as (R,
+    num_ids) int64 limb pairs (``lo`` alone is the int64 sum mod 2^64, Java's
+    long wrap), the (k, num_ids) flag counts and the (num_ids,) rows per id,
+    both int64. A row whose id is outside [0, num_ids) is in no sum and no
+    count: that is how callers mask rows.
 
-    Per-limb per-id sums stay below 127 * n; n <= 2^24 keeps them inside
-    int32. Masked rows must carry value 0 (their id may be anything valid).
-    """
-    s64 = _limb_matmul(rows, ids, num_ids)
-    total = jnp.zeros((len(rows), num_ids), jnp.uint64)
-    for j in range(_INT8_NLIMBS):
-        total = total + (s64[:, j, :] << (_INT8_LIMB * j))
-    return total.astype(jnp.int64)
-
-
-def _limb_matmul(rows: Sequence[jax.Array], ids: jax.Array,
-                 num_ids: int) -> jax.Array:
-    """(R x (n,) int64) -> per-id 7-bit-limb sums (R, 10, num_ids) uint64."""
-    n = ids.shape[0]
-    assert n <= (1 << 24), "int8-limb path needs per-id limb sums < 2^31"
-    oh = (ids[:, None] == jnp.arange(num_ids, dtype=jnp.int32)[None, :]
-          ).astype(jnp.int8)
-    limb_rows = []
-    for r in rows:
-        xu = r.astype(jnp.uint64)
-        for j in range(_INT8_NLIMBS):
-            limb_rows.append(
-                ((xu >> (_INT8_LIMB * j)) & 127).astype(jnp.int8))
-    L = jnp.stack(limb_rows)  # (R*10, n) int8
-    s = jax.lax.dot_general(L, oh, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.int32)
-    return s.astype(jnp.uint64).reshape(len(rows), _INT8_NLIMBS, num_ids)
-
-
-@jax.named_scope("agg.reduce")
-def dense_segment_sums_int128(rows: Sequence[jax.Array], ids: jax.Array,
-                              num_ids: int, neg_counts: jax.Array):
-    """Exact 128-bit per-id sums of int64 rows: -> (hi, lo) (R, num_ids).
-
-    Limb sums recombine into (hi, lo) pairs with carries; residue
-    recombination counts each negative input as +2^64, corrected by
-    ``neg_counts`` ((R, num_ids) int32: negatives per id per row).
+    The limbs are the value's own bytes: a lane is bitcast to (n, 8) int8
+    (little-endian) and contracted over n as it is, so the compiler fuses the
+    bitcast into the contraction's operand and no limb matrix is ever stored.
+    Bytes 0..6 are unsigned and are biased by ``^ 0x80`` into int8 (u - 128);
+    byte 7 is the two's-complement sign byte and enters unchanged, which
+    makes the recombined sum the true signed one. Flags ride seven to an
+    int64 word, a lane like any other; byte 7 of the first word holds 1, so
+    its sum is the rows per id, and 128 times that undoes the bias. int32
+    accumulation holds for ``block_rows`` <= 2^23 rows (|limb| <= 128); a
+    longer batch is contracted in blocks whose sums are added in int64.
+    docs/fusion.md has the argument.
     """
     from spark_rapids_tpu.exec import int128 as I128
 
-    s64 = _limb_matmul(rows, ids, num_ids)
+    assert block_rows <= _LIMB_BLOCK_ROWS
+    n = ids.shape[0]
     R = len(rows)
+    words = [jnp.full((n,), 1 << 56, jnp.int64)]
+    for j, f in enumerate(flags):
+        w, b = divmod(j, _FLAGS_PER_WORD)
+        if w == len(words):
+            words.append(jnp.zeros((n,), jnp.int64))
+        words[w] = words[w] | (f.astype(jnp.int64) << (8 * b))
+    nb = -(-n // block_rows)
+
+    def blocks(a, fill):  # (n, ...) -> (nb, rows of a block, ...)
+        if nb == 1:
+            return a[None]
+        pad = [(0, nb * block_rows - n)] + [(0, 0)] * (a.ndim - 1)
+        return jnp.pad(a, pad, constant_values=fill).reshape(
+            (nb, block_rows) + a.shape[1:])
+
+    oh = (blocks(ids, -1)[..., None]
+          == jnp.arange(num_ids, dtype=jnp.int32)).astype(jnp.int8)
+    bias = np.array([-128] * 7 + [0], np.int8)
+
+    def byte_sums(x):  # (n,) int64 -> (8, num_ids) sums of its biased bytes
+        limbs = jax.lax.bitcast_convert_type(x.astype(jnp.int64), jnp.int8)
+        s = jax.lax.dot_general(
+            blocks(limbs ^ bias, 0), oh, (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.int32)
+        return s.astype(jnp.int64).sum(axis=0)
+
+    s = jnp.stack([byte_sums(x) for x in list(rows) + words])
+    n_rows = s[R, 7]
+    s = s.at[:, :7].add(128 * n_rows)  # undo the bias: true byte sums
+    counts = s[R:, :7].reshape(-1, num_ids)[:len(flags)]
     hi = jnp.zeros((R, num_ids), jnp.int64)
     lo = jnp.zeros((R, num_ids), jnp.int64)
-    for j in range(_INT8_NLIMBS):
-        s = s64[:, j, :]  # uint64, < 2^31
-        sh = _INT8_LIMB * j
-        t_lo = (s << sh).astype(jnp.int64)
-        t_hi = (s >> (64 - sh)).astype(jnp.int64) if sh > 0 else \
-            jnp.zeros_like(t_lo)
-        hi, lo = I128.add(hi, lo, t_hi, t_lo)
-    # residues counted negatives as v + 2^64 -> subtract 2^64 per negative
-    hi = hi - neg_counts.astype(jnp.int64)
-    return hi, lo
+    for k in range(8):  # value = sum_k bytes[k] << 8k, in 128 bits
+        b = s[:R, k]
+        hi, lo = I128.add(hi, lo, b >> (64 - 8 * k) if k else b >> 63,
+                          b << (8 * k))
+    return hi, lo, counts, n_rows
 
 
 @jax.named_scope("agg.reduce")
@@ -1249,18 +1252,6 @@ def segment_sum_int128(hi: jax.Array, lo: jax.Array, seg_ids: jax.Array,
     # lo (we summed unsigned halves exactly).
     h3 = h2 + s_hi
     return h3, l2
-
-
-@jax.named_scope("agg.reduce")
-def dense_segment_counts(flags: Sequence[jax.Array], ids: jax.Array,
-                         num_ids: int) -> jax.Array:
-    """Per-id counts of boolean flag rows via one int8 matmul:
-    (R x (n,) bool) -> (R, num_ids) int32. Exact for n < 2^31 / 1."""
-    oh = (ids[:, None] == jnp.arange(num_ids, dtype=jnp.int32)[None, :]
-          ).astype(jnp.int8)
-    L = jnp.stack([f.astype(jnp.int8) for f in flags])
-    return jax.lax.dot_general(L, oh, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.int32)
 
 
 # ---------------------------------------------------------------------------

@@ -185,12 +185,9 @@ def test_segmented_scan_pallas(one_chip, op, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_agg_step_q1(one_chip):
-    """The benchmark's Q1 (DECIMAL money, 128-bit sums) as the fused stage's
-    streaming step (exec/fused.py ``_make_step``): a window of two batches,
-    one unrolled body each, one carry merge. The program of cell
-    ``sf10_q1_agg1`` at a capacity this suite can afford (its merge pass
-    sorts)."""
+def _q1_stage(sf, capacity):
+    """(fused stage, its first batch) of the benchmark's Q1 over DECIMAL
+    money at ``capacity`` rows a batch."""
     import sys
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
@@ -200,17 +197,61 @@ def test_fused_agg_step_q1(one_chip):
     import harness
     from spark_rapids_tpu.exec import fused as F
     from spark_rapids_tpu.plan import from_arrow
-    table = datagen.arrow(datagen.make(["lineitem"], 0.0005, 7)["lineitem"])
-    df = from_arrow(table, batch_rows=SORT_CAP, partitions=1)
+    table = datagen.arrow(datagen.make(["lineitem"], sf, 7)["lineitem"])
+    df = from_arrow(table, batch_rows=capacity, partitions=1)
     plan = harness.load_by_path("queries", "q1").build(
         {"lineitem": df}).physical_plan()
     stage = plan.children[0]
     assert isinstance(stage, F.TpuFusedStageExec)
-    agg = stage.agg
     batch = next(iter(stage.child.execute(0)))
-    assert batch.capacity == SORT_CAP
+    assert batch.capacity == capacity
+    return stage, batch
+
+
+def test_fused_agg_step_q1(one_chip):
+    """The benchmark's Q1 (DECIMAL money, 128-bit sums) as the fused stage's
+    streaming step (exec/fused.py ``_make_step``): a window of two batches,
+    one unrolled body each, one carry merge. The program of cell
+    ``sf10_q1_agg1`` at a capacity this suite can afford (its merge pass
+    sorts)."""
+    from spark_rapids_tpu.exec import fused as F
+    stage, batch = _q1_stage(0.0005, SORT_CAP)
+    agg = stage.agg
     carry = jax.eval_shape(F._make_seed([], agg), batch, ())[0]
     carry = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), carry)
     step = F._make_step([], agg, *F._carry_shape(carry))
     compiled = _compile(step, one_chip, carry, (batch,) * 2, ())
     assert "fusion" in compiled.as_text()
+
+
+def test_dense_first_pass_q1_stores_no_limb_matrix(one_chip):
+    """Q1's first pass over its DECIMAL lanes (the seed program: filter ->
+    dense first pass, which does not sort, so 2^16 rows): the compiled entry
+    computation holds no byte-typed array of batch width with a limb axis
+    (the int8 limb matrix the MXU contraction once read from HBM, with a
+    per-limb relayout and a concatenate in front of it: 3.0 ms a 2^20-row
+    batch on the chip against 0.8 ms, PERF.md PR 32), and the reduce leaves
+    few batch-width results of any kind: the bytes are made inside the
+    contractions."""
+    import re
+    from spark_rapids_tpu.exec import fused as F
+    stage, batch = _q1_stage(0.01, CAP)
+    text = _compile(F._make_seed([], stage.agg), one_chip, batch,
+                    ()).as_text()
+    entry = re.search(r"^ENTRY .*?\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    limb_matrices, reduce_wide = [], []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(\S+) = (\S+) ([\w\-]+)\(", line)
+        if m is None or m.group(3) in ("parameter", "get-tuple-element",
+                                       "bitcast"):
+            continue
+        name, shape, _ = m.groups()
+        dims = re.match(r"(?:s8|u8|pred)\[([\d,]+)\]", shape)
+        if dims and str(CAP) in dims.group(1).split(",") \
+                and "," in dims.group(1):
+            limb_matrices.append(f"{name} {shape}")
+        if "agg.reduce" in line and re.search(rf"\b{CAP}\b", shape):
+            reduce_wide.append(f"{name} {shape}")
+    assert not limb_matrices, limb_matrices
+    assert len(reduce_wide) <= 12, reduce_wide
+    assert "concatenate" not in " ".join(reduce_wide)
