@@ -9,8 +9,9 @@ distinct semi/anti joins; scalar subqueries evaluate eagerly at build time
 on the SAME engine configuration (Spark also plans them as separate
 subquery executions).
 
-The differential tracker (tools/tpcds_tracker.py) runs every query twice —
-device engine vs the CPU fallback engine — and compares results, mirroring
+The differential tests (tests/test_tpcds.py, tests/test_fusion_diff.py,
+tests/test_reuse_diff.py) run a query twice — device engine vs the CPU
+fallback engine, or a rewrite on vs off — and compare results, mirroring
 the reference's assert_gpu_and_cpu_are_equal_collect discipline
 (reference: integration_tests/src/main/python/asserts.py:479-617).
 """

@@ -459,7 +459,7 @@ def shrink_to_live(batch: ColumnarBatch, min_capacity: int = 1 << 20
     Static shapes mean every downstream kernel pays for the full capacity:
     a filter/join/agg output holding 1M live rows in a 16M-capacity batch
     makes every later gather/sort/scan 16x more expensive than needed
-    (device cost scales with capacity — tools/perf_probe.py). The shrink is
+    (device cost scales with capacity). The shrink is
     ONE host sync of (row count + string byte counts) and contiguous
     slices; only applied when at least half the capacity would be saved
     and the batch is big enough for the sync to pay for itself.

@@ -74,20 +74,6 @@ EXPLAIN = conf(
     doc="Explain why parts of a plan did or did not run on TPU: NONE, "
         "NOT_ON_TPU, ALL. (reference: spark.rapids.sql.explain)")
 
-BATCH_SIZE_BYTES = conf(
-    "spark.rapids.tpu.sql.batchSizeBytes", default=1 << 30,
-    doc="Target size in bytes for TPU-resident columnar batches "
-        "(reference: spark.rapids.sql.batchSizeBytes).")
-
-BATCH_SIZE_ROWS = conf(
-    "spark.rapids.tpu.sql.batchSizeRows", default=1 << 22,
-    doc="Target row count for TPU columnar batches. Batches are padded to "
-        "power-of-two capacity buckets to keep the XLA compile cache warm.")
-
-MIN_BUCKET_ROWS = conf(
-    "spark.rapids.tpu.sql.minBucketRows", default=1024,
-    doc="Minimum capacity bucket for padded batches.", internal=True)
-
 CONCURRENT_TASKS = conf(
     "spark.rapids.tpu.sql.concurrentTpuTasks", default=2,
     doc="Number of tasks that may hold the TPU concurrently "
@@ -158,17 +144,6 @@ AGG_REPARTITION_MAX_DEPTH = conf(
         "the engine falls back to split-retry as the last resort.",
     check=lambda v: None if v >= 1 else "must be >= 1")
 
-OOM_INJECT_MODE = conf(
-    "spark.rapids.tpu.test.injectRetryOOM.mode", default="NONE",
-    doc="Test-only fault injection: NONE, RETRY, SPLIT (reference: "
-        "spark.rapids.sql.test.injectRetryOOM; RapidsConf.scala:2753).",
-    internal=True)
-
-OOM_INJECT_SKIP = conf(
-    "spark.rapids.tpu.test.injectRetryOOM.skipCount", default=0,
-    doc="Number of pool allocations to allow before injecting an OOM.",
-    internal=True)
-
 # -- fault injection & resilience (docs/fault_injection.md) -----------------
 
 TEST_FAULTS = conf(
@@ -227,33 +202,6 @@ FAULT_BLACKLIST_THRESHOLD = conf(
         "whole-query retries but never degrade.",
     check=lambda v: None if v >= 1 else "must be >= 1")
 
-SHUFFLE_MODE = conf(
-    "spark.rapids.tpu.shuffle.mode", default="MULTITHREADED",
-    doc="Shuffle manager mode: MULTITHREADED (host files, works everywhere), "
-        "ICI (mesh all_to_all for co-scheduled stages), CACHE_ONLY "
-        "(reference: RapidsConf.scala:1767 RapidsShuffleManagerMode).")
-
-SHUFFLE_WRITER_THREADS = conf(
-    "spark.rapids.tpu.shuffle.multiThreaded.writer.threads", default=4,
-    doc="Threads for the multithreaded shuffle writer.")
-
-SHUFFLE_READER_THREADS = conf(
-    "spark.rapids.tpu.shuffle.multiThreaded.reader.threads", default=4,
-    doc="Threads for the multithreaded shuffle reader.")
-
-SHUFFLE_COMPRESS = conf(
-    "spark.rapids.tpu.shuffle.compression.codec", default="none",
-    doc="Codec for serialized shuffle batches: none, lz4, zstd.")
-
-PARQUET_READER_TYPE = conf(
-    "spark.rapids.tpu.sql.format.parquet.reader.type", default="MULTITHREADED",
-    doc="PERFILE, MULTITHREADED, or COALESCING parquet reader "
-        "(reference: RapidsConf.scala:315 RapidsReaderType).")
-
-PARQUET_READER_THREADS = conf(
-    "spark.rapids.tpu.sql.format.parquet.multiThreadedRead.numThreads", default=8,
-    doc="Thread pool size for the multithreaded parquet reader.")
-
 METRICS_LEVEL = conf(
     "spark.rapids.tpu.sql.metrics.level", default="MODERATE",
     doc="Operator metrics verbosity: ESSENTIAL, MODERATE, DEBUG "
@@ -289,7 +237,7 @@ METRICS_JOURNAL_ENABLED = conf(
         "compile/execute/finish) plus spill/retry/fault/worker events in "
         "the bounded in-process journal (obs/events.py; "
         "docs/observability.md). Per-event cost is one dict append under "
-        "a lock — measured <3% on TPC-H q1 (docs/perf_notes_r09.md).")
+        "a lock.")
 
 METRICS_JOURNAL_CAPACITY = conf(
     "spark.rapids.tpu.metrics.journal.capacity", default=4096,
@@ -353,25 +301,10 @@ HEALTH_PROGRESS_TIMEOUT_S = conf(
         "for this long is flagged stalled in the health registry and "
         "raises a worker-stale journal event (obs/health.py).")
 
-ANSI_ENABLED = conf(
-    "spark.rapids.tpu.sql.ansi.enabled", default=False,
-    doc="ANSI SQL mode: overflow and invalid casts raise instead of "
-        "wrapping/returning null (Spark spark.sql.ansi.enabled semantics).")
-
-SESSION_TIMEZONE = conf(
-    "spark.rapids.tpu.sql.session.timeZone", default="UTC",
-    doc="Session timezone for date/timestamp expressions. Only UTC is "
-        "TPU-accelerated in round 1 (reference gates similarly on UTC; "
-        "GpuOverrides timezone checks).")
-
 CPU_FALLBACK_ENABLED = conf(
     "spark.rapids.tpu.sql.fallback.enabled", default=True,
     doc="Allow per-operator CPU fallback. When false an unsupported operator "
         "raises instead.")
-
-RETRY_MAX_ATTEMPTS = conf(
-    "spark.rapids.tpu.memory.retry.maxAttempts", default=32,
-    doc="Max OOM retry attempts before surfacing the failure.", internal=True)
 
 AQE_ENABLED = conf(
     "spark.rapids.tpu.sql.adaptive.enabled", default=True,
@@ -647,11 +580,6 @@ SHUFFLE_TARGET_BATCH_ROWS = conf(
     doc="Post-shuffle coalesce row target for merged device uploads "
         "(reference: GpuShuffleCoalesceExec target size).")
 
-CLUSTER_HEARTBEAT_INTERVAL_S = conf(
-    "spark.rapids.tpu.cluster.heartbeat.intervalSeconds", default=2.0,
-    doc="Executor heartbeat period for the multi-process cluster "
-        "(reference: RapidsShuffleHeartbeatManager interval).")
-
 CLUSTER_HEARTBEAT_TIMEOUT_S = conf(
     "spark.rapids.tpu.cluster.heartbeat.timeoutSeconds", default=10.0,
     doc="Missed-heartbeat window after which an executor is declared dead "
@@ -673,11 +601,6 @@ TZ_DB_ENABLED = conf(
     "spark.rapids.tpu.sql.timezone.db.enabled", default=True,
     doc="Device timezone-transition table for non-UTC timestamp "
         "expressions (reference: GpuTimeZoneDB).")
-
-FILECACHE_ENABLED = conf(
-    "spark.rapids.tpu.filecache.enabled", default=False,
-    doc="Local range cache for remote scan byte ranges (reference: "
-        "spark.rapids.filecache.enabled).")
 
 FILECACHE_MAX_BYTES = conf(
     "spark.rapids.tpu.filecache.maxBytes", default=8 << 30,
@@ -938,8 +861,7 @@ SERVE_SLO_ENABLED = conf(
     doc="Per-tenant SLO metrics (serve/metrics.py): queue-wait, semaphore-"
         "wait, and deadline-slack histograms plus admission-outcome "
         "counters keyed by (tenant, priority), surfaced in Prometheus "
-        "exposition, explain_analyze, and the bench.py --clients "
-        "per-tenant percentile block (docs/observability.md).")
+        "exposition and explain_analyze (docs/observability.md).")
 
 SERVE_SLO_MAX_TENANTS = conf(
     "spark.rapids.tpu.serve.slo.maxTenants", default=64,
@@ -1131,19 +1053,6 @@ class RapidsConf:
         merged = dict(self._values)
         merged.update(kv)
         return RapidsConf(merged)
-
-    # Convenience accessors used on hot paths
-    @property
-    def sql_enabled(self) -> bool:
-        return self[SQL_ENABLED]
-
-    @property
-    def batch_size_rows(self) -> int:
-        return self[BATCH_SIZE_ROWS]
-
-    @property
-    def ansi(self) -> bool:
-        return self[ANSI_ENABLED]
 
 
 def all_entries() -> List[ConfEntry]:

@@ -1,9 +1,8 @@
 """Cross-process persistence for shared_jit programs.
 
 ``shared_jit`` dedupes traced programs within one process, but a fresh
-process still pays trace + compile (~0.3–1 s per kernel through the
-XLA:CPU disk cache, docs/perf_notes_r09.md) for every distinct program
-before its first query returns. This module extends the dedupe across
+process still pays trace + compile for every distinct program before
+its first query returns. This module extends the dedupe across
 process restarts: on a shared_jit miss the traced program is serialized
 with ``jax.export`` to an on-disk entry, and the next process that asks
 for the same semantic key deserializes the executable instead of
@@ -91,7 +90,7 @@ def _environment_salt() -> str:
     """Everything outside the semantic key that changes what a serialized
     program means: jax serialization format (jax.__version__), the target
     platform (jax.default_backend()), and the host instruction set
-    (cpu_feature_fingerprint()). Guarded by tools/check_cache_keys.py."""
+    (cpu_feature_fingerprint()). Guarded by tools/lint/cache_keys.py."""
     return "|".join((jax.__version__, jax.default_backend(),
                      cpu_feature_fingerprint()))
 

@@ -157,11 +157,10 @@ def ensure_plain_batch(batch: ColumnarBatch) -> ColumnarBatch:
 def _arr_to_words(a: jax.Array) -> List[jax.Array]:
     """Fixed-width data lane -> uint32 words (bijective encodings).
 
-    MEASURED TPU fact (tools/perf_probe.py, v5e): one XLA gather op at 16M
-    rows costs ~0.25s almost regardless of width, so gathering k columns as
-    k ops costs k*0.25s while ONE gather of a (W, N) packed uint32 matrix
-    costs ~0.4-0.6s total. All per-batch row movement therefore packs every
-    fixed-width lane into uint32 words, gathers once, and unpacks.
+    All per-batch row movement packs every fixed-width lane into uint32
+    words, gathers the (W, N) matrix once, and unpacks: one gather op, not
+    one per column. No cell of the benchmark reaches the gather yet, so the
+    form has no number on the ledger (ROADMAP S5).
     """
     dt = a.dtype
     if dt == jnp.bool_:
@@ -1549,7 +1548,7 @@ def probe_join_table_unique(probe: ColumnarBatch, tbl: JoinTable,
 #
 # Static jit keys carry (capacity, seed, max_probes): the table layout
 # parameters can never collide in the jit/persist caches
-# (tools/check_cache_keys.py guards this structurally).
+# (tools/lint/cache_keys.py guards this structurally).
 
 HASHTBL_MAX_PROBES = 16  # default static probe bound per seed
 HASHTBL_MAX_REHASH = 4   # host-side seeded rehash attempts before fallback

@@ -64,20 +64,12 @@ def corrupt(site: str, data: bytes, **ctx) -> bytes:
 
 def configure(conf=None) -> None:
     """Install (or clear) the registry from the active conf's
-    ``spark.rapids.tpu.test.faults`` spec, folding in the legacy
-    ``injectRetryOOM`` knobs as a ``mem.alloc`` rule."""
+    ``spark.rapids.tpu.test.faults`` spec."""
     from spark_rapids_tpu.config import conf as _C
 
     if conf is None:
         conf = _C.get_active()
-    spec = _C.TEST_FAULTS.get(conf)
-    mode = _C.OOM_INJECT_MODE.get(conf)
-    if mode and mode != "NONE":
-        action = "retry" if mode.upper() == "RETRY" else "split"
-        legacy = (f"mem.alloc:{action}"
-                  f"@skip={_C.OOM_INJECT_SKIP.get(conf)}")
-        spec = f"{spec};{legacy}" if spec else legacy
-    install(spec)
+    install(_C.TEST_FAULTS.get(conf))
 
 
 def install(spec: str) -> None:

@@ -1,7 +1,7 @@
 """NetClient: blocking client for the QueryFrontend wire protocol.
 
-Used by tests and the ``bench.py --serve-open`` driver. One client = one
-connection = one authenticated session; thread-safe for sequential use
+Used by tests, ``chip_smoke.py`` and the benchmark's harness. One client =
+one connection = one authenticated session; thread-safe for sequential use
 per instance (hold one client per worker thread, the same discipline as
 a DB-API connection).
 

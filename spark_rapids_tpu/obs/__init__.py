@@ -41,7 +41,6 @@ from spark_rapids_tpu.obs.trace_export import (  # noqa: F401
 from spark_rapids_tpu.obs.expose import (  # noqa: F401
     render_histograms,
     render_prometheus,
-    write_textfile,
 )
 from spark_rapids_tpu.obs import events as journal  # noqa: F401
 from spark_rapids_tpu.obs import health  # noqa: F401

@@ -6,8 +6,7 @@ driver logs; standalone we keep a process-wide ring of structured events
 finish, plus spill / retry / fault-recovered / degraded / worker-stale)
 that tests, ``tools/obs_report.py``, and humans can query or dump as
 JSONL. The journal is always on: emission is one dict build plus a
-deque append under a lock (bounded, oldest evicted), cheap enough for
-the <3% overhead budget in docs/perf_notes_r09.md — per-event work is
+deque append under a lock (bounded, oldest evicted) — per-event work is
 per *query phase*, never per batch or per row.
 
 Event shape: ``{"ts": epoch_s, "kind": str, ...fields}``; ``query_id``

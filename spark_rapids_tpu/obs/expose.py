@@ -105,10 +105,3 @@ def render_prometheus(snapshot: Optional[Dict[str, int]] = None) -> str:
         lines.append(f"{full} {snap.get(name, 0)}")
     return ("\n".join(lines) + "\n" + render_histograms()
             + render_tenant_slos())
-
-
-def write_textfile(path: str) -> str:
-    """Write the exposition for the node_exporter textfile collector."""
-    with open(path, "w") as f:
-        f.write(render_prometheus())
-    return path

@@ -3,9 +3,8 @@
 Latency *distributions* — not just totals — are what ROADMAP items 1/2
 gate on (p50/p95/p99 for serving, sub-second small-query tails). Each
 histogram is a fixed array of power-of-two buckets: ``record(ns)`` is
-one ``bit_length`` plus two adds under a lock, no allocation, so the
-per-batch opTime site in exec/base.py stays within the <3% always-on
-overhead budget (docs/perf_notes_r09.md).
+one ``bit_length`` plus two adds under a lock, no allocation, which is
+what lets the per-batch opTime site in exec/base.py stay always on.
 
 Bucket ``i`` counts values with ``int(v).bit_length() == i`` — i.e.
 ``[2**(i-1), 2**i)`` ns for ``i >= 1``; bucket 0 holds zeros. 64 buckets

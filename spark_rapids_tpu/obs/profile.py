@@ -177,7 +177,7 @@ class QueryProfile:
         """Dispatch decisions across the plan, counted by
         ``op:path:source`` — which join/agg paths served the query and
         whether each choice was measured or the static default
-        (plan/autotune.py; bench.py emits this per query)."""
+        (plan/autotune.py)."""
         out: Dict[str, int] = {}
         for node in self.nodes:
             for d in node.get("dispatch", ()):

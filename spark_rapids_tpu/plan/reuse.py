@@ -180,7 +180,7 @@ def _local_key(node) -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# duplicate discovery (shared by the rewrite and tools/perf_probe.py)
+# duplicate discovery
 # ---------------------------------------------------------------------------
 
 
@@ -212,24 +212,6 @@ def _reusable_roots(root, memo) -> Dict[tuple, List[Tuple[object, int, object]]]
             fp = plan_fingerprint(node, memo)
             groups.setdefault(fp, []).append((parent, idx, node))
     return groups
-
-
-def duplicate_groups(root) -> List[dict]:
-    """Per-plan report of repeated reusable subtrees (perf_probe 'reuse'
-    mode): one dict per fingerprint occurring more than once."""
-    memo: Dict[int, tuple] = {}
-    out = []
-    for fp, occs in _reusable_roots(root, memo).items():
-        distinct = {id(n): n for _, _, n in occs}
-        if len(distinct) < 2:
-            continue
-        first = next(iter(distinct.values()))
-        out.append({
-            "root": first.node_description(),
-            "occurrences": len(distinct),
-            "subtree_nodes": _subtree_size(first),
-        })
-    return out
 
 
 def _subtree_size(node) -> int:

@@ -2,7 +2,7 @@
 plus the per-tenant SLO surface.
 
 Every name here is declared in obs/gauges.CATALOG (guarded by
-tools/check_gauge_catalog.py); ``counters()`` feeds gauges.snapshot() the
+tools/lint/gauge_catalog.py); ``counters()`` feeds gauges.snapshot() the
 same way pipeline.STATS and faults.counters() do. Counters are process
 totals; gauges (queue depth, reserved bytes, active queries) are levels.
 

@@ -4,7 +4,7 @@
 # dropped fetches) and must be bit-identical to the fault-free run with
 # srtpu_fault_recovered_total > 0 — the acceptance net for the hardened
 # retry/refetch/degradation paths (docs/fault_injection.md). The executor
-# kill + recompute paths run in the cluster suite (tests/run_slow_lane.sh).
+# kill + recompute paths run in the cluster suite (tests/test_cluster.py).
 # tests/test_serve.py adds the concurrent-serving variant: N client threads
 # through the QueryServer under seeded serve.admit/serve.cancel faults,
 # still bit-identical to the fault-free serial run (docs/serving.md).

@@ -8,6 +8,8 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+import conftest
+
 from spark_rapids_tpu import faults
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import batch_from_arrow, batch_to_arrow
@@ -24,19 +26,6 @@ def _clean_conf_and_pool():
     C.set_active(None)
     set_pool(None)
     faults.install("")
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _drop_jit_programs():
-    # These tests compile many programs at capacities (1024-row batches,
-    # 2 MB pools, per-level bucket shapes) nothing else in the suite uses.
-    # Keeping those executables live for the rest of the session pushes
-    # XLA:CPU's cumulative jit-code footprint over a threshold where a
-    # LATER unrelated compile segfaults inside the compiler; dropping them
-    # at module teardown keeps the process well clear of it.
-    yield
-    import jax
-    jax.clear_caches()
 
 
 def _table(n=20_000, n_keys=5000, seed=7):
@@ -95,16 +84,31 @@ def test_capped_pool_completes_via_repartition_bit_identical(monkeypatch):
     assert node.metrics["numRepartitions"].value > 0
 
 
-def test_repartition_recurses_and_spills_buckets():
+def test_repartition_recurses_and_spills_buckets(monkeypatch):
     """A tiny target forces recursion past level 0; bucket sub-batches are
     registered spillable and shed through the framework under pressure."""
     from spark_rapids_tpu.mem.spill import get_framework
 
+    # The recursion binds a program per bucket shape and depth: 60,325
+    # mappings when the capped run holds them all at once, of the 65,530 a
+    # process may have (ROADMAP D1). So it starts from none, and gives
+    # them back between two levels whenever half the limit is in use.
+    recurse = HashAggregateExec._repartition_merge
+
+    def recurse_with_room(self, *args, **kwargs):
+        if conftest.mappings() > 32_000:
+            conftest.drop_programs()
+        yield from recurse(self, *args, **kwargs)
+
+    monkeypatch.setattr(HashAggregateExec, "_repartition_merge",
+                        recurse_with_room)
+    conftest.drop_programs()
     t = _table()
     C.set_active(C.RapidsConf(
         {"spark.rapids.tpu.sql.agg.repartition.enabled": False}))
     base = _run(_agg(t))
 
+    conftest.drop_programs()
     set_pool(HbmPool(1 << 21))
     C.set_active(C.RapidsConf({
         "spark.rapids.tpu.sql.agg.repartition.targetBytes": 1,
@@ -154,15 +158,3 @@ def test_single_partial_skips_repartition():
     s1 = AGG.repartition_snapshot()
     assert s1["total"] == s0["total"]
     assert len(got) == len({(r[0], r[1]) for r in got})
-
-
-def test_pool_cap_refuses_correctness_gate_shrinkage():
-    """bench --pool-cap must obey the same contract as --faults: no
-    shrinking of what the correctness gate checks."""
-    import bench
-
-    bench._faults_guard(None, {}, pool_cap=1 << 20)  # no gate envs: fine
-    with pytest.raises(SystemExit, match="pool-cap"):
-        bench._faults_guard(None, {"BENCH_RUNS": "1"}, pool_cap=1 << 20)
-    with pytest.raises(SystemExit):
-        bench._faults_guard("mem.alloc:retry@p=0.01", {"BENCH_SF_H": "0.1"})

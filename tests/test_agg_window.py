@@ -21,6 +21,8 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+import conftest
+
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
 if BENCH not in sys.path:
@@ -34,32 +36,16 @@ from spark_rapids_tpu.obs import gauges, span  # noqa: E402
 from spark_rapids_tpu.plan import from_arrow  # noqa: E402
 from spark_rapids_tpu.utils import tracing  # noqa: E402
 
-def _drop_programs():
-    import gc
-
-    import jax
-
-    from spark_rapids_tpu.exec import jit_cache
-    jit_cache._CACHE.clear()
-    jax.clear_caches()
-    gc.collect()
-
 
 @pytest.fixture(scope="module", autouse=True)
 def _release_programs():
     """Once XLA:CPU has loaded it, a Q1 step program of 7 unrolled bodies
     is thousands of memory mappings, and a process may hold 65,530
     (``vm.max_map_count``): 17,522 after this file's first case in a fresh
-    process, 45,895 after its last (``/proc/self/maps``). An xdist worker
-    that has already run a few hundred tests dies of a segmentation fault
-    inside the next load (``compile_and_load``, or ``_cache_read``; the
-    seed's one failure, ``test_agg_repartition``, peaks at 62,163 alone
-    and dies of the same behind any other test). So the
-    programs this process holds are dropped before these cases and after
-    them; dropping them gives the mappings back."""
-    _drop_programs()
-    yield
-    _drop_programs()
+    process, 45,895 after its last (``/proc/self/maps``). So these cases
+    start from none, whatever the worker held (conftest.py gives them back
+    again when the next module starts)."""
+    conftest.drop_programs()
 
 
 BATCH = 2048

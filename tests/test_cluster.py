@@ -255,12 +255,17 @@ def test_cluster_executor_kill_fault_recovery(rng):
         "v": pa.array(rng.integers(0, 1000, n), pa.int64()),
     })
     # worker 1 dies on its SECOND task (skip=1): it completes one map task
-    # first, so its written blocks are LOST and must recompute via lineage
+    # first, so its written blocks are LOST and must recompute via lineage.
+    # The small-query fast path would plan ONE reduce partition for 4,000
+    # rows, worker 1 would get no second task and nobody would die: the
+    # four reduce partitions asked for below are what reaches it.
+    wide = {"spark.rapids.tpu.sql.enabled": True,
+            "spark.rapids.tpu.fastpath.enabled": False}
     fault_conf = RapidsConf({
-        "spark.rapids.tpu.sql.enabled": True,
+        **wide,
         "spark.rapids.tpu.test.faults": "executor:kill@id=1,skip=1",
     })
-    df_clean = from_arrow(t, _conf(), batch_rows=512, partitions=6)
+    df_clean = from_arrow(t, RapidsConf(wide), batch_rows=512, partitions=6)
     df_clean.shuffle_partitions = 4
     q_clean = df_clean.group_by("k").agg(E.Sum(col("v")).alias("s"),
                                          E.Count(col("v")).alias("n"))

@@ -3,8 +3,8 @@
 Fast-lane sections: schedule grammar + determinism + thread safety of the
 registry (faults/registry.py), the legacy OomInjector race fix, the shuffle
 integrity trailer + refetch path, blacklist classification and CPU
-degradation, retry backoff/recovery accounting, the cache-key static guard
-(tools/check_cache_keys.py), and bench.py's chaos correctness-gate guard.
+degradation, retry backoff/recovery accounting, and the cache-key static
+guard (tools/lint/cache_keys.py).
 
 Chaos lane (``SRTPU_CHAOS_LANE=1``, tests/run_chaos_lane.sh): every tracker
 TPC-H/TPC-DS query runs under a seeded fault schedule (injected OOMs,
@@ -14,8 +14,6 @@ net for the hardened retry/refetch/degradation paths.
 """
 
 import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -175,10 +173,9 @@ def test_oom_injector_on_alloc_thread_safe():
 
 # -- configuration ----------------------------------------------------------
 
-def test_configure_folds_legacy_oom_knobs():
+def test_configure_installs_the_conf_schedule():
     conf = RapidsConf({
-        "spark.rapids.tpu.test.injectRetryOOM.mode": "RETRY",
-        "spark.rapids.tpu.test.injectRetryOOM.skipCount": 2,
+        "spark.rapids.tpu.test.faults": "mem.alloc:retry@skip=2",
     })
     faults.configure(conf)
     reg = faults.get_registry()
@@ -466,21 +463,13 @@ def test_gauges_surface_fault_counters():
 # -- satellite: cache-key static guard --------------------------------------
 
 def test_cache_key_guard_passes_on_tree():
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "check_cache_keys.py")],
-        capture_output=True, text=True)
-    assert r.returncode == 0, r.stderr
-    assert "cache-key guard OK" in r.stdout
+    from tools.lint import cache_keys
+
+    assert cache_keys.run_pass(REPO) == []
 
 
 def test_cache_key_guard_flags_violation(tmp_path):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "check_cache_keys", os.path.join(REPO, "tools",
-                                         "check_cache_keys.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    from tools.lint import cache_keys
 
     bad = tmp_path / "bad_expr.py"
     bad.write_text(
@@ -490,7 +479,7 @@ def test_cache_key_guard_flags_violation(tmp_path):
         "    def cache_key(self):\n"
         "        return (type(self).__name__,)\n")
     violations = []
-    mod._check_file(str(bad), violations)
+    cache_keys.check_file(str(bad), violations, REPO)
     assert len(violations) == 1 and "Broken" in violations[0]
 
     ok = tmp_path / "ok_expr.py"
@@ -501,23 +490,8 @@ def test_cache_key_guard_flags_violation(tmp_path):
         "    def cache_key(self):\n"
         "        return super().cache_key() + self._params\n")
     violations = []
-    mod._check_file(str(ok), violations)
+    cache_keys.check_file(str(ok), violations, REPO)
     assert violations == []
-
-
-# -- satellite: bench correctness-gate guard --------------------------------
-
-def test_bench_refuses_gate_shrinkage_with_faults():
-    import bench
-
-    with pytest.raises(SystemExit, match="refusing"):
-        bench._faults_guard("mem.alloc:retry@p=0.1", {"BENCH_RUNS": "1"})
-    with pytest.raises(SystemExit):
-        bench._faults_guard("x:y", {"BENCH_SF_H": "0.001", "HOME": "/root"})
-    # no faults, or faults with no shrinkage overrides: fine
-    bench._faults_guard("", {"BENCH_RUNS": "1"})
-    bench._faults_guard(None, {"BENCH_SF_DS": "0.001"})
-    bench._faults_guard("mem.alloc:retry", {"HOME": "/root"})
 
 
 # -- chaos lane: tracker differential under a seeded fault schedule ---------

@@ -2,8 +2,8 @@
 metric attribution, and the jit-cache key regression from VERDICT r5.
 
 The full tracker differential (every TPC-H/TPC-DS planner query, fusion
-on vs off) lives in test_fusion_diff.py on the slow lane; this module
-keeps the fast lane to hand-built chains plus one small planner query.
+on vs off) lives in test_fusion_diff.py; this module keeps to hand-built
+chains plus one small planner query.
 """
 
 import numpy as np

@@ -1,4 +1,4 @@
-"""Tracker-wide fusion differential (slow lane, run_slow_lane.sh).
+"""Tracker-wide fusion differential.
 
 Every TPC-H and TPC-DS query the planner can build runs twice — fusion on
 and fusion off — through the full DataFrame/Overrides/shuffle pipeline;

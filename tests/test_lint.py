@@ -268,6 +268,25 @@ def test_conf_keys_flags_undocumented_declaration(repo_copy):
                for x in v)
 
 
+def test_conf_keys_flags_unread_declaration(repo_copy):
+    """A declared key nothing reads is a knob that does nothing: flagged
+    until the package (not conf.py itself) names it."""
+    _append(repo_copy, "spark_rapids_tpu/config/conf.py",
+            '\nFIXTURE_DEAD = conf("spark.rapids.tpu.fixture.deadKnob", '
+            'default=1, doc="never read")\n'
+            '_SELF_READ = FIXTURE_DEAD.key\n')
+
+    def unread(v):
+        return [x for x in v if "read by nothing" in x]
+
+    v = unread(conf_keys.run_pass(repo_copy))
+    assert len(v) == 1 and "spark.rapids.tpu.fixture.deadKnob" in v[0]
+    _append(repo_copy, "spark_rapids_tpu/exec/misc.py",
+            "\ndef _fixture(conf, C):\n"
+            "    return C.FIXTURE_DEAD.get(conf)\n")
+    assert unread(conf_keys.run_pass(repo_copy)) == []
+
+
 def test_conf_keys_ignores_prose_fragments():
     """Doc strings saying 'spark.rapids.tpu.sql.enabled is false' must not
     count as key reads (the matcher requires a full key, nothing more)."""

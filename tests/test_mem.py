@@ -161,11 +161,15 @@ def test_semaphore_limits_and_priority():
             import time
             time.sleep(hold_s)
 
+    import time
     threads = [threading.Thread(target=task, args=(i, 0.05)) for i in range(6)]
-    for t in threads:
+    for i, t in enumerate(threads):
         t.start()
-        import time
-        time.sleep(0.01)  # stagger arrival so wait priority is deterministic
+        # the next task arrives only once this one is in the queue (or in):
+        # a fixed 10 ms stagger let two arrivals swap on a loaded machine
+        give_up = time.monotonic() + 30
+        while sem.acquire_count <= i and time.monotonic() < give_up:
+            time.sleep(0.001)
     for t in threads:
         t.join()
     assert sorted(order) == list(range(6))

@@ -506,13 +506,13 @@ def test_tracing_process_label_stamps_events():
 # -- gauge catalog static guard --------------------------------------------
 
 def test_gauge_catalog_guard_passes_on_tree():
-    from tools import check_gauge_catalog as G
-    assert G.main() == 0
+    from tools.lint import gauge_catalog as G
+    assert G.run_pass(str(_ROOT)) == []
 
 
 def test_gauge_catalog_guard_catches_undeclared(tmp_path):
-    from tools import check_gauge_catalog as G
-    declared = G.catalog_names()
+    from tools.lint import gauge_catalog as G
+    declared = G.catalog_names(str(_ROOT))
     assert "pool_oom_total" in declared
     bad = tmp_path / "bad.py"
     bad.write_text(
@@ -525,7 +525,7 @@ def test_gauge_catalog_guard_catches_undeclared(tmp_path):
         "    alias('year_total')\n"   # SQL alias shape: must NOT be flagged
     )
     violations = []
-    G._check_file(str(bad), declared, violations)
+    G.check_file(str(bad), declared, violations, root=str(_ROOT))
     flagged = " ".join(violations)
     assert "made_up_thing_total" in flagged
     assert "other_unknown_total" in flagged
@@ -988,3 +988,27 @@ def test_prometheus_tenant_slo_exposition():
             'outcome="completed"} 1') in text
     histo.reset_all()
     sm.reset_tenants()
+
+
+def test_obs_report_demo_writes_a_complete_bundle(tmp_path):
+    """``tools/obs_report.py --demo``: the diagnostics bundle an operator
+    asks for holds every file its manifest names, non-empty, and a trace
+    the viewer accepts. In a process of its own: the demo plants a
+    synthetic OOM post-mortem that this process should not carry."""
+    import os
+    import subprocess
+
+    out = tmp_path / "bundle"
+    proc = subprocess.run(
+        [sys.executable, str(_ROOT / "tools" / "obs_report.py"), "--demo",
+         "--out", str(out)],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    for name in ("profiles.json", "journal.jsonl", "metrics.prom",
+                 "trace.json", "config.json", "health.json", "memory.json",
+                 "memory.txt", "MANIFEST.json"):
+        assert (out / name).stat().st_size > 0, name
+    assert validate_trace(json.loads((out / "trace.json").read_text())) == []
+    conf = json.loads((out / "config.json").read_text())
+    assert "spark.rapids.tpu.sql.enabled" in conf

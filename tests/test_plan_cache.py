@@ -1,6 +1,6 @@
 """Plan-rewrite memo, small-query fast path, and persistent-program-cache
-recovery (default lane; the cross-process warm start and tracker-wide
-on/off differential live in the slow lane, tests/test_warmstart.py)."""
+recovery (the cross-process warm start and tracker-wide on/off
+differential live in tests/test_warmstart.py)."""
 
 import threading
 
@@ -205,7 +205,7 @@ def test_offpath_takes_semaphore():
 
 # ---------------------------------------------------------------------------
 # persistent program cache: corruption recovery (same-process shape; the
-# cross-process warm start is slow-lane)
+# cross-process warm start is tests/test_warmstart.py)
 # ---------------------------------------------------------------------------
 
 

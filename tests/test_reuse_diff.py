@@ -1,5 +1,4 @@
-"""Tracker-wide computation-reuse differential (slow lane,
-run_slow_lane.sh).
+"""Tracker-wide computation-reuse differential.
 
 Every TPC-H and TPC-DS query the planner can build runs twice — exchange
 reuse on and off — through the full DataFrame/Overrides/shuffle pipeline;

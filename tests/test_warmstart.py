@@ -1,4 +1,4 @@
-"""Cross-process warm start and cache/fastpath differential (slow lane).
+"""Cross-process warm start and cache/fastpath differential.
 
 Two halves:
 

@@ -1,7 +1,6 @@
 """cache-keys pass: the jit-cache key contract (VERDICT r5 bug class).
 
-Migrated from tools/check_cache_keys.py (now a thin shim). Two programs
-whose expressions differ only in a non-child parameter (a LIKE pattern, a
+Two programs whose expressions differ only in a non-child parameter (a LIKE pattern, a
 round scale, a trunc format...) MUST produce different ``cache_key()``
 tuples, or they silently share one compiled kernel and return wrong
 results. The convention: such parameters are recorded in ``self._params``,

@@ -1,6 +1,5 @@
 """gauge-catalog pass: metric/histogram names must be declared.
 
-Migrated from tools/check_gauge_catalog.py (now a thin shim). Contract:
 ``obs/gauges.CATALOG`` is the single source of truth for every metric the
 process exposes — a counter a subsystem increments but never declares is
 invisible to snapshot()/Prometheus/QueryProfile diffs. Counter names end

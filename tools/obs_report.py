@@ -23,8 +23,8 @@ process,
 
 CLI: ``python tools/obs_report.py --out DIR [--demo]``. ``--demo`` runs a
 tiny in-memory query with profiling + trace capture on first — plus one
-synthetic OOM post-mortem — so the bundle is non-empty; the smoke path
-tests/run_slow_lane.sh exercises it.
+synthetic OOM post-mortem — so the bundle is non-empty; tests/test_obs.py
+exercises it.
 """
 
 from __future__ import annotations

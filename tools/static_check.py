@@ -3,8 +3,7 @@
 
 Runs every registered pass from tools/lint/ against the repo and prints
 per-pass timings. Exit 0 only when every pass is clean; any violation or
-crashing pass exits 1. Wired into the default tier-1 lane via
-tests/test_lint.py and into tests/run_slow_lane.sh.
+crashing pass exits 1. Wired into the tier-1 tests via tests/test_lint.py.
 
     python tools/static_check.py              # all passes
     python tools/static_check.py --list       # show passes
