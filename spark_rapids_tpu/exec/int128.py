@@ -284,7 +284,7 @@ def mul_128_exact(ah, al, bh, bl, precision: int):
     """Signed 128x128 multiply with Spark overflow-to-NULL semantics.
 
     Returns (hi, lo, overflow): overflow is True when |a*b| needs more
-    than 128 bits or exceeds 10^precision."""
+    than 127 bits or is >= 10^precision. One int64 operand: mul_128x64."""
     sa = is_neg(ah, al)
     sb = is_neg(bh, bl)
     aah, aal = abs_(ah, al)
@@ -300,6 +300,26 @@ def mul_128_exact(ah, al, bh, bl, precision: int):
     ol = jnp.where(neg_out, nl, l)
     ovf = high_any | overflow_mask(oh, ol, precision) | is_neg(h, l)
     return oh, ol, ovf
+
+
+def mul_128x64(ah, al, b, precision: int):
+    """Signed 128x64 multiply: ``mul_128_exact``'s contract and its very
+    (hi, lo, overflow) for an int64 ``b``, at a quarter of its device time
+    (PERF.md, PR 34; why it is exact: docs/fusion.md). With a = ah*2^64 +
+    al_u, a*b = al_u*b + (ah*b << 64): two signed 64x64 products summed
+    word by word; no limb axis, no abs/neg."""
+    p0h, p0l = mul_64x64(al, b)
+    p0h = p0h + jnp.where(al < 0, b, I64(0))  # al is unsigned (mul_small)
+    p1h, p1l = mul_64x64(ah, b)
+    # words of the 192-bit sum: lo = p0l, hi = p0h + p1l, and above them
+    # sext(p0h) + p1h + carry (|p1h| <= 2^62: no wrap), which is the sign
+    # extension of hi exactly where the product fits 128 bits
+    h = p0h + p1l
+    top = (p0h >> 63) + p1h + _ult(h, p1l).astype(I64)
+    ovf = (top != (h >> 63)) | overflow_mask(h, p0l, precision)
+    if precision >= 39:  # mask off; -2^127 fits 128 bits but not 127
+        ovf = ovf | ((h == _SIGN) & (p0l == 0))
+    return h, p0l, ovf
 
 
 def _clz16_limbs(v: jax.Array) -> jax.Array:

@@ -1605,18 +1605,21 @@ def _eval_arith_wide(expr, out_t: T.DecimalType, lt, rt, l, r,
                        valid & ~ovf)
     if isinstance(expr, E.Multiply):
         # out scale == s1 + s2: the raw product of the scaled values IS the
-        # result, so no rescale — narrow pairs use the 64x64 fast path,
-        # wide operands the exact limb multiply (DecimalUtils.multiply128)
+        # result, so no rescale — narrow pairs use the 64x64 fast path, one
+        # wide operand the 128x64 multiply (in either order: half of the
+        # narrow operand's limbs would be sign bits), two wide operands the
+        # exact limb multiply (DecimalUtils.multiply128)
         if isinstance(l, ColVal) and isinstance(r, ColVal):
             h, lo = I128.mul_64x64(l.data.astype(jnp.int64),
                                    r.data.astype(jnp.int64))
             ovf = I128.overflow_mask(h, lo, out_t.precision)
+        elif isinstance(l, ColVal) or isinstance(r, ColVal):
+            w, n = (r, l) if isinstance(l, ColVal) else (l, r)
+            h, lo, ovf = I128.mul_128x64(w.hi, w.lo,
+                                         n.data.astype(jnp.int64),
+                                         out_t.precision)
         else:
-            s1 = lt.scale if isinstance(lt, T.DecimalType) else 0
-            s2 = rt.scale if isinstance(rt, T.DecimalType) else 0
-            wl = _as_wide(l, lt, s1)
-            wr = _as_wide(r, rt, s2)
-            h, lo, ovf = I128.mul_128_exact(wl.hi, wl.lo, wr.hi, wr.lo,
+            h, lo, ovf = I128.mul_128_exact(l.hi, l.lo, r.hi, r.lo,
                                             out_t.precision)
         z = jnp.zeros_like(h)
         return WideVal(jnp.where(ovf, z, h), jnp.where(ovf, z, lo),

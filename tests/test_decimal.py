@@ -41,21 +41,21 @@ def table():
     })
 
 
-def both(build):
+def both(build, make=table):
     out = []
     for enabled in (True, False):
         conf = RapidsConf({"spark.rapids.tpu.sql.enabled": enabled})
-        t = table()
+        t = make()
         df = from_arrow(t, conf)
         df.shuffle_partitions = 2
         out.append(build(df).collect())
     return out
 
 
-def assert_same(build):
+def assert_same(build, make=table):
     import math
 
-    dev, cpu = both(build)
+    dev, cpu = both(build, make)
     assert len(dev) == len(cpu), f"dev={dev}\ncpu={cpu}"
     for ra, rb in zip(dev, cpu):
         assert ra.keys() == rb.keys()
@@ -400,3 +400,57 @@ def test_greatest_least_wide_decimal128():
     assert dev[1]["l"] == D("-2.75")
     assert dev[2]["g"] == D("4.50") and dev[2]["l"] == D("4.50")
     assert dev[1]["gn"] == D("777777777777777.2500")
+
+
+def _wide_narrow_table():
+    i64 = np.iinfo(np.int64)
+    return pa.table({
+        # Q1's disc_price type; rows: 28 integer digits, the type's extremes,
+        # one unit either side of zero, NULL, a lo limb with its top bit set
+        # (2^63 units), zero
+        "w": pa.array(
+            [D("1234567890123456789012345678.1234"),
+             D("-9999999999999999999999999999.9999"), None,
+             D("9999999999999999999999999999.9999"), D("0.0001"),
+             D("-0.0001"), D("922337203685477.5808"), D("0.0000"),
+             D("-31415926535897932384.6264")], type=pa.decimal128(32, 4)),
+        "m": pa.array(
+            [D("1.07"), D("-99999999999999.99"), D("3.00"), None,
+             D("99999999999999.99"), D("-0.01"), D("-1.00"), D("5.55"),
+             D("0.00")], type=pa.decimal128(16, 2)),
+        "q32": pa.array([7, -2147483648, 1, 2147483647, None, -1, 0, 12, 3],
+                        type=pa.int32()),
+        "q64": pa.array([i64.min, i64.max, 5, None, i64.min, i64.max, -1,
+                         10**12, 0], type=pa.int64()),
+        # a second wide column: wide x wide stays on mul_128_exact
+        "v": pa.array([D("-3"), D("99999999999999999999"), None, D("2"),
+                       D("-99999999999999999999"), D("10000000000"),
+                       D("18446744073709551615"), D("1"), D("7")],
+                      type=pa.decimal128(20, 0)),
+    })
+
+
+@pytest.mark.parametrize("other", ["m", "q32", "q64", "v"])
+@pytest.mark.parametrize("order", ["wide_first", "wide_second"])
+def test_wide_times_narrow(order, other):
+    """One wide and one narrow operand, in either order, decimal and
+    integral: the 128x64 multiply (exec/int128.mul_128x64) through the
+    expression path equals the CPU engine, NULLs on either side stay NULL
+    and a product of 10^38 or more comes out NULL. ``v`` is the control:
+    a second wide operand, the limb multiply, the same demands."""
+    pair = (col("w"), col(other))
+    prod = Multiply(*(pair if order == "wide_first" else pair[::-1]))
+    build = lambda df: df.select(prod.alias("p"), *pair)  # noqa: E731
+    stats = build(from_arrow(_wide_narrow_table(),
+                             RapidsConf({}))).device_plan_stats()
+    assert stats["device_fraction"] == 1.0, stats
+    dev = assert_same(build, _wide_narrow_table)
+    null_in = sum(r["w"] is None or r[other] is None for r in dev)
+    got = [r["p"] for r in dev]
+    assert sum(g is None for g in got) > null_in, got  # overflow -> NULL
+    assert sum(g is not None for g in got) >= 3, got
+    with decimal.localcontext() as ctx:
+        ctx.prec = 80
+        for g, r in zip(got, dev):
+            if g is not None:
+                assert g == r["w"] * r[other]

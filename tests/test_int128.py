@@ -136,3 +136,80 @@ def test_sortable_keys():
     order = np.lexsort((np.asarray(kl), np.asarray(kh)))
     assert [signed128(a[i]) for i in order] == \
         [signed128(a[i]) for i in sa]
+
+
+INT64_MIN, INT64_MAX = -(1 << 63), (1 << 63) - 1
+
+
+def _wide_narrow_cases(family, precision):
+    """(wide, narrow) Python-int operand pairs of one family."""
+    rng = np.random.default_rng(7)
+    if family == "random":  # every width of either operand, both signs
+        pairs = []
+        for _ in range(400):
+            a = int.from_bytes(rng.bytes(16), "little") >> int(
+                rng.integers(1, 128))
+            b = int.from_bytes(rng.bytes(8), "little") >> int(
+                rng.integers(1, 64))
+            pairs.append((a if rng.random() < .5 else -a,
+                          b if rng.random() < .5 else -b))
+        return pairs
+    if family == "extremes":
+        wide = [0, 1, -1, 10**38 - 1, -(10**38 - 1), (1 << 127) - 1,
+                -(1 << 127), (1 << 64) - 1, 1 << 64, -(1 << 64),
+                1 << 63, -(1 << 63), (1 << 63) | 5, -((1 << 63) | 5),
+                (7 << 64) | (1 << 63), 10**19, 10**37]  # lo's top bit set
+        narrow = [0, 1, -1, INT64_MAX, INT64_MIN, INT64_MIN + 1,
+                  INT64_MAX - 1, 10**18, -(10**18), 1 << 32, -(1 << 32),
+                  (1 << 32) - 1, 3]
+        return [(a, b) for a in wide for b in narrow]
+    if family == "bound":  # |a*b| just under, at and just over 10^p
+        pairs = []
+        for b in (1, -1, 3, -7, 10**9 + 7, -(10**17 + 3), (1 << 62) + 1,
+                  INT64_MIN):
+            q = min(10**precision, (1 << 127) - 1) // abs(b)
+            pairs += [(s * min(q + d, (1 << 127) - 1), b)
+                      for d in (-2, -1, 0, 1, 2) for s in (1, -1)]
+        return pairs
+    assert family == "over128"  # |a*b| in [2^127 - eps, 2^191)
+    pairs = []
+    for b in (2, -2, 1 << 40, INT64_MAX, INT64_MIN, -(10**18)):
+        for bits in (127, 128, 129, 160, 190):
+            q = (1 << bits) // abs(b)
+            pairs += [(s * min(q + d, (1 << 127) - 1), b)
+                      for d in (-1, 0, 1) for s in (1, -1)]
+    return pairs + [(-(1 << 127), 1), (-(1 << 127), -1), (1 << 126, 2),
+                    (-(1 << 126), 2), (1 << 126, -2), (1 << 64, INT64_MIN)]
+
+
+@pytest.mark.parametrize("precision", [38, 24, 39])
+@pytest.mark.parametrize("family", ["random", "extremes", "bound", "over128"])
+def test_mul_128x64(family, precision):
+    """The wide x narrow multiply against Python integers, and against
+    mul_128_exact of the sign-extended narrow operand: (hi, lo, overflow)
+    equal on every row, overflow rows included (precision 39: no decimal
+    bound, the 127-bit one alone)."""
+    import jax
+
+    pairs = _wide_narrow_cases(family, precision)
+    a = [x for x, _ in pairs]
+    b = np.array([y for _, y in pairs], np.int64)
+    ah, al = to_dev(a)
+    bd = jax.device_put(b)
+    h, l, ovf = jax.jit(I.mul_128x64, static_argnums=3)(ah, al, bd, precision)
+    want = [x * int(y) for x, y in pairs]
+    want_ovf = [abs(v) >= 1 << 127 or (precision <= 38
+                                       and abs(v) >= 10**precision)
+                for v in want]
+    assert np.asarray(ovf).tolist() == want_ovf
+    assert any(want_ovf) or family == "random"
+    assert not all(want_ovf) or family == "over128"
+    got = back(h, l)
+    assert [g for g, o in zip(got, want_ovf) if not o] == \
+        [v for v, o in zip(want, want_ovf) if not o]
+    bh, bl = I.from_i64(bd)
+    eh, el, eovf = jax.jit(I.mul_128_exact, static_argnums=4)(
+        ah, al, bh, bl, precision)
+    assert np.array_equal(np.asarray(ovf), np.asarray(eovf))
+    assert np.array_equal(np.asarray(h), np.asarray(eh))
+    assert np.array_equal(np.asarray(l), np.asarray(el))

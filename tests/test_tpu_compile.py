@@ -78,13 +78,19 @@ def lineitem_small():
     return _lineitem(0.0005, SORT_CAP)
 
 
-def _compile(fn, one_chip, *args):
-    """Lower ``fn`` over the shapes of ``args`` for the described chip and
-    compile it: raises what the chip's compiler would raise."""
+def _lower(fn, one_chip, *args):
+    """``fn`` traced and lowered over the shapes of ``args`` for the
+    described chip."""
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         args)
-    return jax.jit(fn).lower(*shapes).compile()
+    return jax.jit(fn).lower(*shapes)
+
+
+def _compile(fn, one_chip, *args):
+    """Lower ``fn`` over the shapes of ``args`` for the described chip and
+    compile it: raises what the chip's compiler would raise."""
+    return _lower(fn, one_chip, *args).compile()
 
 
 def test_graft_entry_fused_step(one_chip):
@@ -224,9 +230,28 @@ def test_fused_agg_step_q1(one_chip):
     assert "fusion" in compiled.as_text()
 
 
-def test_dense_first_pass_q1_stores_no_limb_matrix(one_chip):
-    """Q1's first pass over its DECIMAL lanes (the seed program: filter ->
-    dense first pass, which does not sort, so 2^16 rows): the compiled entry
+@pytest.fixture(scope="module")
+def q1_seed(one_chip):
+    """(traced StableHLO, [(name, shape, op, line)] of the compiled entry
+    computation) of Q1's seed program (filter -> dense first pass, which
+    does not sort, so 2^16 rows): the program of both cells' first batch."""
+    import re
+    from spark_rapids_tpu.exec import fused as F
+    stage, batch = _q1_stage(0.01, CAP)
+    lowered = _lower(F._make_seed([], stage.agg), one_chip, batch, ())
+    text = lowered.compile().as_text()
+    entry = re.search(r"^ENTRY .*?\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    ops = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(\S+) = (.+?) ([\w\-]+)\(", line)
+        if m is not None and m.group(3) not in (
+                "parameter", "get-tuple-element", "bitcast"):
+            ops.append(m.groups() + (line,))
+    return lowered.as_text(), ops
+
+
+def test_dense_first_pass_q1_stores_no_limb_matrix(q1_seed):
+    """Q1's first pass over its DECIMAL lanes: the compiled entry
     computation holds no byte-typed array of batch width with a limb axis
     (the int8 limb matrix the MXU contraction once read from HBM, with a
     per-limb relayout and a concatenate in front of it: 3.0 ms a 2^20-row
@@ -234,18 +259,8 @@ def test_dense_first_pass_q1_stores_no_limb_matrix(one_chip):
     few batch-width results of any kind: the bytes are made inside the
     contractions."""
     import re
-    from spark_rapids_tpu.exec import fused as F
-    stage, batch = _q1_stage(0.01, CAP)
-    text = _compile(F._make_seed([], stage.agg), one_chip, batch,
-                    ()).as_text()
-    entry = re.search(r"^ENTRY .*?\{\n(.*?)^\}", text, re.S | re.M).group(1)
     limb_matrices, reduce_wide = [], []
-    for line in entry.splitlines():
-        m = re.match(r"\s*(?:ROOT )?(\S+) = (\S+) ([\w\-]+)\(", line)
-        if m is None or m.group(3) in ("parameter", "get-tuple-element",
-                                       "bitcast"):
-            continue
-        name, shape, _ = m.groups()
+    for name, shape, _, line in q1_seed[1]:
         dims = re.match(r"(?:s8|u8|pred)\[([\d,]+)\]", shape)
         if dims and str(CAP) in dims.group(1).split(",") \
                 and "," in dims.group(1):
@@ -255,3 +270,27 @@ def test_dense_first_pass_q1_stores_no_limb_matrix(one_chip):
     assert not limb_matrices, limb_matrices
     assert len(reduce_wide) <= 12, reduce_wide
     assert "concatenate" not in " ".join(reduce_wide)
+
+
+def test_q1_charge_multiplies_wide_by_narrow_without_limbs(q1_seed):
+    """Q1's ``charge`` is DECIMAL(32,4) x DECIMAL(16,2), one wide operand:
+    the seed program is traced with the 128x64 multiply
+    (exec/int128.mul_128x64) and not with ``mul_128_exact``'s 16-bit limb
+    engine. As traced it holds no batch-width int64 array with a trailing
+    limb axis (the engine's stacks of 8 and 16); as compiled for the v5e its
+    entry has three fusions that write batch-width arrays in front of the
+    contractions (the id select and the two lane fusions) where the limb
+    engine had six, among them a first stage that wrote the limbs to HBM
+    for the lane fusion to read: 0.46 ms of a 2^20-row batch's 0.81 on the
+    chip against 0.12 of 0.43 (PERF.md PR 34). This is the proof that the
+    narrow form engages in the cells' program: the choice is made when the
+    program is traced, so no counter could count it."""
+    import re
+    traced, ops = q1_seed
+    assert f"tensor<{CAP}x" in traced
+    limb_axis = re.findall(rf"tensor<{CAP}x(?:8|16)xi64>", traced)
+    assert not limb_axis, sorted(set(limb_axis))
+    wide_fusions = [f"{name} {shape.count(str(CAP))} arrays"
+                    for name, shape, op, _ in ops
+                    if op == "fusion" and re.search(rf"\[{CAP}\]", shape)]
+    assert 1 <= len(wide_fusions) <= 3, wide_fusions
