@@ -976,6 +976,11 @@ CAPABILITIES = {
         "window length), at most three for a partition of equal batches "
         "whatever their number, each compiled once (exec/fused.py, "
         "exec/jit_persist.py; docs/fusion.md)",
+    "sort.boundedTopN":
+        "a sort with a limit is planned as a top-N (exec/sort.py TopNExec): "
+        "k rounds of selection per batch, whose program does not grow with "
+        "the batch as a variadic sort's does, so ORDER BY ... LIMIT over a "
+        "100k-group aggregate compiles in seconds (docs/fusion.md)",
 }
 
 
@@ -992,7 +997,9 @@ REQUIRES = conf(
         "instead of running it without. Naming a capability changes no "
         "behaviour. Known: agg.boundedStepPrograms (step programs of a "
         "streaming aggregate are bounded per partition and compile once, "
-        "so a large resident table's first start fits a deadline).",
+        "so a large resident table's first start fits a deadline); "
+        "sort.boundedTopN (a sort with a limit is a top-N by selection, "
+        "whose compile cost does not grow with the batch).",
     check=_check_requires)
 
 

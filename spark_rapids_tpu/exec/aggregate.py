@@ -388,10 +388,13 @@ class HashAggregateExec(UnaryExec):
         return K.group_rows(pre, list(range(self._n_keys)), active)
 
     @jax.named_scope("agg.first_pass")
-    def _first_pass(self, batch: ColumnarBatch) -> ColumnarBatch:
-        """pre-project + (fused filter) + group + per-buffer aggregations."""
+    def _first_pass(self, batch: ColumnarBatch,
+                    row_mask=None) -> ColumnarBatch:
+        """pre-project + (fused filter) + group + per-buffer aggregations.
+        ``row_mask``: the rows a filter below kept, where a fused stage
+        left them in place instead of compacting (exec/fused.py)."""
         ctx = EV.EvalContext(batch)
-        active = batch.active_mask()
+        active = batch.active_mask() if row_mask is None else row_mask
         if self._filter_bound is not None:
             pv = EV.eval_expr(self._filter_bound, ctx)
             active = active & pv.data & pv.validity
@@ -409,6 +412,11 @@ class HashAggregateExec(UnaryExec):
             v = EV.eval_expr(e, ctx)
             if isinstance(v, EV.StringVal):
                 pre_cols.append(DeviceColumn(T.STRING, v.data, v.validity, v.offsets))
+            elif isinstance(v, EV.WideVal):
+                # a DECIMAL128 input (a product of two decimals, say): both
+                # limbs ride as the column, _wide_agg sums them
+                pre_cols.append(DeviceColumn(e.dtype, v.lo, v.validity,
+                                             data2=v.hi))
             else:
                 pre_cols.append(DeviceColumn(e.dtype, v.data, v.validity))
         if not pre_cols:
@@ -816,7 +824,8 @@ class HashAggregateExec(UnaryExec):
                            > T.DecimalType.MAX_LONG_DIGITS)
                 if src is not None and (src.is_wide_decimal or wide_bt):
                     out_cols.append(self._wide_agg(
-                        src, gi, contributing, op, bt, cap, out_row_valid))
+                        src, gi, contributing, op, bt, cap, out_row_valid,
+                        seg_ends))
                     continue
                 seg_op = op
                 if op in ("sumsq", "sum3", "sum4"):
@@ -876,7 +885,8 @@ class HashAggregateExec(UnaryExec):
         return [vcol, ocol]
 
     def _wide_agg(self, src: DeviceColumn, gi: K.GroupInfo, contributing,
-                  op: str, bt, cap: int, out_row_valid) -> DeviceColumn:
+                  op: str, bt, cap: int, out_row_valid,
+                  seg_ends) -> DeviceColumn:
         """Segment reduction over a DECIMAL128 (hi, lo) column — or a
         narrow int64 decimal whose sum buffer is wide (sign-extended)."""
         from spark_rapids_tpu.exec import int128 as I128
@@ -889,6 +899,15 @@ class HashAggregateExec(UnaryExec):
             hi = jnp.where(lo < 0, jnp.int64(-1), jnp.int64(0))
         valid = src.validity[gi.perm]
         live = contributing & valid
+        if op == "sum":
+            # sorted segments: running sums and two gathers, no scatter-add
+            # (93 ms for 2^20 int64 on the v5e; PERF.md PR 35)
+            h, l, n_live = K.sorted_segment_sum_int128(
+                jnp.where(live, hi, 0), jnp.where(live, lo, 0), live,
+                gi.group_starts, seg_ends)
+            v_out = (n_live > 0) & out_row_valid
+            return DeviceColumn(bt, jnp.where(v_out, l, 0), v_out,
+                                data2=jnp.where(v_out, h, 0))
         any_valid = jax.ops.segment_max(
             live.astype(jnp.int32), gi.segment_ids, num_segments=cap) > 0
         v_out = any_valid & out_row_valid
@@ -898,12 +917,6 @@ class HashAggregateExec(UnaryExec):
                                     num_segments=cap)
             return DeviceColumn(bt, jnp.where(out_row_valid, c, 0),
                                 out_row_valid)
-        if op == "sum":
-            h, l = K.segment_sum_int128(
-                jnp.where(live, hi, 0), jnp.where(live, lo, 0),
-                gi.segment_ids, cap)
-            return DeviceColumn(bt, jnp.where(v_out, l, 0), v_out,
-                                data2=jnp.where(v_out, h, 0))
         if op in ("min", "max", "first", "last"):
             idx = jnp.arange(cap, dtype=jnp.int32)
             if op in ("first", "last"):

@@ -57,6 +57,8 @@ obs/profile.py renders them as ``fused=#<stage>`` rows under the stage.
 
 from __future__ import annotations
 
+import itertools
+import threading
 from typing import Iterator, List, Optional, Tuple
 
 import jax
@@ -135,15 +137,51 @@ def _carry_byte_targets(first: ColumnarBatch) -> Tuple[int, ...]:
 
 
 def _make_body(fns):
-    """Compose segment fns into one traced chain returning every
-    intermediate live-row count (per-constituent metric attribution)."""
+    """Compose segment fns into one traced chain. A fn takes and returns
+    ``(batch, live)``: ``live`` is None for a front-packed batch, or the
+    mask of the rows a filter kept where it left them in place for the
+    segment that consumes them (a join probe or the aggregate's first
+    pass: ``_OpSeg``). Returns the last batch, its mask, every
+    intermediate live-row count (per-constituent metric attribution), and
+    whether a probe that shrinks its output ran out of room."""
     def body(batch, consts):
-        counts = []
+        live, counts, short = None, [], jnp.bool_(False)
         for fn, cst in zip(fns, consts):
-            batch = fn(batch, cst)
-            counts.append(batch.num_rows)
-        return batch, tuple(counts)
+            batch, live, cut = fn(batch, live, cst)
+            counts.append(batch.num_rows if live is None
+                          else jnp.sum(live).astype(jnp.int32))
+            if cut is not None:
+                short = short | cut
+        return batch, live, tuple(counts), short
     return body
+
+
+def _packed(batch: ColumnarBatch, live) -> ColumnarBatch:
+    """The batch front-packed: the rows of ``live``, in order."""
+    if live is None:
+        return batch
+    from spark_rapids_tpu.exec import kernels as K
+    idx, n = K.compact_indices(live, batch.capacity)
+    return K.gather_batch(batch, idx, n)
+
+
+def _make_plain(fns):
+    body = _make_body(fns)
+
+    def plain(batch, consts):
+        out, live, counts, short = body(batch, consts)
+        return _packed(out, live), counts, short
+    return plain
+
+
+def _make_sizing(fns):
+    """The chain for its row counts alone: what the compiler keeps of it
+    is what the counts depend on (masks, table lookups), not the gathers."""
+    body = _make_body(fns)
+
+    def sizing(batch, consts):
+        return body(batch, consts)[2]
+    return sizing
 
 
 def _as_carry(first: ColumnarBatch) -> ColumnarBatch:
@@ -173,8 +211,8 @@ def _make_seed(fns, agg):
     body = _make_body(fns)
 
     def seed(batch, consts):
-        out, counts = body(batch, consts)
-        return _as_carry(agg._first_pass(out)), counts
+        out, live, counts, _ = body(batch, consts)
+        return _as_carry(agg._first_pass(out, live)), counts
     return seed
 
 
@@ -192,8 +230,8 @@ def _make_step(fns, agg, carry_cap: int, bc_targets: Tuple[int, ...]):
         firsts = []
         counts_all = []
         for batch in batches:
-            out, counts = body(batch, consts)
-            firsts.append(agg._first_pass(out))
+            out, live, counts, _ = body(batch, consts)
+            firsts.append(agg._first_pass(out, live))
             counts_all.append(counts)
         # named scopes are HLO metadata only (docs/observability.md): the
         # window's concatenate-and-reduce reads as aggwin/* in an op profile
@@ -206,12 +244,52 @@ def _make_step(fns, agg, carry_cap: int, bc_targets: Tuple[int, ...]):
     return step
 
 
+def _make_partial(fns, agg):
+    """A window of the DEFERRED streaming aggregate: chain -> first pass
+    for every batch of the window, their buffers packed end to end, and no
+    merge. Taken where a probe shrank the chain's output so far that a
+    window's first passes fit one source batch (``_execute_agg``): the
+    first passes then hold a few thousand
+    groups each, a partition's worth fits one buffer, and ONE merge at the
+    end (sized by the rows the windows really hold, read with the overflow
+    flags) does what a merge into a carry after every window would do nine
+    times at the carry's capacity. Returns (buffers, a probe ran short,
+    counts)."""
+    from spark_rapids_tpu.exec import kernels as K
+    from spark_rapids_tpu.exec.aggregate import concat_jit
+    body = _make_body(fns)
+
+    def partial(batches, consts):
+        firsts, counts_all, short = [], [], jnp.bool_(False)
+        for batch in batches:
+            out, live, counts, cut = body(batch, consts)
+            firsts.append(agg._first_pass(out, live))
+            counts_all.append(counts)
+            short = short | cut
+        with jax.named_scope("aggwin.concat"):
+            # room for every first pass: nothing to guess, nothing dropped
+            cat = (K.ensure_plain_batch(firsts[0]) if len(firsts) == 1
+                   else concat_jit(firsts))
+        return cat, short, tuple(counts_all)
+    return partial
+
+
 _STEP_KEYS: set = set()  # streaming-step program keys bound in this process
+
+#: why a partition left its fused stage for the unfused operator chain
+FALLBACK_CAUSES = ("join-refused", "carry-overflow", "empty")
+_fallback_lock = threading.Lock()
+_fallbacks = dict.fromkeys(FALLBACK_CAUSES, 0)
 
 
 def counters() -> dict:
     """For obs/gauges.snapshot()."""
-    return {"fused_step_programs_total": len(_STEP_KEYS)}
+    with _fallback_lock:
+        out = {f"fused_fallback_{c.replace('-', '_')}_total": n
+               for c, n in _fallbacks.items()}
+    out["fused_fallback_total"] = sum(out.values())
+    out["fused_step_programs_total"] = len(_STEP_KEYS)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -220,14 +298,36 @@ def counters() -> dict:
 
 
 class _OpSeg:
-    """A narrow batch_fn operator inside a stage (shape-independent)."""
+    """A narrow batch_fn operator inside a stage (shape-independent).
+
+    A filter that can say which rows it keeps (``mask_fn``) does not
+    compact them: it hands the mask on, ANDed with the one it was given,
+    to the next segment that consumes one (a join probe, the aggregate's
+    first pass, the stage's end), through operators that compute a row
+    from that row alone (``row_preserving``: a projection outside ANSI
+    mode, which may not raise for a row a filter dropped). Any other
+    operator is given its input front-packed."""
 
     __slots__ = ("op", "_fn", "_key")
 
     def __init__(self, op: TpuExec):
         self.op = op
-        fn = op.batch_fn()
-        self._fn = lambda batch, _cst, f=fn: f(batch)
+        keep = op.mask_fn() if hasattr(op, "mask_fn") else None
+        if keep is not None:
+            def fn(batch, live, _cst):
+                was = batch.active_mask() if live is None else live
+                return batch, was & keep(batch), None
+        elif getattr(op, "row_preserving", False):
+            apply = op.batch_fn()
+
+            def fn(batch, live, _cst):
+                return apply(batch), live, None
+        else:
+            apply = op.batch_fn()
+
+            def fn(batch, live, _cst):
+                return apply(_packed(batch, live)), None, None
+        self._fn = fn
         self._key = op.batch_fn_key()
 
     def key_part(self, in_cap: int) -> tuple:
@@ -268,6 +368,10 @@ class TpuFusedStageExec(UnaryExec):
             op.shrink_output for op in self.segments))
         self._register_metric("numFallbacks")
         self._register_metric("numFusedBatches")
+        # partition -> the capacity its first join's hits were found to fit
+        # (_size), None where they did not; kept with the plan, so a
+        # repeated query sizes once
+        self._learned: dict = {}
 
     # -- plan surface ------------------------------------------------------
     @property
@@ -305,9 +409,26 @@ class TpuFusedStageExec(UnaryExec):
                 segs.append(_OpSeg(op))
         return segs
 
-    def _fall_back(self, partition: int) -> Iterator[ColumnarBatch]:
+    def _fall_back(self, partition: int,
+                   cause: str) -> Iterator[ColumnarBatch]:
+        """Re-runs the partition through the unfused operator chain, under
+        an ``exec:fused-fallback`` span that lasts until the chain is
+        drained, so a request that left its fused stage shows it in its
+        own span tree. The span is not installed as the thread's context:
+        it stays open across this generator's yields, where the consumer
+        runs."""
         self.metrics["numFallbacks"].add(1)
-        return self._fallback.execute(partition)
+        with _fallback_lock:
+            _fallbacks[cause] += 1
+        ctx = _span.current()
+        sp = (_span.Span("exec:fused-fallback", ctx=ctx,
+                         attrs={"cause": cause})
+              if _span.enabled() and ctx is not None else None)
+        try:
+            yield from self._fallback.execute(partition)
+        finally:
+            if sp is not None:
+                sp.finish()
 
     def _stage_key(self, segs, in_cap: int) -> tuple:
         parts = []
@@ -315,7 +436,9 @@ class TpuFusedStageExec(UnaryExec):
         for seg in segs:
             parts.append(seg.key_part(cap))
             cap = seg.out_cap(cap)
-        return ("fused_stage",) + tuple(parts)
+        # "live": the chain's (batch, live) protocol; a program a store
+        # kept from before it has another signature under the old key
+        return ("fused_stage", "live") + tuple(parts)
 
     def _chain_fns(self, segs, in_cap: int):
         fns = []
@@ -336,57 +459,207 @@ class TpuFusedStageExec(UnaryExec):
                                                  "metrics.rows")))
                 op._pending_rows.clear()
 
+    # -- learned capacities -------------------------------------------------
+    # a later batch may hold twice the first batch's hits (and up to the
+    # bucket's end) before the partition has to run again unshrunk
+    SHRINK_HEADROOM = 2
+
+    def _size(self, partition: int, segs, first: ColumnarBatch,
+              consts) -> None:
+        """Sets the capacity the first absorbed join shrinks its output to
+        (``_FusedJoinProbe.shrink_to``). A selective join leaves a few
+        thousand rows in a batch of 2^20, and every gather, sort and
+        segment sum after it costs by capacity, not by rows. What it
+        leaves is learned, not configured: the first time a partition
+        runs, the chain is run over its first batch for its row counts
+        alone (one dispatch, one host sync: ``fused.sizing``), and the
+        join's hits there, with ``SHRINK_HEADROOM``, name a capacity
+        bucket. A later batch that does not fit says so (the programs'
+        ``short`` flag): what was cut is run again unshrunk and the stage
+        stops shrinking that partition. The learned capacity stays with
+        the plan (``_learned``), which a repeated query reuses."""
+        at = next((i for i, seg in enumerate(segs)
+                   if hasattr(seg, "shrink_to")), None)
+        if at is None:
+            return
+        if partition not in self._learned:
+            cap = first.capacity
+            fns = self._chain_fns(segs, cap)
+            run = shared_jit(self._stage_key(segs, cap) + ("sizing",),
+                             lambda: _make_sizing(fns))
+            hits = int(host_get(run(first, consts)[at], "fused.sizing"))
+            in_cap = cap
+            for seg in segs[:at]:
+                in_cap = seg.out_cap(in_cap)
+            want = bucket_capacity(max(self.SHRINK_HEADROOM * hits, 1024))
+            self._learned[partition] = want if want * 2 <= in_cap else None
+        segs[at].shrink_to = self._learned[partition]
+
+    def _unlearn(self, partition: int, segs) -> None:
+        self._learned[partition] = None
+        for seg in segs:
+            if hasattr(seg, "shrink_to"):
+                seg.shrink_to = None
+
     def do_execute(self, partition: int) -> Iterator[ColumnarBatch]:
         segs = self._runtime_segments(partition)
         if segs is None:
-            yield from self._fall_back(partition)
+            yield from self._fall_back(partition, "join-refused")
             return
         if self.agg is not None:
             yield from self._execute_agg(partition, segs)
         else:
             yield from self._execute_plain(partition, segs)
 
+    def _windows(self, batches) -> Iterator[List[ColumnarBatch]]:
+        """Runs of up to ``agg_window`` batches of ONE capacity: what one
+        dispatch of the streaming aggregate takes."""
+        window: List[ColumnarBatch] = []
+        for batch in batches:
+            if window and batch.capacity != window[0].capacity:
+                yield window
+                window = []
+            window.append(batch)
+            if len(window) == self.agg_window:
+                yield window
+                window = []
+        if window:
+            yield window
+
     def _execute_plain(self, partition: int, segs):
         consts = tuple(seg.consts for seg in segs)
         runs = {}
-        for batch in self.child.execute(partition):
-            cap = batch.capacity
+
+        def program(cap: int):
             run = runs.get(cap)
             if run is None:
                 fns = self._chain_fns(segs, cap)
-                run = shared_jit(self._stage_key(segs, cap),
-                                 lambda: _make_body(fns))
-                runs[cap] = run
-            out, counts = run(batch, consts)
-            self.metrics["numFusedBatches"].add(1)
-            self._attribute(segs, counts)
+                run = runs[cap] = shared_jit(self._stage_key(segs, cap),
+                                             lambda: _make_plain(fns))
+            return run
+
+        def run_all(batches):
+            for batch in batches:
+                out, counts, short = program(batch.capacity)(batch, consts)
+                self.metrics["numFusedBatches"].add(1)
+                self._attribute(segs, counts)
+                yield out, short
+
+        it = self.child.execute(partition)
+        first = next(it, None)
+        if first is None:
+            return
+        self._size(partition, segs, first, consts)
+        batches = itertools.chain([first], it)
+        if self._learned.get(partition) is None:
+            for out, _ in run_all(batches):
+                yield out
+            return
+        # shrunk outputs (half a source batch or less each) wait for the
+        # partition's end: ONE host sync reads every probe's ``short`` flag,
+        # as the deferred aggregate does. Where a batch's hits did not fit
+        # what was learned, the partition runs again at the probes' own
+        # capacity, and the stage stops shrinking it
+        held = list(run_all(batches))
+        if any(bool(v) for v in host_get([short for _, short in held],
+                                         "fused.short")):
+            self._unlearn(partition, segs)
+            runs.clear()
+            held = run_all(self.child.execute(partition))
+        for out, _ in held:
             yield out
 
     def _execute_agg(self, partition: int, segs):
         agg = self.agg
         agg._prepare()
         consts = tuple(seg.consts for seg in segs)
-        akey = ("streaming",) + agg._base_key
-        carry = None
         it = self.child.execute(partition)
+        first = next(it, None)
+        if first is None:
+            yield from self._fall_back(partition, "empty")
+            return
+        self._size(partition, segs, first, consts)
+        out_cap = first.capacity
+        for seg in segs:
+            out_cap = seg.out_cap(out_cap)
+        # deferred where a window's first passes and the carry's share fit
+        # one source batch together: the buffers kept are then no more
+        # than the batches read
+        if out_cap * (self.agg_window + 1) <= first.capacity:
+            yield from self._execute_deferred(partition, segs, consts,
+                                              first, it)
+        else:
+            yield from self._execute_carried(partition, segs, consts,
+                                             first, it)
+
+    def _execute_deferred(self, partition: int, segs, consts, first, it):
+        """The streaming aggregate behind a probe that shrank: a window's
+        first passes are packed and kept (``_make_partial``), and one merge
+        at the end, at the bucket of the rows they hold, gives the groups.
+        One host sync a partition, as the carried form has: the probes'
+        ``short`` flags and the windows' row counts together."""
+        from spark_rapids_tpu.exec.aggregate import concat_jit
+        agg = self.agg
+        akey = ("streaming",) + agg._base_key
+        runs = {}
+        partials, shorts = [], []
+
+        def dispatch(window: List[ColumnarBatch]):
+            cap, n = window[0].capacity, len(window)
+            prog = runs.get((cap, n))
+            if prog is None:
+                fns = self._chain_fns(segs, cap)
+                key = akey + ("partial", cap, n) + self._stage_key(segs, cap)
+                prog = runs[(cap, n)] = shared_jit(
+                    key, lambda: _make_partial(fns, agg))
+                _STEP_KEYS.add(key)
+            with _span.task_span("exec:agg-step",
+                                 attrs={"batches": n, "rows": n * cap}):
+                part, short, counts_all = prog(tuple(window), consts)
+            partials.append(part)
+            shorts.append(short)
+            self.metrics["numFusedBatches"].add(n)
+            agg.metrics["numAggBatches"].add(n)
+            for counts in counts_all:
+                self._attribute(segs, counts)
+
+        for window in self._windows(itertools.chain([first], it)):
+            dispatch(window)
+        cut, rows = host_get((shorts, [p.num_rows for p in partials]),
+                             "fused.overflow_flags")
+        if any(bool(v) for v in cut):
+            # a batch's hits outgrew what the first batch's had promised:
+            # the partition again, carried, at the probe's own capacity
+            self._unlearn(partition, segs)
+            yield from self._execute_agg(partition, segs)
+            return
+        total = sum(int(n) for n in rows)
+        with _span.task_span("exec:agg-step",
+                             attrs={"batches": 0, "rows": total}):
+            merged = agg._merge_pass_fn(concat_jit(
+                partials, out_capacity=bucket_capacity(max(total, 1))))
+            out = (merged if agg.mode == "partial"
+                   else agg._final_project_fn(merged))
+        agg.metrics["numOutputBatches"].add(1)
+        agg._pending_rows.append(out.num_rows)
+        yield out
+
+    def _execute_carried(self, partition: int, segs, consts, first, it):
+        agg = self.agg
+        akey = ("streaming",) + agg._base_key
         # seed: the first batch's first-pass output defines the carry's
         # static capacity (its bucket bounds the groups a partition may
         # hold fused — more groups trip the overflow flag -> fallback)
-        for batch in it:
-            cap = batch.capacity
-            key = self._stage_key(segs, cap) + akey + ("seed",)
-            fns = self._chain_fns(segs, cap)
-            run = shared_jit(key, lambda: _make_seed(fns, agg))
-            with _span.task_span("exec:agg-step",
-                                 attrs={"batches": 1, "rows": cap}):
-                carry, counts = run(batch, consts)
-            self.metrics["numFusedBatches"].add(1)
-            agg.metrics["numAggBatches"].add(1)
-            self._attribute(segs, counts)
-            break
-        if carry is None:
-            yield from self._fall_back(partition)
-            return
+        cap = first.capacity
+        key = self._stage_key(segs, cap) + akey + ("seed",)
+        fns = self._chain_fns(segs, cap)
+        run = shared_jit(key, lambda: _make_seed(fns, agg))
+        with _span.task_span("exec:agg-step",
+                             attrs={"batches": 1, "rows": cap}):
+            carry, counts = run(first, consts)
+        self.metrics["numFusedBatches"].add(1)
+        agg.metrics["numAggBatches"].add(1)
+        self._attribute(segs, counts)
         # steps: windows of up to agg_window batches of ONE capacity, one
         # dispatch each — chain+first_pass per batch then a single
         # (carry+firsts) concat/merge (the classic operator pays a dispatch
@@ -395,22 +668,14 @@ class TpuFusedStageExec(UnaryExec):
         # same buffers, and each length is a program of that many unrolled
         # bodies to compile
         step = _StepRunner(self, segs, agg, consts, akey, carry)
-        window: List[ColumnarBatch] = []
-        for batch in it:
-            if window and batch.capacity != window[0].capacity:
-                step.run(window)
-                window = []
-            window.append(batch)
-            if len(window) == self.agg_window:
-                step.run(window)
-                window = []
-        if window:
+        for window in self._windows(it):
             step.run(window)
         # ONE host sync per partition resolves every overflow flag; on
         # overflow the carry holds truncated garbage -> re-run unfused
-        if step.flags and any(bool(v) for v in
-                              host_get(step.flags, "fused.overflow_flags")):
-            yield from self._fall_back(partition)
+        flags = (host_get(step.flags, "fused.overflow_flags")
+                 if step.flags else ())
+        if any(bool(v) for v in flags):
+            yield from self._fall_back(partition, "carry-overflow")
             return
         carry = step.carry
         out = carry if agg.mode == "partial" else agg._final_project_fn(carry)
@@ -435,7 +700,7 @@ class _StepRunner:
         self.consts, self.akey = consts, akey
         self.carry = carry
         self.carry_cap, self.bc_targets = _carry_shape(carry)
-        self.flags: list = []
+        self.flags: list = []  # per step: the carry overflowed
         self._runs: dict = {}  # (capacity, window length) -> program
 
     def _program(self, cap: int, length: int):
@@ -446,7 +711,8 @@ class _StepRunner:
             # closures are those of the window's batch capacity
             fns = stage._chain_fns(segs, cap)
             key = (self.akey + ("step", self.carry_cap, self.bc_targets,
-                                cap, length) + stage._stage_key(segs, cap))
+                                cap, length)
+                   + stage._stage_key(segs, cap))
             carry_cap, bc_targets = self.carry_cap, self.bc_targets
             run = shared_jit(key, lambda: _make_step(
                 fns, agg, carry_cap, bc_targets))

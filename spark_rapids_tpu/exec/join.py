@@ -25,6 +25,7 @@ output from join row counts.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from functools import partial
 from typing import Iterator, List, Optional, Sequence, Tuple
@@ -40,9 +41,58 @@ from spark_rapids_tpu.exec import kernels as K
 from spark_rapids_tpu.exec.aggregate import concat_jit
 from spark_rapids_tpu.exprs import expr as E
 from spark_rapids_tpu.exprs import eval as EV
+from spark_rapids_tpu.obs import span as _span
 from spark_rapids_tpu.utils.sync import host_get
 
 JOIN_TYPES = ("inner", "left", "right", "full", "left_semi", "left_anti")
+
+# -- the build side's span and counters --------------------------------------
+
+BUILD_PATHS = ("dense", "unique", "ht", "sorted")
+_build_tls = threading.local()
+_build_lock = threading.Lock()
+_build_paths = dict.fromkeys(BUILD_PATHS, 0)
+
+
+def counters() -> dict:
+    """For obs/gauges.snapshot(): build sides by the probe structure they
+    ended in."""
+    with _build_lock:
+        out = {f"join_build_path_{p}_total": n
+               for p, n in _build_paths.items()}
+    out["join_build_path_total"] = sum(out.values())
+    return out
+
+
+@contextlib.contextmanager
+def build_span(join):
+    """One ``exec:join-build`` span around ``join``'s build side: executing
+    it and constructing the probe structure, the construction's host syncs
+    as its children. Yields the dict the span's attrs are taken from
+    (``path``, ``rows``, ``capacity``). The same join's build inside its
+    own build (a broadcast build re-prepared for a fused probe) fills the
+    outer one's, so a build side is one span and one count of
+    ``join_build_path_total``, under the path it is probed by; another
+    join's build below it (a join in the build subtree) is a span of its
+    own, a child of this one."""
+    outer = getattr(_build_tls, "open", None)
+    if outer is not None and outer[0] is join:
+        yield outer[1]
+        return
+    attrs = {}
+    _build_tls.open = (join, attrs)
+    try:
+        with _span.task_span("exec:join-build") as sp:
+            try:
+                yield attrs
+            finally:
+                if sp is not None:
+                    sp.attrs.update(attrs)
+    finally:
+        _build_tls.open = outer
+        if attrs.get("path") in _build_paths:
+            with _build_lock:
+                _build_paths[attrs["path"]] += 1
 
 
 class HashJoinExec(BinaryExec):
@@ -119,40 +169,19 @@ class HashJoinExec(BinaryExec):
 
     def do_execute(self, partition: int) -> Iterator[ColumnarBatch]:
         self._prepare()
-        with self.timer("buildTimeNs"):
-            # collect the build side as spillable handles: while later build
-            # batches are still being produced, earlier ones can shed to
-            # host/disk under pool pressure (same door as agg buckets and
-            # out-of-core sort runs), then re-materialize for the concat
-            from spark_rapids_tpu.mem.spill import SpillableBatch, get_framework
-
-            fw = get_framework()
-            handles = [SpillableBatch(b, fw)
-                       for b in self.right.execute(partition)]
-            try:
-                if handles:
-                    build_batches = [h.get() for h in handles]
-                    try:
-                        build = (build_batches[0] if len(build_batches) == 1
-                                 else concat_jit(build_batches))
-                    finally:
-                        for h in handles:
-                            h.unpin()
-                else:
-                    from spark_rapids_tpu.columnar.batch import empty_batch
-                    build = empty_batch(self.right.output_schema.types(), 16)
-            finally:
-                for h in handles:
-                    h.close()
-        # Peek one probe batch so the path decision happens at the probe's
-        # shape-class (plan/autotune.py): capacity is the log2 rows bucket
-        # and is static, so this costs no device sync.
-        probe_iter = self.left.execute(partition)
-        first = next(probe_iter, None)
-        probe_cap = first.capacity if first is not None else 16
-        with self.timer("buildTimeNs"):
-            (dense, table, ht, jh, path,
-             source, shape) = self._choose_path(build, probe_cap)
+        with build_span(self) as battrs:
+            with self.timer("buildTimeNs"):
+                build = self._collect_build(partition)
+            # Peek one probe batch so the path decision happens at the
+            # probe's shape-class (plan/autotune.py): capacity is the log2
+            # rows bucket and is static, so this costs no device sync.
+            probe_iter = self.left.execute(partition)
+            first = next(probe_iter, None)
+            probe_cap = first.capacity if first is not None else 16
+            with self.timer("buildTimeNs"):
+                (dense, table, ht, jh, path,
+                 source, shape) = self._choose_path(build, probe_cap)
+            battrs.update(path=path, capacity=build.capacity)
         build_matched = jnp.zeros(build.capacity, jnp.bool_)
         join_ns0 = self.metrics["joinTimeNs"].value
         probe_rows = 0
@@ -198,6 +227,32 @@ class HashJoinExec(BinaryExec):
             self, f"join:{self.join_type}", path, source, shape,
             ns=self.metrics["joinTimeNs"].value - join_ns0,
             rows=probe_rows)
+
+    def _collect_build(self, partition: int) -> ColumnarBatch:
+        """The build side as one batch. Its batches are collected as
+        spillable handles: while later ones are still being produced,
+        earlier ones can shed to host/disk under pool pressure (same door
+        as agg buckets and out-of-core sort runs), then re-materialize for
+        the concat."""
+        from spark_rapids_tpu.mem.spill import SpillableBatch, get_framework
+
+        fw = get_framework()
+        handles = [SpillableBatch(b, fw)
+                   for b in self.right.execute(partition)]
+        try:
+            if not handles:
+                from spark_rapids_tpu.columnar.batch import empty_batch
+                return empty_batch(self.right.output_schema.types(), 16)
+            build_batches = [h.get() for h in handles]
+            try:
+                return (build_batches[0] if len(build_batches) == 1
+                        else concat_jit(build_batches))
+            finally:
+                for h in handles:
+                    h.unpin()
+        finally:
+            for h in handles:
+                h.close()
 
     def _choose_path(self, build: ColumnarBatch, probe_cap: int):
         """Pick the probe structure for this partition: the static
@@ -249,6 +304,16 @@ class HashJoinExec(BinaryExec):
     # with STATIC output shapes: out_cap = probe capacity, no per-batch
     # candidate-count host sync, one compile per probe bucket. The ONLY
     # sync is the (dup_any, max_bucket) pair read once per build side.
+    #
+    # The rung has two layouts, by who probes. This operator, the mesh
+    # executor's broadcast join and the general path's sort keep the
+    # bucket-contiguous one (_prepare_table): five lanes of the build's
+    # capacity, 29 B a row up to 2^27 rows, a view of which IS the
+    # general path's JoinHashes when the keys repeat. The fused probe
+    # (_prepare_rows) reads fixed rows of a bucket's slots with one gather
+    # a probe row where this one makes two and five a slot: 256 B a row of
+    # the build's capacity (half as many buckets, each padded to 128
+    # words), so 4 GB at 2^24 rows against 0.5 GB, and no view to hand on.
 
     @property
     def _max_unique_slots(self) -> int:
@@ -281,6 +346,31 @@ class HashJoinExec(BinaryExec):
         # needs it static — it is a pure function of the build capacity
         tbl = tbl._replace(lg_b=K._join_lg_b(build.capacity))
         return tbl, slots
+
+    def _prepare_rows(self, build: ColumnarBatch,
+                      told: Optional[dict] = None):
+        """The unique table as the fused probe reads it (``K.join_row_slots``
+        + ``K.join_rows_table``): (rows, slots, lg_b), or None where the
+        keys repeat or a bucket outgrows ``join.uniqueTable.maxSlots``
+        (the general path's case). Two syncs a build side: the largest
+        bucket sizes the table's rows, then whether two rows share a key."""
+        if build.capacity > (1 << 24):
+            return None  # 256 B a row of capacity: 4 GB of table here
+        placed, largest = K.join_row_slots(build, tuple(self._rkeys))
+        mb, rows = host_get((largest, build.num_rows), "join.table_stats")
+        if told is not None:
+            told["rows"] = int(rows)
+        slots = 1
+        while slots < max(int(mb), 1):
+            slots *= 2
+        lg_b = K.join_rows_lg_b(build.capacity)
+        if (slots > self._max_unique_slots
+                or (K.ROW_WORDS * slots) << lg_b >= 1 << 31):
+            return None  # (the table's word offsets are int32)
+        tbl, twin = K.join_rows_table(placed, slots, lg_b)
+        if bool(host_get(twin, "join.table_dup")):
+            return None
+        return tbl, slots, lg_b
 
     def _join_batch_unique(self, probe: ColumnarBatch, build: ColumnarBatch,
                            table, build_matched, partition: int):
@@ -335,7 +425,8 @@ class HashJoinExec(BinaryExec):
     # statically bounded by the probe capacity (max one match per row).
     # cuDF has no analog (it cannot assume key density); the sorted-hash
     # path remains the general fallback.
-    def _prepare_dense(self, build: ColumnarBatch):
+    def _prepare_dense(self, build: ColumnarBatch,
+                       told: Optional[dict] = None):
         if len(self._rkeys) != 1:
             return None
         assert self.join_type in JOIN_TYPES  # all types have a dense impl
@@ -348,6 +439,8 @@ class HashJoinExec(BinaryExec):
         stats = host_get(_dense_key_stats(build, self._rkeys[0]),
                          "join.dense_key_stats")
         kmin, kmax, n_valid = (int(stats[0]), int(stats[1]), int(stats[2]))
+        if told is not None:
+            told.update(rows=n_valid, kmin=kmin, kmax=kmax)
         if n_valid == 0 or kmin < 0 or kmax >= self._dense_max_domain:
             return None
         size = bucket_capacity(kmax + 1, 16)
@@ -607,21 +700,27 @@ class HashJoinExec(BinaryExec):
         if self.join_type != "inner":
             return None
         self._prepare()
-        build = self._fused_build_side(partition)
-        if build is None:
-            return None  # classic path has the empty-build semantics
-        with self.timer("buildTimeNs"):
-            dense = self._prepare_dense(build)
-            slots = lg_b = None
-            if dense is not None:
-                kind, tbl = "dense", dense
-            else:
-                prep = self._prepare_table(build)
-                if prep is not None and not isinstance(prep, K.JoinHashes):
-                    kind, (tbl, slots) = "unique", prep
-                    lg_b = tbl.lg_b
+        with build_span(self) as battrs:
+            build = self._fused_build_side(partition)
+            if build is None:
+                return None  # classic path has the empty-build semantics
+            battrs["capacity"] = build.capacity
+            with self.timer("buildTimeNs"):
+                dense = self._prepare_dense(build, battrs)
+                slots = lg_b = None
+                if dense is not None:
+                    kind, tbl = "dense", dense
                 else:
-                    return None  # duplicate keys: per-batch host sync path
+                    prep = self._prepare_rows(build, battrs)
+                    if prep is None:
+                        # duplicate keys: per-batch host sync path; the
+                        # unfused operator builds again and counts there
+                        return None
+                    kind, (tbl, slots, lg_b) = "unique", prep
+                    tbl = (tbl,) + self._presence(build, battrs)
+            battrs["path"] = kind
+            battrs.pop("kmin", None)
+            battrs.pop("kmax", None)
         # longest build row per string column, read ONCE per build; byte
         # bounds for any probe capacity are then pure host arithmetic
         mls = {i: _max_row_bytes(c)
@@ -636,6 +735,23 @@ class HashJoinExec(BinaryExec):
                            AT.family_of(str(ls[i].dtype)
                                         for i in self._lkeys)))
         return _FusedJoinProbe(self, kind, build, tbl, slots, lg_b, mls)
+
+    PRESENCE_MAX_DOMAIN = 1 << 28  # one byte a possible key
+
+    def _presence(self, build: ColumnarBatch, told: dict) -> tuple:
+        """(present, first key): which keys of the build's range exist, one
+        byte a possible key, where the unique table's key is one integer
+        whose range was read (``_prepare_dense`` read it and found it past
+        the dense table's bound) and is at most ``PRESENCE_MAX_DOMAIN``
+        wide; else ``(None, None)``. The fused probe asks it first: one
+        gather of a byte a probe row says exactly which rows have a match,
+        and the table of rows is then probed by those alone."""
+        if "kmin" not in told or not (
+                0 <= told["kmax"] - told["kmin"] < self.PRESENCE_MAX_DOMAIN):
+            return None, None
+        size = bucket_capacity(told["kmax"] - told["kmin"] + 1, 16)
+        first = jnp.int64(told["kmin"])
+        return _presence_table(build, self._rkeys[0], first, size), first
 
     def _fused_build_side(self, partition: int) -> Optional[ColumnarBatch]:
         """Materialize the build side exactly as do_execute would see it.
@@ -685,10 +801,23 @@ class _FusedJoinProbe:
     """Stage segment for an absorbed inner join (HashJoinExec.fused_probe).
 
     Holds the materialized build side + probe table for one partition and
-    hands the fusion driver (exec/fused.py) a pure ``fn(batch, (build,
-    tbl))`` per probe capacity, plus the static key fragment that makes the
-    composed stage program shareable across identical plans.
-    """
+    hands the fusion driver (exec/fused.py) a pure ``fn(batch, live,
+    (build, tbl)) -> (batch, live, ran short?)`` per probe capacity (the
+    chain's protocol: ``_make_body``), plus the static key fragment that
+    makes the composed stage program shareable across identical plans.
+
+    The probe is written for a chip on which a gather costs about 9 ns an
+    index whatever it fetches and a sort of 2^20 (key, row id) pairs 1.3 ms
+    (v5e, PERF.md PR 35). At the probe batch's capacity it makes ONE gather
+    (the dense table's slot, or the unique table's whole bucket:
+    ``K.join_rows_table``) and one sort (``K.compact_indices`` of the
+    hits); everything after that, the pairs' gathers and the exact key
+    comparison, runs over ``shrink_to`` rows where the stage has learned
+    that the hits fit there (``out_cap``), and says so when they do not.
+    The unique table over one integer key is asked only about the rows a
+    presence table (``HashJoinExec._presence``: a byte a possible key, one
+    gather a row) says have a match, already compacted to ``shrink_to``.
+    ``live`` is the mask a filter below left instead of compacting."""
 
     def __init__(self, join: HashJoinExec, kind: str, build: ColumnarBatch,
                  tbl, slots, lg_b, mls):
@@ -700,13 +829,17 @@ class _FusedJoinProbe:
         self.lg_b = lg_b
         self._mls = mls  # string col -> longest build row in bytes
         self._bcaps = {}
+        self.shrink_to: Optional[int] = None  # the stage's (exec/fused.py)
 
     @property
     def consts(self):
         return (self.build, self.tbl)
 
     def out_cap(self, in_cap: int) -> int:
-        return in_cap  # dense/unique probes emit at most one row per row
+        # dense/unique probes emit at most one row per row
+        if self.shrink_to is not None and self.shrink_to < in_cap:
+            return self.shrink_to
+        return in_cap
 
     def _bcaps_t(self, out_cap: int) -> tuple:
         t = self._bcaps.get(out_cap)
@@ -717,43 +850,98 @@ class _FusedJoinProbe:
             self._bcaps[out_cap] = t
         return t
 
-    def key_part(self, out_cap: int) -> tuple:
+    def key_part(self, in_cap: int) -> tuple:
         j = self.op
+        out_cap = self.out_cap(in_cap)
         return ("join", self.kind, tuple(j._lkeys), tuple(j._rkeys),
                 j._cond_bound.cache_key() if j._cond_bound is not None
                 else None,
-                self.slots, self.lg_b, out_cap, self._bcaps_t(out_cap))
+                self.slots, self.lg_b, in_cap, out_cap,
+                self._bcaps_t(out_cap),
+                # the unique kind's presence table, or that it has none
+                tuple(None if a is None else a.shape
+                      for a in self.tbl[1:2]) if self.kind == "unique"
+                else None)
 
-    def probe_fn(self, out_cap: int):
+    def probe_fn(self, in_cap: int):
         join, kind = self.op, self.kind
+        out_cap = self.out_cap(in_cap)
         bt = self._bcaps_t(out_cap)
         lkeys, rkeys = tuple(join._lkeys), tuple(join._rkeys)
         cond = join._cond_bound
-        slots, lg_b = self.slots, self.lg_b
+        lg_b = self.lg_b
+        # the bucket's candidates matched on 128 hash bits; the exact key
+        # comparison (and a residual condition) runs on the pairs, before
+        # they are compacted where that leaves the batch's capacity and
+        # after (a second, small compaction) where it shrinks
+        check_pairs = kind == "unique" or cond is not None
 
-        def run(probe, consts):
+        def pairs_ok(pair, n_probe_cols):
+            ok = jnp.ones(pair.capacity, jnp.bool_)
+            if kind == "unique":
+                rows = jnp.arange(pair.capacity, dtype=jnp.int32)
+                ok = K.keys_equal(pair, rows, list(lkeys), pair, rows,
+                                  [n_probe_cols + k for k in rkeys])
+            if cond is not None:
+                cv = EV.eval_expr(cond, EV.EvalContext(pair))
+                ok = ok & cv.data & cv.validity
+            return ok
+
+        def run(probe, live, consts):
             build, tbl = consts
             cap = probe.capacity
             join._pcaps = {i: c.byte_capacity
                            for i, c in enumerate(probe.columns)
                            if c.offsets is not None}
             join._bcaps = dict(bt)
-            dummy = jnp.zeros(build.capacity, jnp.bool_)
+            pvalid = probe.active_mask() if live is None else live
+            for i in lkeys:
+                pvalid = pvalid & probe.columns[i].validity
+            short = jnp.bool_(False)
+            if kind == "unique":
+                tbl, present, first = tbl
+                if present is not None:
+                    # exactly the rows that have a match, by one gather of
+                    # a byte a row; the table is probed by those alone
+                    off = probe.columns[lkeys[0]].data.astype(
+                        jnp.int64) - first
+                    inb = pvalid & (off >= 0) & (off < present.shape[0])
+                    found = inb & present[
+                        jnp.where(inb, off, 0).astype(jnp.int32)]
+                    idx, n = K.compact_indices(found, out_cap)
+                    short = n > out_cap
+                    probe = K.gather_batch(probe, idx,
+                                           jnp.minimum(n, out_cap))
+                    pvalid, cap = probe.active_mask(), out_cap
             if kind == "dense":
-                pi, bi, hit, n_out, _m = _dense_probe(
-                    probe, build, tbl, lkeys[0], cond, "inner", dummy, bt)
-                bi_valid = bi >= 0
-                return join._gather_pairs(probe, build, pi,
-                                          jnp.where(bi_valid, bi, 0),
-                                          bi_valid, n_out, cap)
-            bi, hit, _m = _unique_probe(
-                probe, build, tbl, dummy, lkeys, rkeys, slots, lg_b,
-                cond, "inner", bt)
-            idx, n = K.filter_indices(hit, probe.active_mask())
-            bi_c = jnp.where(idx < cap, bi[jnp.clip(idx, 0, cap - 1)], 0)
-            return join._gather_pairs(
-                probe, build, idx, jnp.clip(bi_c, 0, None),
-                jnp.arange(cap, dtype=jnp.int32) < n, n, cap)
+                k64 = probe.columns[lkeys[0]].data.astype(jnp.int64)
+                inb = (k64 >= 0) & (k64 < tbl.shape[0])
+                bi = tbl[jnp.where(pvalid & inb, k64, 0).astype(jnp.int32)]
+                hit = pvalid & inb & (bi >= 0)
+            else:
+                bi, hit = K.probe_join_rows(
+                    tbl, lg_b, K.hash_keys(probe, list(lkeys)),
+                    K.hash_keys(probe, list(lkeys), variant=1), pvalid)
+            if check_pairs and out_cap * 4 > cap:
+                bcols = K.gather_columns(
+                    build.columns, jnp.where(hit, bi, 0), hit,
+                    [dict(self._bcaps_t(cap)).get(i)
+                     for i in range(len(build.columns))])
+                pair = ColumnarBatch(list(probe.columns) + list(bcols),
+                                     probe.num_rows)
+                hit = hit & pairs_ok(pair, len(probe.columns))
+            idx, n = K.compact_indices(hit, out_cap)
+            short = short | (n > out_cap)
+            n = jnp.minimum(n, out_cap)
+            row_live = jnp.arange(out_cap, dtype=jnp.int32) < n
+            bi_c = jnp.where(row_live, bi[idx], 0)  # masked by row_live
+            out = join._gather_pairs(probe, build, idx, bi_c, row_live, n,
+                                     out_cap)
+            if check_pairs and out_cap * 4 <= cap:
+                ok = row_live & pairs_ok(out, len(probe.columns))
+                idx2, n2 = K.compact_indices(ok, out_cap)
+                out = K.gather_batch(out, idx2, n2)
+            return out, None, short  # front-packed: no mask to hand on
         return run
 
 
@@ -769,6 +957,15 @@ def _pad_idx(idx: jax.Array, out_cap: int) -> jax.Array:
         return idx[:out_cap]
     pad = jnp.zeros(out_cap - idx.shape[0], jnp.int32)
     return jnp.concatenate([idx, pad])
+
+
+@partial(jax.jit, static_argnums=(1, 3))
+def _presence_table(build: ColumnarBatch, key: int, first, size: int):
+    c = build.columns[key]
+    live = c.validity & build.active_mask()
+    at = jnp.where(live, c.data.astype(jnp.int64) - first, size)
+    return jnp.zeros(size, jnp.bool_).at[at.astype(jnp.int32)].set(
+        True, mode="drop")
 
 
 @partial(jax.jit, static_argnums=(1,))

@@ -33,7 +33,8 @@ from spark_rapids_tpu.columnar.batch import (
 from spark_rapids_tpu.exec.base import BatchSourceExec, BinaryExec, TpuExec
 from spark_rapids_tpu.exec import kernels as K
 from spark_rapids_tpu.exec.aggregate import concat_jit
-from spark_rapids_tpu.exec.join import HashJoinExec, _null_column, _pad_idx
+from spark_rapids_tpu.exec.join import (
+    HashJoinExec, _null_column, _pad_idx, build_span)
 from spark_rapids_tpu.exprs import expr as E
 from spark_rapids_tpu.exprs import eval as EV
 from spark_rapids_tpu.utils.sync import host_get
@@ -110,7 +111,8 @@ class BroadcastHashJoinExec(HashJoinExec):
                             "ht" if shared[2] is not None else "sorted",
                             "default", shape)
                         return self._broadcast
-                with self.timer("broadcastTimeNs"):
+                with build_span(self) as battrs, \
+                        self.timer("broadcastTimeNs"):
                     batches = list(self.right.execute_all())
                     if batches:
                         build = (batches[0] if len(batches) == 1
@@ -130,6 +132,7 @@ class BroadcastHashJoinExec(HashJoinExec):
                     if ht is None:
                         jh = jax.jit(K.prepare_join_side, static_argnums=1)(
                             build, tuple(self._rkeys))
+                    battrs.update(path=path, capacity=build.capacity)
                 self._broadcast = (build, jh, ht)
                 self._bcast_decision = (path, source, shape)
                 if holder is not None:

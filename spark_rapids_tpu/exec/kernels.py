@@ -221,6 +221,7 @@ def gather_lanes(lanes: Sequence[jax.Array], idx: jax.Array) -> List[jax.Array]:
                                    lanes[k].dtype)
     return out  # type: ignore[return-value]
 
+
 @jax.named_scope("gather")
 def gather_columns(
     cols: Sequence[DeviceColumn],
@@ -243,6 +244,8 @@ def gather_columns(
                               out_byte_capacities[i]
                               if out_byte_capacities else None)
                 for i, c in enumerate(cols)]
+    if not cols:
+        return []
     safe_idx = jnp.where(row_valid, indices, 0).astype(jnp.int32)
     fixed = [i for i, c in enumerate(cols)
              if c.offsets is None and c.children is None]
@@ -486,6 +489,23 @@ def _lexsort_variadic_max() -> int:
     return _C.LEXSORT_VARIADIC_MAX.get(_C.get_active())
 
 
+def _sort_words(keys: Sequence[jax.Array]) -> List[jax.Array]:
+    """``keys`` (least-significant first) as native sort words, in the same
+    order: 64-bit integer keys are word-pair-emulated on the VPU (~18x the
+    cost of native u32), so each splits into (lo32, hi32), which give the
+    same total order under a stable LSD composition."""
+    flat: List[jax.Array] = []
+    for k in keys:
+        if k.dtype == jnp.int64:
+            k = k.astype(jnp.uint64) ^ jnp.uint64(_SIGN64)
+        if k.dtype == jnp.uint64:
+            flat.append((k & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32))
+            flat.append((k >> jnp.uint64(32)).astype(jnp.uint32))
+        else:
+            flat.append(k)
+    return flat
+
+
 @jax.named_scope("sort.lexsort")
 def lexsort_chain(keys: Sequence[jax.Array]) -> jax.Array:
     """Stable lexicographic argsort. Semantics match ``jnp.lexsort(keys)``
@@ -498,30 +518,33 @@ def lexsort_chain(keys: Sequence[jax.Array]) -> jax.Array:
     within ties).
     """
     assert keys, "lexsort_chain needs at least one key"
-
-    def passes(k: jax.Array) -> List[jax.Array]:
-        # 64-bit integer sorts are word-pair-emulated on the VPU (~18x the
-        # cost of native u32): split into (lo32, hi32) passes, which give
-        # the same total order under the stable LSD composition
-        if k.dtype == jnp.int64:
-            k = k.astype(jnp.uint64) ^ jnp.uint64(_SIGN64)
-        if k.dtype == jnp.uint64:
-            lo = (k & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
-            hi = (k >> jnp.uint64(32)).astype(jnp.uint32)
-            return [lo, hi]
-        return [k]
-
-    flat: List[jax.Array] = []  # least-significant first
-    for k in keys:
-        flat.extend(passes(k))
+    flat = _sort_words(keys)
     n = flat[0].shape[0]
     row_ids = jnp.arange(n, dtype=jnp.int32)
     if len(flat) <= _lexsort_variadic_max():
         operands = tuple(reversed(flat)) + (row_ids,)
         out = jax.lax.sort(operands, num_keys=len(flat), is_stable=True)
         return out[-1]
-    perm = row_ids
-    for i, k in enumerate(flat):
+    return lsd_order(flat)
+
+
+def lsd_order(words: Sequence[jax.Array]) -> jax.Array:
+    """Stable argsort by ``words`` (least-significant first) as a chain of
+    single-key stable sorts, each carrying the permutation as its payload:
+    one gather a pass, and ONE sort signature (key, int32 payload) however
+    many words there are.
+
+    The TPU compiler's bill for a sort is per distinct signature (operand
+    count, types, length), grows steeply with the operand count and hardly
+    with the length (sandbox compile for a described v5e, PR 35: one u32
+    key and a payload 18 s at 2^17 rows and 24 s at 2^20; three u32 keys
+    and a payload 88 s, 108 s, and 132 s at 2^23; two u64 keys and a
+    payload, the join build's former ``jnp.lexsort``, 214 s at 2^21; four
+    identical sorts in one program cost what one does). So whatever sorts
+    at a batch's capacity inside a fused stage or a join build goes
+    through here."""
+    perm = jnp.arange(words[0].shape[0], dtype=jnp.int32)
+    for i, k in enumerate(words):
         kg = k if i == 0 else k[perm]
         _, perm = jax.lax.sort((kg, perm), num_keys=1, is_stable=True)
     return perm
@@ -558,14 +581,73 @@ def sort_indices(
         packed = packed_sort_keys(batch, specs)
         if packed is not None:
             return lexsort_chain(packed).astype(jnp.int32)
+    keys = _spec_keys(batch, specs)
+    keys.append(jnp.where(active, jnp.uint32(0), jnp.uint32(1)))  # padding last
+    return lexsort_chain(keys).astype(jnp.int32)
+
+
+def _spec_keys(batch: ColumnarBatch, specs: Sequence[SortSpec]
+               ) -> List[jax.Array]:
+    """The lexsort keys of ``specs``: LAST key is primary, so the
+    least-significant spec comes first."""
     keys: List[jax.Array] = []
-    # lexsort: LAST key is primary -> emit least-significant spec first
     for spec in reversed(list(specs)):
         keys.extend(sortable_keys(batch.columns[spec.column], spec.ascending,
                                   spec.nulls_first,
                                   getattr(spec, "str_words", 2)))
-    keys.append(jnp.where(active, jnp.uint32(0), jnp.uint32(1)))  # padding last
-    return lexsort_chain(keys).astype(jnp.int32)
+    return keys
+
+
+def topn_select_max_k(capacity: int) -> int:
+    """The largest k for which ``topn_indices`` is taken over a full sort
+    of ``capacity`` rows: a selection round reads every key word once or
+    twice, as one compare-exchange stage of a sorting network over the same
+    words does, and a network of 2^c rows has c(c+1)/2 stages; past that
+    many rounds the sort moves less (55 at 2^10 rows, 153 at 2^17, 210 at
+    2^20)."""
+    c = max(int(capacity) - 1, 1).bit_length()
+    return c * (c + 1) // 2
+
+
+@jax.named_scope("sort.topn")
+def topn_indices(batch: ColumnarBatch, specs: Sequence[SortSpec],
+                 k: jax.Array, out_cap: int) -> Tuple[jax.Array, jax.Array]:
+    """The first ``k`` entries of ``sort_indices(batch, specs)`` without the
+    sort: ``k`` rounds of selection. A round narrows the rows not yet taken
+    to those that hold the least value of the most significant key word,
+    then of the next word among those, and so on; of the rows equal on
+    every word it takes the one with the lowest index, which is where a
+    stable sort puts it. Exact on ties, nulls and NaNs because the words
+    are the sort's own (``sortable_keys``). Elementwise passes and
+    reductions only, under one ``while`` loop: the program is the same for
+    every ``k`` (traced; at most ``out_cap``) and its compile cost does not
+    grow with the batch, as a variadic sort's does (PERF.md). Returns
+    (indices padded to ``out_cap``, rows taken = min(k, live rows))."""
+    cap = batch.capacity
+    live = batch.active_mask()
+    words = _sort_words(_spec_keys(batch, specs))[::-1]  # primary first
+    rows = jnp.arange(cap, dtype=jnp.int32)
+
+    def ceiling(w):
+        if jnp.issubdtype(w.dtype, jnp.floating):
+            return jnp.array(jnp.inf, w.dtype)
+        return jnp.array(jnp.iinfo(w.dtype).max, w.dtype)
+
+    def pick(i, state):
+        taken, out = state
+        cand = live & ~taken
+        for w in words:
+            least = jnp.min(jnp.where(cand, w, ceiling(w)))
+            cand = cand & (w == least)
+        j = jnp.min(jnp.where(cand, rows, jnp.int32(cap)))  # cap: none left
+        return taken | (rows == j), out.at[i].set(
+            jnp.where(j < cap, j, 0), mode="drop")
+
+    n = jnp.minimum(k.astype(jnp.int32), batch.num_rows.astype(jnp.int32))
+    _, out = jax.lax.fori_loop(
+        0, n, pick, (jnp.zeros(cap, jnp.bool_),
+                     jnp.zeros(out_cap, jnp.int32)))
+    return out, n
 
 
 def str_key_words(batch: ColumnarBatch, specs: Sequence[SortSpec],
@@ -830,6 +912,29 @@ def filter_indices(keep: jax.Array, active: jax.Array) -> Tuple[jax.Array, jax.A
     return out, jnp.sum(k).astype(jnp.int32)
 
 
+@jax.named_scope("filter.compact")
+def compact_indices(keep: jax.Array, out_cap: int
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """``filter_indices`` by ONE stable sort of (dropped?, row id) instead
+    of a cumsum and a scatter, cut to the first ``out_cap`` slots: (indices,
+    rows kept). On the v5e a sort of a u32 key with an int32 payload over
+    2^20 rows takes 1.3 ms and a scatter of as many int32 4.9 ms (my chip
+    run, PR 35; PERF.md); the sort's signature is ``lsd_order``'s, so a
+    program that groups pays nothing more to compile it. ``keep`` already
+    holds what is live. Slots past the count hold dropped rows' ids: valid
+    indices, masked by the count as ``filter_indices``' are. A count above
+    ``out_cap`` means rows were cut: the caller's to flag."""
+    perm = lsd_order([jnp.where(keep, jnp.uint32(0), jnp.uint32(1))])
+    return fit_indices(perm, out_cap), jnp.sum(keep).astype(jnp.int32)
+
+
+def fit_indices(idx: jax.Array, out_cap: int) -> jax.Array:
+    """An index vector cut, or padded with row 0, to ``out_cap`` slots."""
+    if idx.shape[0] >= out_cap:
+        return idx[:out_cap]
+    return jnp.concatenate([idx, jnp.zeros(out_cap - idx.shape[0], idx.dtype)])
+
+
 # ---------------------------------------------------------------------------
 # Group-by: sort-based segmented aggregation
 # ---------------------------------------------------------------------------
@@ -883,12 +988,21 @@ def group_rows(batch: ColumnarBatch, key_cols: Sequence[int],
         perm = lexsort_chain(keys).astype(jnp.int32)
         neq = _neighbor_key_neq(batch, key_cols, perm, extra=(h1, h2))
         return _group_from_boundaries(perm, neq, active, cap)
-    h = hash_keys(batch, key_cols)
-    keys: List[jax.Array] = [h]
-    keys.append(jnp.where(active, jnp.uint32(0), jnp.uint32(1)))
-    perm = lexsort_chain(keys).astype(jnp.int32)
+    perm = _hash_order(hash_keys(batch, key_cols), active)
     neq = _neighbor_key_neq(batch, key_cols, perm)
     return _group_from_boundaries(perm, neq, active, cap)
+
+
+def _hash_order(h: jax.Array, active: jax.Array) -> jax.Array:
+    """Rows in the order of 63 bits of their hash, inactive rows last: two
+    passes of ``lsd_order``, low word then high word (the padding flag
+    rides in the high word's top bit). The variadic (hash lo, hash hi,
+    flag, row) sort it replaces made every program that groups pay the
+    four-operand price once per capacity it groups at."""
+    lo = (h & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
+    hi = jnp.where(active, (h >> jnp.uint64(33)).astype(jnp.uint32),
+                   jnp.uint32(0xFFFFFFFF))
+    return lsd_order([lo, hi])
 
 
 
@@ -1227,30 +1341,35 @@ def dense_segment_reduce(rows: Sequence[jax.Array],
 
 
 @jax.named_scope("agg.reduce")
-def segment_sum_int128(hi: jax.Array, lo: jax.Array, seg_ids: jax.Array,
-                       num_segments: int):
-    """Scatter-based exact 128-bit segment sums for (hi, lo) columns
-    (merge passes over small partial batches; the dense MXU path handles
-    the large first pass).  Decomposes lo into 32-bit halves so int64
-    scatter-adds cannot lose carries (n < 2^31)."""
+def sorted_segment_sum_int128(hi: jax.Array, lo: jax.Array, live: jax.Array,
+                              starts: jax.Array, ends: jax.Array):
+    """Exact 128-bit sums of (hi, lo) rows over SORTED segments, and the
+    live rows of each: (hi, lo, count) at the segments' ids. ``starts`` and
+    ``ends`` are each segment's first and last row (``GroupInfo``,
+    ``segment_ends``); rows that do not contribute come in as zeros.
+
+    A segment's total is the running sum at its end less the running sum
+    before its start: four running sums (the low limb in 32-bit halves so
+    no carry is lost: n < 2^31) and two packed gathers; three scatter-adds
+    of int64 (93 ms each for 2^20 rows on the v5e) did it before."""
+    n = hi.shape[0]
     lo_u = lo.astype(jnp.uint64)
-    lo0 = (lo_u & jnp.uint64(0xFFFFFFFF)).astype(jnp.int64)
-    lo1 = (lo_u >> 32).astype(jnp.int64)
-    s_lo0 = jax.ops.segment_sum(lo0, seg_ids, num_segments=num_segments)
-    s_lo1 = jax.ops.segment_sum(lo1, seg_ids, num_segments=num_segments)
-    s_hi = jax.ops.segment_sum(hi, seg_ids, num_segments=num_segments)
+    lanes = [(lo_u & jnp.uint64(0xFFFFFFFF)).astype(jnp.int64),
+             (lo_u >> jnp.uint64(32)).astype(jnp.int64), hi,
+             live.astype(jnp.int64)]
+    run = [jnp.cumsum(v) for v in lanes]
+    at_end = gather_lanes(run, jnp.clip(ends, 0, n - 1))
+    before = gather_lanes([r - v for r, v in zip(run, lanes)],
+                          jnp.clip(starts, 0, n - 1))
+    s_lo0, s_lo1, s_hi, count = (e - b for e, b in zip(at_end, before))
     from spark_rapids_tpu.exec import int128 as I128
 
-    # total_lo_u = s_lo0 + s_lo1 * 2^32 as 128-bit
-    h = (s_lo1.astype(jnp.uint64) >> 32).astype(jnp.int64)
-    l = (s_lo1.astype(jnp.uint64) << 32).astype(jnp.int64)
+    # total_lo_u = s_lo0 + s_lo1 * 2^32 as 128 bits, + s_hi * 2^64 (mod
+    # 2^128: only the hi limb)
+    h = (s_lo1.astype(jnp.uint64) >> jnp.uint64(32)).astype(jnp.int64)
+    l = (s_lo1.astype(jnp.uint64) << jnp.uint64(32)).astype(jnp.int64)
     h2, l2 = I128.add(h, l, jnp.zeros_like(s_lo0), s_lo0)
-    # + s_hi * 2^64 (mod 2^128: only the hi limb) ... but s_hi summed lo's
-    # SIGNED values? No: hi rows are the stored signed hi limbs; their sum
-    # mod 2^64 is the hi contribution. Residue correction: none needed for
-    # lo (we summed unsigned halves exactly).
-    h3 = h2 + s_hi
-    return h3, l2
+    return h2 + s_hi, l2, count
 
 
 # ---------------------------------------------------------------------------
@@ -1267,8 +1386,11 @@ def concat_device(
     """Concatenate batches entirely on device (no host round trip).
 
     The reference concatenates on device via cudf Table.concatenate
-    (GpuCoalesceBatches.scala:160); here each input's live rows are scattered
-    to a running offset. Capacities are static; live row counts are traced.
+    (GpuCoalesceBatches.scala:160); here each input's live rows are placed
+    at a running offset. Capacities are static; live row counts are traced.
+    Rows past ``out_capacity`` (a caller that packs into less than the
+    capacities' sum and guessed too low) are dropped: ``num_rows`` stays
+    the true total, which is how the caller sees it.
     """
     ncols = len(batches[0].columns)
     total_rows = jnp.int32(0)
@@ -1276,24 +1398,33 @@ def concat_device(
     for b in batches:
         starts.append(total_rows)
         total_rows = total_rows + b.num_rows
+    room = max(b.capacity for b in batches)
+
+    def place(lanes):
+        """One fixed-width lane of every input, live rows only, end to
+        end: each input is written whole (its padding zeroed) at its
+        offset, in order, so a later input's live rows overwrite only an
+        earlier one's padding. Contiguous copies at a dynamic offset, not
+        scatters (which serialize on the TPU); the buffer's tail of
+        ``room`` slots takes whatever starts past ``out_capacity``."""
+        buf = jnp.zeros(out_capacity + room, lanes[0].dtype)
+        for b, st, lane in zip(batches, starts, lanes):
+            live = jnp.arange(lane.shape[0], dtype=jnp.int32) < b.num_rows
+            buf = jax.lax.dynamic_update_slice(
+                buf, jnp.where(live, lane, jnp.zeros_like(lane)),
+                (jnp.minimum(st, out_capacity),))
+        return buf[:out_capacity]
+
     out_cols: List[DeviceColumn] = []
     for ci in range(ncols):
         dtype = batches[0].columns[ci].dtype
         is_string = batches[0].columns[ci].offsets is not None
         if not is_string:
-            data = jnp.zeros(out_capacity, batches[0].columns[ci].data.dtype)
-            validity = jnp.zeros(out_capacity, jnp.bool_)
+            data = place([b.columns[ci].data for b in batches])
+            validity = place([b.columns[ci].validity for b in batches])
             wide = batches[0].columns[ci].data2 is not None
-            data2 = jnp.zeros(out_capacity, jnp.int64) if wide else None
-            for b, st in zip(batches, starts):
-                c = b.columns[ci]
-                j = jnp.arange(c.capacity, dtype=jnp.int32)
-                live = j < b.num_rows
-                pos = jnp.where(live, st + j, out_capacity)  # OOB drops
-                data = data.at[pos].set(c.data, mode="drop")
-                validity = validity.at[pos].set(c.validity, mode="drop")
-                if wide:
-                    data2 = data2.at[pos].set(c.data2, mode="drop")
+            data2 = (place([b.columns[ci].data2 for b in batches])
+                     if wide else None)
             # dict codes concat only when every input shares one dictionary
             # (the concat_jit host wrapper decodes mismatched dicts first)
             first = batches[0].columns[ci]
@@ -1459,15 +1590,20 @@ def build_join_table(batch: ColumnarBatch, key_cols: Tuple[int, ...]):
     for i in key_cols:
         valid = valid & batch.columns[i].validity
     h1m = jnp.where(valid, h1, jnp.uint64(0xFFFFFFFFFFFFFFFF))
-    order = jnp.lexsort((h2, h1m)).astype(jnp.int32)
+    # (h1m, h2) order by four single-key passes, not one variadic sort of
+    # two u64 keys (lsd_order: the compiler's bill)
+    order = lsd_order(_sort_words([h2, h1m]))
     sh1 = h1m[order]
     sh2 = h2[order]
     sv = valid[order]
-    bucket = (sh1 >> jnp.uint64(64 - lg_b)).astype(jnp.uint32)
+    bucket = (sh1 >> jnp.uint64(64 - lg_b)).astype(jnp.int32)
     B = 1 << lg_b
-    starts = jnp.searchsorted(
-        bucket, jnp.arange(B + 1, dtype=jnp.uint32), side="left"
-    ).astype(jnp.int32)
+    # a bucket starts where the rows of the buckets before it end: one
+    # histogram and its running sum (a searchsorted of B + 1 needles is a
+    # loop of gathers: 158 ms for 2^20 in 2^20 on the v5e, PERF.md PR 35)
+    sizes = jnp.zeros(B, jnp.int32).at[bucket].add(1)
+    starts = jnp.concatenate(
+        [jnp.zeros(1, jnp.int32), jnp.cumsum(sizes).astype(jnp.int32)])
     # exact duplicate-key detection: equal adjacent (h1,h2) pairs verified
     # by full key equality (adjacency is sufficient — equal keys hash equal
     # and the sort groups equal (h1,h2))
@@ -1521,6 +1657,134 @@ def probe_join_table_unique(probe: ColumnarBatch, tbl: JoinTable,
     first = jnp.argmax(ok, axis=1)
     bi = jnp.where(hit, rows[jnp.arange(cap_p), first], -1)
     return bi.astype(jnp.int32), hit
+
+
+ROW_WORDS = 5  # an entry of the row table: h1 lo, h1 hi, h2 lo, h2 hi, row
+
+
+def join_rows_lg_b(capacity: int) -> int:
+    """log2 of the row table's bucket count: half the build's capacity, so
+    a full build puts two rows in a bucket on average and its largest
+    bucket stays under ``join.uniqueTable.maxSlots`` (16). A bucket is a
+    row of ``slots * ROW_WORDS`` words, which the TPU's layout pads to 128:
+    more buckets than this buy shorter rows that cost the same memory."""
+    return max(int(capacity - 1).bit_length() - 1, 4)
+
+
+@partial(jax.jit, static_argnums=(1,))
+@jax.named_scope("join.build")
+def join_row_slots(batch: ColumnarBatch, key_cols: Tuple[int, ...]):
+    """First half of the row table's build: each build row's slot.
+
+    Returns ((h1, h2, valid, bucket, rank), largest bucket): rows with a
+    valid key fall into the bucket the top ``join_rows_lg_b`` bits of h1
+    name; ``rank`` is a row's position among its bucket's rows. ONE sort of
+    (bucket, row id) puts a bucket's rows side by side, a running maximum
+    of the runs' first positions ranks them, and a second sort, of (row
+    id, rank), brings the ranks back into row order: no gather, no scatter
+    (``lsd_order``'s signature both times). The caller reads the largest
+    bucket, sizes the table's rows by it, and calls ``join_rows_table``."""
+    cap = batch.capacity
+    lg_b = join_rows_lg_b(cap)
+    h1 = hash_keys(batch, list(key_cols))
+    h2 = hash_keys(batch, list(key_cols), variant=1)
+    valid = batch.active_mask()
+    for i in key_cols:
+        valid = valid & batch.columns[i].validity
+    B = 1 << lg_b
+    bucket = jnp.where(valid, (h1 >> jnp.uint64(64 - lg_b)).astype(jnp.int32),
+                       B)  # rows without a key sort past every bucket
+    i = jnp.arange(cap, dtype=jnp.int32)
+    sb, perm = jax.lax.sort((bucket.astype(jnp.uint32), i), num_keys=1,
+                            is_stable=True)
+    first = jnp.concatenate([jnp.ones(1, jnp.bool_), sb[1:] != sb[:-1]])
+    run_start = jax.lax.cummax(jnp.where(first, i, 0))
+    _, rank = jax.lax.sort((perm.astype(jnp.uint32), i - run_start),
+                           num_keys=1, is_stable=True)
+    largest = jnp.max(jnp.where(valid, rank, -1)) + 1
+    return (h1, h2, valid, bucket, rank), largest
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+@jax.named_scope("join.build")
+def join_rows_table(placed, slots: int, lg_b: int):
+    """Second half: the buckets as fixed rows, for a probe that reads a
+    bucket with ONE gather: (2^lg_b, ROW_WORDS * slots) uint32, a bucket's
+    row holding its ``slots`` entries word by word (every h1 lo, then
+    every h1 hi, h2 lo, h2 hi, then the build rows' ids, -1 where the
+    bucket is shorter). Also whether two build rows share a 128-bit hash
+    pair: every build row is probed against the table, and one that finds
+    another row before itself has a twin. Two rows with one key always
+    share the pair, so a table without twins holds unique keys, and one
+    with them is refused as duplicate-keyed (rows of different keys and
+    one hash pair: the engine-wide treat-as-exact bar of ``group_rows``).
+
+    On the v5e a gather costs about 9 ns an index whatever it fetches and
+    a sort of 2^20 (key, row id) pairs 1.3 ms (my chip runs, PR 35;
+    PERF.md): the bucket-contiguous layout's build (``build_join_table``)
+    sorts by both hashes and gathers every lane into that order, and its
+    probe (``probe_join_table_unique``) makes two gathers a probe row for
+    the bucket's range and five a slot. Every array here is long in its
+    last axis: the TPU pads a last axis of 5 to 128."""
+    h1, h2, valid, bucket, rank = placed
+    B = 1 << lg_b
+    n = h1.shape[0]
+    width = ROW_WORDS * slots
+    at = jnp.where(valid & (rank < slots), bucket * width + rank, B * width)
+    words = _sort_words([h1, h2]) + [jnp.arange(n, dtype=jnp.uint32)]
+    # ONE flat table, scattered into word by word: a (B, slots) array a
+    # word would be padded to 128 lanes each (2.5 GB at 2^20 x 16 slots)
+    flat = jnp.tile(jnp.concatenate([
+        jnp.zeros(4 * slots, jnp.uint32),
+        jnp.full(slots, 0xFFFFFFFF, jnp.uint32)]), B)
+    for k, w in enumerate(words):
+        flat = flat.at[jnp.where(at < B * width, at + k * slots,
+                                 B * width)].set(w, mode="drop")
+    rows = flat.reshape(B, width)
+    # the build probes its own table a piece at a time: a fetched bucket is
+    # padded to 128 words a row, 1 GB for 2^21 rows at once (on the chip the
+    # whole-build probe wanted 6 GB beside 10.5 GB of resident tables)
+    piece = min(n, 1 << 17)
+
+    def twins(args):
+        ph1, ph2, ok, first = args
+        bi, hit = probe_join_rows(rows, lg_b, ph1, ph2, ok)
+        return jnp.any(hit & (bi != first + jnp.arange(piece,
+                                                       dtype=jnp.int32)))
+    firsts = jnp.arange(0, n, piece, dtype=jnp.int32)
+    twin = jnp.any(jax.lax.map(twins, (
+        h1.reshape(-1, piece), h2.reshape(-1, piece),
+        valid.reshape(-1, piece), firsts)))
+    return rows, twin
+
+
+@jax.named_scope("join.probe")
+def probe_join_rows(rows: jax.Array, lg_b: int, ph1: jax.Array,
+                    ph2: jax.Array, pvalid: jax.Array
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """(build row or -1, candidate?) per probe row against
+    ``join_rows_table``'s table: one gather of the probe row's bucket, then
+    a compare of the 128-bit hash pair over its slots in registers; the
+    first slot that matches wins. A candidate still has to pass the exact
+    key comparison (the caller's, over the few rows that are left once the
+    candidates are compacted)."""
+    slots = rows.shape[1] // ROW_WORDS
+    b = (ph1 >> jnp.uint64(64 - lg_b)).astype(jnp.int32)
+    # (ROW_WORDS * slots, n): a word of a slot is then a contiguous lane; a
+    # column of the fetched (n, words) array is a pass over all of it
+    # (2.3 ms each for 2^20 rows: 92 ms a batch at 8 slots on the v5e)
+    ent = jnp.transpose(jnp.take(rows, b, axis=0, mode="clip"))
+    words = _sort_words([ph1, ph2])
+    bi = jnp.full(ph1.shape[0], -1, jnp.int32)
+    hit = jnp.zeros(ph1.shape[0], jnp.bool_)
+    for s in range(slots):
+        row = jax.lax.bitcast_convert_type(ent[4 * slots + s], jnp.int32)
+        ok = (row >= 0) & pvalid
+        for k, w in enumerate(words):
+            ok = ok & (ent[k * slots + s] == w)
+        bi = jnp.where(ok & ~hit, row, bi)
+        hit = hit | ok
+    return bi, hit
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,7 @@ from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.base import LeafExec, TpuExec, UnaryExec
 from spark_rapids_tpu.exec import kernels as K
 from spark_rapids_tpu.exec.aggregate import concat_jit
-from spark_rapids_tpu.exec.sort import SortExec, SortOrder
+from spark_rapids_tpu.exec.sort import SortOrder, TopNExec
 from spark_rapids_tpu.exec.project import ProjectExec
 from spark_rapids_tpu.exec.join import _pad_idx
 from spark_rapids_tpu.exprs import expr as E
@@ -174,10 +174,14 @@ def take_ordered_and_project(orders: Sequence[SortOrder], limit: int,
                              child: TpuExec,
                              project: Optional[Sequence[E.Expression]] = None
                              ) -> TpuExec:
-    """GpuTakeOrderedAndProjectExec analog: per-partition sort+limit, then a
-    single-partition merge sort + limit + optional projection."""
-    local = LocalLimitExec(limit, SortExec(orders, child))
-    merged = GlobalLimitExec(limit, SortExec(orders, _Gather(local)))
+    """GpuTakeOrderedAndProjectExec analog: a top-N per partition
+    (exec/sort.py ``TopNExec``: the k best rows, never a sort of the
+    partition), then, where there are several partitions, a top-N of their
+    gathered results, + optional projection."""
+    several = child.num_partitions() > 1
+    merged = TopNExec(orders, limit, child, partial=several)
+    if several:
+        merged = TopNExec(orders, limit, _Gather(merged))
     if project is not None:
         return ProjectExec(project, merged)
     return merged

@@ -55,6 +55,14 @@ class ProjectExec(UnaryExec):
     def node_description(self) -> str:
         return f"TpuProject [{', '.join(map(repr, self.exprs))}]"
 
+    @property
+    def row_preserving(self) -> bool:
+        """An output row is computed from its input row alone, and for a
+        row that a filter below has dropped but not yet compacted away
+        (exec/fused.py ``_OpSeg``) nothing can go wrong: outside ANSI mode
+        an expression gives NULL where ANSI would raise."""
+        return not self._ansi
+
     def batch_fn(self):
         self._bind()
         bound, ansi = self._bound, self._ansi
@@ -110,6 +118,21 @@ class FilterExec(UnaryExec):
             idx, n = K.filter_indices(keep, batch.active_mask())
             return K.gather_batch(batch, idx, n)
         return run
+
+    def mask_fn(self):
+        """fn(batch) -> the rows the predicate keeps, for a fused stage
+        that lets the next consumer of a mask do the compacting; None in
+        ANSI mode, where the predicate may not be evaluated for a row an
+        earlier filter dropped."""
+        if self._ansi:
+            return None
+        self._bind()
+        bound = self._bound
+
+        def keep(batch):
+            pred = EV.eval_expr(bound, EV.EvalContext(batch, False))
+            return pred.data & pred.validity
+        return keep
 
     def batch_fn_key(self) -> tuple:
         if self._bound is None:

@@ -34,6 +34,32 @@ def _sort_run(batch: ColumnarBatch, specs, path: str = "lex"):
     return K.gather_batch(batch, idx, batch.num_rows)
 
 
+@partial(jax.jit, static_argnums=(1, 3))
+def _topn_run(batch: ColumnarBatch, specs, k, out_cap: int):
+    """The ``k`` first rows of the sorted batch, in order, by selection."""
+    idx, n = K.topn_indices(batch, specs, k, out_cap)
+    return K.gather_batch(batch, idx, n)
+
+
+@partial(jax.jit, static_argnums=(1, 3))
+def _sorted_head(batch: ColumnarBatch, specs, k, out_cap: int):
+    """The same rows by a full sort: for a ``k`` past the selection's
+    bound (``K.topn_select_max_k``), where the sort moves less."""
+    idx = K.sort_indices(batch, specs)
+    n = jnp.minimum(k.astype(jnp.int32), batch.num_rows.astype(jnp.int32))
+    return K.gather_batch(batch, K.fit_indices(idx, out_cap), n)
+
+
+def topn_batch(batch: ColumnarBatch, specs, limit: int,
+               out_cap: int) -> ColumnarBatch:
+    """The ``limit`` first rows of the sorted batch in a batch of
+    ``out_cap`` rows: by selection while ``limit`` is within the bound the
+    batch's capacity gives, by a full sort past it."""
+    run = (_topn_run if limit <= K.topn_select_max_k(batch.capacity)
+           else _sorted_head)
+    return run(batch, specs, jnp.int32(limit), out_cap)
+
+
 @partial(jax.jit, static_argnums=(2, 3, 4))
 def _merge_gather(merged: ColumnarBatch, pieces, col: int, ascending: bool,
                   nulls_first):
@@ -216,6 +242,60 @@ class SortExec(UnaryExec):
         AT.record_decision(
             self, "sort", path, source, shape,
             ns=self.metrics["sortTimeNs"].value - ns0, rows=whole.capacity)
+
+
+class TopNExec(SortExec):
+    """ORDER BY ... LIMIT k (GpuTakeOrderedAndProjectExec / GpuTopN analog):
+    the k first rows of each partition in sort order, never a sort of the
+    partition.
+
+    Per input batch one dispatch keeps the batch's k best rows (a row
+    outside its batch's k best is outside the partition's); the partial
+    results, each sorted and at most k rows in a bucket of k's capacity,
+    are concatenated in arrival order and reduced the same way once. Ties
+    go to the earlier batch and the lower row, which is what the stable
+    sort of the concatenated partition gives, so the rows and their order
+    equal ``SortExec`` + limit on every input. A dispatch selects
+    (``K.topn_indices``) while k is within ``K.topn_select_max_k`` of the
+    batch's capacity and sorts the batch past it: the bound is derived
+    there, not configured."""
+
+    def __init__(self, orders: Sequence[SortOrder], limit: int,
+                 child: TpuExec, partial: bool = False):
+        super().__init__(orders, child)
+        self.limit = max(int(limit), 0)
+        # partial: a TopNExec above a gather reduces this one's results
+        # again, so any split of the rows may run it (the mesh executor
+        # lowers it per device)
+        self.partial = partial
+        self._register_metric("numTopNDispatches")
+
+    def node_description(self) -> str:
+        return (f"TpuTopN {self.limit} "
+                f"[{', '.join(map(repr, self.orders))}]")
+
+    def _best(self, batch: ColumnarBatch) -> ColumnarBatch:
+        from spark_rapids_tpu.obs import span as _span
+        out_cap = bucket_capacity(max(self.limit, 1))
+        with _span.task_span("exec:topn", attrs={
+                "k": self.limit, "rows": batch.capacity,
+                "capacity": out_cap}), self.timer("sortTimeNs"):
+            out = topn_batch(batch, self._batch_specs(batch), self.limit,
+                             out_cap)
+        self.metrics["numTopNDispatches"].add(1)
+        return out
+
+    def do_execute(self, partition: int) -> Iterator[ColumnarBatch]:
+        self._prepare()
+        partials = [self._best(b) for b in self.child.execute(partition)]
+        if not partials:
+            return
+        if len(partials) == 1:
+            yield partials[0]
+            return
+        # each partial holds at most k live rows in k's bucket: pack them
+        yield self._best(concat_jit(partials, out_capacity=bucket_capacity(
+            len(partials) * max(self.limit, 1))))
 
 
 # ---------------------------------------------------------------------------
@@ -456,7 +536,7 @@ def _slice_rows(batch: ColumnarBatch, start, count, cap: int, byte_caps):
 # type_support declarations (spark_rapids_tpu.support)
 from spark_rapids_tpu.support import ORDERABLE, ts  # noqa: E402
 
-SortExec.type_support = ts(
+TopNExec.type_support = SortExec.type_support = ts(
     ORDERABLE, "string",
     note="string keys widened to str_words words (conf "
     "spark.rapids.tpu.sql.sort.stringKeyMaxWords); payload columns may be "

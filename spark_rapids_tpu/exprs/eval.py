@@ -1777,7 +1777,53 @@ def _eval_arith(expr: E.BinaryArithmetic, ctx: EvalContext) -> ColVal:
     raise NotImplementedError(expr.symbol)
 
 
+#: entries a dict-coded column is compared with one by one before a gather
+#: of the entries' answers is cheaper: the program grows by a compare each
+DICT_COMPARE_ENTRIES = 64
+
+
+def _dict_equals_literal(expr, ctx: EvalContext) -> Optional[ColVal]:
+    """``dict-coded column = 'literal'`` on the codes: the literal is
+    compared with the dictionary's few entries, and a row equals it where
+    its code's entry does. Evaluating the column as a string first
+    (``_column_to_val``) decodes every row into bytes, a byte-space gather
+    of the whole batch: a second a 2^20-row batch on the v5e (PR 35: the
+    `customer` filter of `sf10_q3_join1`). Up to ``DICT_COMPARE_ENTRIES``
+    entries the codes are compared with each entry in turn, in registers;
+    past that one gather a row reads its entry's answer (9 ms a 2^20-row
+    batch there, whatever the dictionary's size). None where the
+    expression's shape is another."""
+    if not isinstance(expr, (E.EqualTo, E.EqualNullSafe)):
+        return None
+    for ref, lit in ((expr.left, expr.right), (expr.right, expr.left)):
+        if not (isinstance(ref, E.ColumnRef) and isinstance(lit, E.Literal)
+                and isinstance(lit.value, str)):
+            continue
+        c = ctx.batch.columns[ref.index]
+        if not (c.is_dict and c.dict_size > 0):
+            return None
+        entries = ColumnarBatch([c.dictionary],
+                                jnp.int32(c.dictionary.capacity))
+        ectx = EvalContext(entries)
+        same = _string_eq(ectx.column(0), eval_expr(lit, ectx),
+                          ectx.capacity)
+        code = c.data.astype(jnp.int32)
+        if c.dict_size <= DICT_COMPARE_ENTRIES:
+            eq = jnp.zeros(ctx.capacity, jnp.bool_)
+            for d in range(c.dict_size):
+                eq = eq | ((code == d) & same[d])
+        else:
+            eq = same[jnp.clip(code, 0, c.dict_size - 1)]
+        if isinstance(expr, E.EqualTo):
+            return ColVal(eq, c.validity)
+        return ColVal(eq & c.validity, _all_valid(ctx.capacity))
+    return None
+
+
 def _eval_compare(expr: E.BinaryComparison, ctx: EvalContext) -> ColVal:
+    on_codes = _dict_equals_literal(expr, ctx)
+    if on_codes is not None:
+        return on_codes
     l = eval_expr(expr.left, ctx)
     r = eval_expr(expr.right, ctx)
     cap = ctx.capacity

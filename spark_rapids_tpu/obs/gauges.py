@@ -111,6 +111,32 @@ CATALOG: "List[Tuple[str, str, str]]" = [
      "Distinct streaming-aggregate step programs bound by fused stages in "
      "this process (exec/fused.py: one per batch capacity and window "
      "length; it must not grow with a partition's batch count)"),
+    ("fused_fallback_total", "counter",
+     "Partitions a fused stage re-ran through the unfused operator chain "
+     "(exec/fused.py _fall_back; one exec:fused-fallback span each); the "
+     "three counters below split it by cause"),
+    ("fused_fallback_join_refused_total", "counter",
+     "Fallbacks because an absorbed join's build took a path whose probe "
+     "is not traceable (duplicate keys, an empty build, an outer join)"),
+    ("fused_fallback_carry_overflow_total", "counter",
+     "Fallbacks because the streaming aggregate's groups (or their key "
+     "bytes) outgrew the carry's capacity, the first batch's bucket"),
+    ("fused_fallback_empty_total", "counter",
+     "Fallbacks of partitions without a batch (classic empty-input "
+     "semantics)"),
+    ("join_build_path_total", "counter",
+     "Hash-join build sides constructed (exec/join.py build_span; one "
+     "exec:join-build span each); the four counters below split it by the "
+     "probe structure the build ended in"),
+    ("join_build_path_dense_total", "counter",
+     "Build sides probed through the direct-address table (integer key "
+     "domain under join.denseKey.maxDomain, unique keys)"),
+    ("join_build_path_unique_total", "counter",
+     "Build sides probed through the bucketed unique-key table"),
+    ("join_build_path_ht_total", "counter",
+     "Build sides probed through the open-addressing hash table"),
+    ("join_build_path_sorted_total", "counter",
+     "Build sides probed through the sorted-hash general path"),
     ("ingest_upload_ns_total", "counter",
      "Nanoseconds from the start of an in-memory table's first scan until "
      "its last batch was on the device: dictionary encoding and the "
@@ -343,6 +369,8 @@ def snapshot() -> Dict[str, int]:
     out.update(_agg.counters())
     from spark_rapids_tpu.exec import fused as _fused
     out.update(_fused.counters())
+    from spark_rapids_tpu.exec import join as _join
+    out.update(_join.counters())
     from spark_rapids_tpu.plan import overrides as _ov
     out.update(_ov.upload_counters())
     from spark_rapids_tpu.exec import kernels as _k

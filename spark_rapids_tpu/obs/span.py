@@ -74,6 +74,18 @@ CATALOG: "List[Tuple[str, str]]" = [
      "the seed (first batch), then one per window: chain + first pass per "
      "batch, one carry merge (attrs: batches, rows = batches x their "
      "capacity)"),
+    ("exec:join-build", "One hash join's build side: executing it and "
+     "constructing the probe structure, the construction's host syncs "
+     "(join.dense_key_stats, join.dense_dup, join.table_stats, join.table_dup) as children; "
+     "the unfused operator's also holds the peek of its first probe batch "
+     "(attrs: path = dense | unique | ht | sorted, rows = live build rows "
+     "where a sync already read them, capacity)"),
+    ("exec:topn", "One dispatch of a top-N (exec/sort.py TopNExec): a "
+     "batch's k best rows by selection, or the partials' (attrs: k, rows = "
+     "slots scanned, the batch's capacity, capacity = the output's)"),
+    ("exec:fused-fallback", "A partition re-run through the unfused "
+     "operator chain after its fused stage gave up, until the chain is "
+     "drained (attrs: cause = join-refused | carry-overflow | empty)"),
     ("cluster:map", "Map task executed by a cluster executor process"),
     ("cluster:reduce", "Reduce task executed by a cluster executor process"),
     ("shuffle:fetch", "One shuffle block fetch round-trip (client side)"),

@@ -398,10 +398,8 @@ class MeshExecutor:
             return self._mark(node, self._lower_agg(node))
         if isinstance(node, BroadcastHashJoinExec):
             return self._mark(node, self._lower_bhj(node))
-        from spark_rapids_tpu.exec.misc import LocalLimitExec
-        from spark_rapids_tpu.exec.sort import SortExec
-        if (isinstance(node, LocalLimitExec)
-                and isinstance(node.children[0], SortExec)):
+        from spark_rapids_tpu.exec.sort import TopNExec
+        if isinstance(node, TopNExec) and node.partial:
             return self._mark(node, self._lower_local_topn(node))
         raise NotLowerable(type(node).__name__)
 
@@ -652,39 +650,31 @@ class MeshExecutor:
         return _Lowered(fn, template, out_cap)
 
     def _lower_local_topn(self, node) -> _Lowered:
-        """LocalLimit(Sort(child)): per-device sort + static-N head — the
+        """A partial TopNExec: each device keeps its rows' k best — the
         distributed half of take_ordered_and_project. The host tail
-        (gather + final merge sort + global limit) then works over
-        n_dev * N rows only (reference: GpuTakeOrderedAndProjectExec)."""
-        from spark_rapids_tpu.exec.sort import SortExec, _slice_rows
+        (gather + final top-N) then works over n_dev * k rows only
+        (reference: GpuTakeOrderedAndProjectExec)."""
+        from spark_rapids_tpu.exec.sort import topn_batch
 
-        sort_node = node.children[0]
-        assert isinstance(sort_node, SortExec)
-        child = self._lower_child(sort_node.children[0])
+        child = self._lower_child(node.children[0])
         for c in child.template.columns:
             if c.offsets is not None:
                 raise NotLowerable("plain string column in mesh top-N")
-        sort_node._prepare()
-        specs = tuple(sort_node._specs)
+        node._prepare()
+        specs = tuple(node._specs)
         limit = int(node.limit)
         out_cap = bucket_capacity(max(limit, 1), self.min_local_cap)
         if out_cap > child.cap:
             out_cap = child.cap
-        byte_caps = tuple(0 for _ in child.template.columns)
-
-        from spark_rapids_tpu.exec.sort import _sort_run
 
         def run(b):
-            srt = _sort_run(b, specs)
-            n = jnp.minimum(srt.num_rows, limit)
-            return _slice_rows(srt, jnp.int32(0), n, out_cap, byte_caps)
+            return topn_batch(b, specs, limit, out_cap)
 
         template = run(child.template)
 
         def fn(ctx):
             return run(child.fn(ctx))
 
-        self.dist_nodes.append("SortExec")
         return _Lowered(fn, template, out_cap)
 
     def _lower_bhj(self, node) -> _Lowered:

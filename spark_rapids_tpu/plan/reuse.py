@@ -145,12 +145,12 @@ def _local_key(node) -> tuple:
                else _expr_key(node.pre_filter, cs))
         return ("agg", node.mode, _exprs_key(node.group_exprs, cs),
                 _exprs_key(node.agg_exprs, cs), pre)
-    if isinstance(node, SortExec):
+    if isinstance(node, SortExec):  # covers TopNExec
         cs = node.child.output_schema
         orders = tuple((_expr_key(o.child, cs), o.ascending, o.nulls_first)
                        for o in node.orders)
         return ("sort", orders, node.each_batch, node.out_of_core,
-                node.target_rows)
+                node.target_rows, getattr(node, "limit", None))
     if isinstance(node, LocalLimitExec):
         return ("llimit", node.limit)
     if isinstance(node, GlobalLimitExec):

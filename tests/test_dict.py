@@ -320,3 +320,45 @@ def test_group_concat_across_shared_dict_batches():
     for k, n in exp.items():
         kk = None if not isinstance(k, str) else k
         assert got[kk] == n
+
+
+@pytest.mark.parametrize("literal", ["BUILDING", "MACHINERY", "NO SUCH"])
+@pytest.mark.parametrize("null_safe", [False, True])
+@pytest.mark.parametrize("more", [0, 100], ids=["compared", "gathered"])
+def test_equality_with_a_literal_is_decided_on_the_codes(literal, null_safe,
+                                                         more):
+    """``dict-coded column = 'literal'`` compares the literal with the
+    dictionary's entries and the rows' codes with those (exprs/eval.py
+    ``_dict_equals_literal``), and gives what the comparison of the decoded
+    strings gives: nulls, an empty entry, a literal the dictionary lacks;
+    a dictionary of more entries than ``DICT_COMPARE_ENTRIES`` reads each
+    row's answer by one gather."""
+    import jax
+    from spark_rapids_tpu.exprs import eval as EV
+    from spark_rapids_tpu.exprs import expr as E
+    from spark_rapids_tpu.exprs.expr import EqualNullSafe, EqualTo, col, lit
+    words = ["AUTOMOBILE", "BUILDING", "", "MACHINERY", None] + [
+        f"SEGMENT#{i}" for i in range(more)]
+    assert (len(words) > EV.DICT_COMPARE_ENTRIES) == bool(more)
+    rng = np.random.default_rng(4)
+    vals = [words[i] for i in rng.integers(0, len(words), 300)]
+    t = pa.table({"s": pa.array(vals, pa.string())})
+    enc = batch_from_arrow(dictionary_encode_table(t))
+    plain = batch_from_arrow(t)
+    assert enc.columns[0].is_dict and not plain.columns[0].is_dict
+    schema = T.Schema.from_arrow(t.schema)
+    op = EqualNullSafe if null_safe else EqualTo
+    bound = E.resolve(op(col("s"), lit(literal)), schema)
+    got = EV.eval_expr(bound, EV.EvalContext(enc))
+    want = EV.eval_expr(bound, EV.EvalContext(plain))
+    n = len(vals)
+    assert np.array_equal(np.asarray(got.validity)[:n],
+                          np.asarray(want.validity)[:n])
+    live = np.asarray(want.validity)[:n]
+    assert np.array_equal(np.asarray(got.data)[:n][live],
+                          np.asarray(want.data)[:n][live])
+    # on the codes: the rows' bytes are never made (the decode's output,
+    # rows x longest entry bytes, is in no equation of the program)
+    text = str(jax.make_jaxpr(
+        lambda b: EV.eval_expr(bound, EV.EvalContext(b)).data)(enc))
+    assert f"u8[{enc.capacity * enc.columns[0].dict_max_len}]" not in text

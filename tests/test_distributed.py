@@ -265,7 +265,7 @@ def test_distributed_multikey_join(rng):
 
 
 def test_distributed_local_topn(rng):
-    """take_ordered: the per-device sort+limit half runs on the mesh; the
+    """take_ordered: the per-device top-N half runs on the mesh; the
     host tail merges n_dev * N rows only."""
     n = 5000
     t = pa.table({
@@ -277,7 +277,7 @@ def test_distributed_local_topn(rng):
     q = (d.group_by("k").agg(E.Sum(col("v")).alias("s"))
          .sort(SortOrder(col("s"), ascending=False), limit=10))
     ex = assert_distributed_matches(q, sort=True)
-    assert any("SortExec" in x for x in ex.dist_nodes), (
+    assert any("TopNExec" in x for x in ex.dist_nodes), (
         ex.dist_nodes, ex.host_nodes)
 
 

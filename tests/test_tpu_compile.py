@@ -294,3 +294,125 @@ def test_q1_charge_multiplies_wide_by_narrow_without_limbs(q1_seed):
                     for name, shape, op, _ in ops
                     if op == "fusion" and re.search(rf"\[{CAP}\]", shape)]
     assert 1 <= len(wide_fusions) <= 3, wide_fusions
+
+
+def _sort_operands(stablehlo: str) -> list:
+    """Operand count of every ``stablehlo.sort`` of a lowered program."""
+    import re
+    return [len(m.group(1).split(","))
+            for m in re.finditer(r'"stablehlo\.sort"\(([^)]*)\)', stablehlo)]
+
+
+TOPN_CAP = 1 << 17       # the bucket of Q3's 116k groups at SF10
+TOPN_COMPILE_S = 30.0    # measured here: 0.4 s; the sort it replaced: PERF.md
+
+
+def test_topn_program_compiles_in_seconds_and_does_not_sort(one_chip):
+    """ORDER BY revenue desc, o_orderdate, l_orderkey LIMIT 10 over the
+    aggregate's output in a 2^17-row bucket (cell ``sf10_q3_join1``): a
+    DECIMAL(38,4) key of two 64-bit limbs, a date, an int64. The top-N by
+    selection (exec/kernels.py ``topn_indices``) has no sort at all, and
+    compiles for the described v5e in well under ``TOPN_COMPILE_S``; the
+    full sort the planner used before PR 35 grows with the batch (module
+    docstring: 294 s at 2^16 rows with five operands)."""
+    import time
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.columnar.batch import ColumnarBatch
+    from spark_rapids_tpu.columnar.column import DeviceColumn
+    from spark_rapids_tpu.exec import sort as S
+    n = TOPN_CAP
+    ok = jnp.ones(n, jnp.bool_)
+    batch = ColumnarBatch([
+        DeviceColumn(T.LONG, jnp.zeros(n, jnp.int64), ok),
+        DeviceColumn(T.DATE, jnp.zeros(n, jnp.int32), ok),
+        DeviceColumn(T.INT, jnp.zeros(n, jnp.int32), ok),
+        DeviceColumn(T.DecimalType(38, 4), jnp.zeros(n, jnp.int64), ok,
+                     data2=jnp.zeros(n, jnp.int64))], jnp.int32(n))
+    specs = (K.SortSpec(3, ascending=False), K.SortSpec(1), K.SortSpec(0))
+
+    def topn(b, k):
+        return S._topn_run.__wrapped__(b, specs, k, 1024)
+    lowered = _lower(topn, one_chip, batch, jnp.int32(10))
+    assert _sort_operands(lowered.as_text()) == []
+    t = time.perf_counter()
+    assert lowered.compile() is not None
+    assert time.perf_counter() - t < TOPN_COMPILE_S
+    # what it replaced, as traced: eleven key words, past the variadic
+    # sort's operand cap, so a chain of eleven sorts and ten gathers of the
+    # whole batch (float64 money: one variadic sort of five operands)
+    old = _lower(lambda b: K.sort_indices(b, specs), one_chip, batch)
+    assert len(_sort_operands(old.as_text())) == 11
+
+
+def test_grouping_and_join_build_sort_two_operands_at_a_time(
+        one_chip, lineitem_small):
+    """The sorts a fused Q3 stage runs at a batch's capacity (grouping by
+    hash in the first pass and the merge, the unique join table's build)
+    are chains of ONE two-operand signature (``K.lsd_order``): the TPU
+    compiler bills a sort per distinct signature and steeply per operand
+    (three u32 keys and a payload 108 s at 2^20 rows against 24 s for one
+    key, two u64 keys 214 s at 2^21; PERF.md PR 35), and a cold start of
+    ``sf10_q3_join1`` has 1,000 s for everything."""
+    batch, col = lineitem_small
+    keys = (col["l_orderkey"],)
+    group = _lower(lambda b: K.group_rows(b, [col["l_orderkey"],
+                                              col["l_shipdate"]]),
+                   one_chip, batch)
+    assert set(_sort_operands(group.as_text())) == {2}
+    build = _lower(lambda b: K.build_join_table.__wrapped__(b, keys),
+                   one_chip, batch)
+    assert set(_sort_operands(build.as_text())) == {2}
+    assert group.compile() is not None and build.compile() is not None
+
+
+def test_fused_q3_stage_programs(one_chip):
+    """The benchmark's Q3 (cell ``sf10_q3_join1``) as its fused `lineitem`
+    stage runs it, at a capacity this suite can afford: the sizing program
+    (the chain for its row counts), a deferred window of two batches
+    (filter mask -> projection -> one-gather probe of the unique table ->
+    compaction by sort -> sort-based first pass at the learned capacity,
+    packed, no merge), and the unique table's two build programs. The
+    order keys are shifted past ``join.denseKey.maxDomain`` as SF10's are.
+    Every sort in them has two operands (``K.lsd_order``'s signature)."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import datagen
+    import harness
+    from spark_rapids_tpu.exec import fused as F
+    from spark_rapids_tpu.plan import from_arrow
+    raw = datagen.make(["lineitem", "orders", "customer"], 0.01, 7)
+    for t, c in (("lineitem", "l_orderkey"), ("orders", "o_orderkey")):
+        raw[t][c] = raw[t][c] + (1 << 26)
+    d = {k: from_arrow(datagen.arrow(v), batch_rows=8192, partitions=1)
+         for k, v in raw.items()}
+    plan = harness.load_by_path("queries", "q3").build(d).physical_plan()
+    stage = plan.children[0]
+    assert isinstance(stage, F.TpuFusedStageExec) and stage.agg is not None
+    batch = next(iter(stage.child.execute(0)))
+    segs = stage._runtime_segments(0)
+    consts = tuple(seg.consts for seg in segs)
+    assert [getattr(s, "kind", None) for s in segs] == [None, None, "unique"]
+    stage._size(0, segs, batch, consts)
+    assert stage._learned == {0: 1024}
+    fns = stage._chain_fns(segs, batch.capacity)
+    sizing = _lower(F._make_sizing(fns), one_chip, batch, consts)
+    window = _lower(F._make_partial(fns, stage.agg), one_chip,
+                    (batch, batch), consts)
+    for lowered in (sizing, window):
+        assert set(_sort_operands(lowered.as_text())) <= {2}
+        assert lowered.compile() is not None
+    build = segs[2].build
+    slots = _lower(lambda b: K.join_row_slots.__wrapped__(b, (0,)),
+                   one_chip, build)
+    assert set(_sort_operands(slots.as_text())) == {2}
+    assert slots.compile() is not None
+    placed = jax.eval_shape(
+        lambda b: K.join_row_slots.__wrapped__(b, (0,)), build)[0]
+    placed = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), placed)
+    table = _compile(lambda p: K.join_rows_table.__wrapped__(
+        p, 4, K.join_rows_lg_b(build.capacity)), one_chip, placed)
+    # no array with a short last axis: the TPU pads a last axis to 128
+    assert table.memory_analysis().temp_size_in_bytes < 64 << 20

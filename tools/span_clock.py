@@ -23,7 +23,9 @@ program's spans against the run's own ``.xplane.pb``:
 The last line of standard output is one JSON object: ``clock``, ``tiles``,
 ``sync_sites``, ``await_wakes`` (the front-end's submit counters and, on a
 program that has them, its counters of what ended each wait for a result,
-over the whole run) and the run's ordinary ``result``. Needs the chip, as benchmark/run.py
+over the whole run), ``operators`` (the joins' build paths and the fused
+stages' fallbacks by their counters, and the exec:* spans per request) and
+the run's ordinary ``result``. Needs the chip, as benchmark/run.py
 does (``--rehearse-sf`` runs every step on the CPU and prints no device
 number). It wraps one private function of the harness to see the offset
 and the spans, and edits nothing there."""
@@ -137,6 +139,27 @@ def sync_sites(spans: list, requests: list) -> dict:
                 out.items(), key=lambda kv: -kv[1][1])}
 
 
+def operator_spans(spans: list, requests: list) -> dict:
+    """{span name [path or cause]: [per request, ms per request]} of the
+    exec:* spans other than host syncs over the window's requests."""
+    import spantree
+    trees = list(spantree.by_request(spans, requests).values())
+    out = {}
+    for tree in trees:
+        for s in tree:
+            if not s["name"].startswith("exec:") or (
+                    s["name"] == "exec:host-sync"):
+                continue
+            tag = s["attrs"].get("path") or s["attrs"].get("cause")
+            row = out.setdefault(s["name"] + (f" {tag}" if tag else ""),
+                                 [0, 0.0])
+            row[0] += 1
+            row[1] += (s["end"] - s["start"]) / 1e6
+    n = max(len(trees), 1)
+    return {k: {"per_request": c / n, "ms_per_request": ms / n}
+            for k, (c, ms) in sorted(out.items())}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -200,6 +223,16 @@ def main() -> int:
     report["await_wakes"] = {
         k: v for k, v in net_metrics.counters().items()
         if k.startswith(("net_await_wake_", "net_submit_"))}
+    # over the whole run (set-up and warm rounds too): which probe
+    # structure the joins' build sides ended in, and every fallback of a
+    # fused stage by cause; per request of the window: each operator
+    # span's count and milliseconds
+    from spark_rapids_tpu.obs import gauges
+    report["operators"] = {
+        "counters": {k: v for k, v in gauges.snapshot().items()
+                     if k.startswith(("join_build_path_", "fused_fallback_",
+                                      "fused_step_programs_"))},
+        "spans": operator_spans(seen["spans"], seen["requests"])}
     if rehearsal:  # no device number from a rehearsal
         out = {"rehearsal": True, "correct": out["correct"],
                "metrics_read": sorted(out["metrics"])}
