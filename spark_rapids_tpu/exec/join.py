@@ -353,7 +353,9 @@ class HashJoinExec(BinaryExec):
         + ``K.join_rows_table``): (rows, slots, lg_b), or None where the
         keys repeat or a bucket outgrows ``join.uniqueTable.maxSlots``
         (the general path's case). Two syncs a build side: the largest
-        bucket sizes the table's rows, then whether two rows share a key."""
+        bucket sizes the table's rows, then whether two rows share a key
+        (two slots of one bucket of the finished table hold one hash
+        pair: the table's own compare, not a probe of it by the build)."""
         if build.capacity > (1 << 24):
             return None  # 256 B a row of capacity: 4 GB of table here
         placed, largest = K.join_row_slots(build, tuple(self._rkeys))

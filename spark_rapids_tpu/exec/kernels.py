@@ -1713,11 +1713,12 @@ def join_rows_table(placed, slots: int, lg_b: int):
     row holding its ``slots`` entries word by word (every h1 lo, then
     every h1 hi, h2 lo, h2 hi, then the build rows' ids, -1 where the
     bucket is shorter). Also whether two build rows share a 128-bit hash
-    pair: every build row is probed against the table, and one that finds
-    another row before itself has a twin. Two rows with one key always
-    share the pair, so a table without twins holds unique keys, and one
-    with them is refused as duplicate-keyed (rows of different keys and
-    one hash pair: the engine-wide treat-as-exact bar of ``group_rows``).
+    pair: such twins share h1's top bits and so a bucket, whose slots are
+    compared with each other (``slots`` covers ``join_row_slots``'s
+    largest bucket: every keyed row is in the table). Two rows with one
+    key always share the pair, so a table without twins holds unique keys,
+    and one with them is refused as duplicate-keyed (rows of different keys
+    and one hash pair: the engine-wide treat-as-exact bar of ``group_rows``).
 
     On the v5e a gather costs about 9 ns an index whatever it fetches and
     a sort of 2^20 (key, row id) pairs 1.3 ms (my chip runs, PR 35;
@@ -1741,21 +1742,17 @@ def join_rows_table(placed, slots: int, lg_b: int):
         flat = flat.at[jnp.where(at < B * width, at + k * slots,
                                  B * width)].set(w, mode="drop")
     rows = flat.reshape(B, width)
-    # the build probes its own table a piece at a time: a fetched bucket is
-    # padded to 128 words a row, 1 GB for 2^21 rows at once (on the chip the
-    # whole-build probe wanted 6 GB beside 10.5 GB of resident tables)
-    piece = min(n, 1 << 17)
-
-    def twins(args):
-        ph1, ph2, ok, first = args
-        bi, hit = probe_join_rows(rows, lg_b, ph1, ph2, ok)
-        return jnp.any(hit & (bi != first + jnp.arange(piece,
-                                                       dtype=jnp.int32)))
-    firsts = jnp.arange(0, n, piece, dtype=jnp.int32)
-    twin = jnp.any(jax.lax.map(twins, (
-        h1.reshape(-1, piece), h2.reshape(-1, piece),
-        valid.reshape(-1, piece), firsts)))
-    return rows, twin
+    # word-major a slot's word is a lane over the buckets: slots s and s + d
+    # of every bucket compare elementwise (probing for it: 260 ms, PERF.md)
+    ent = jnp.transpose(rows).reshape(ROW_WORDS, slots, B)
+    held = ent[4] != jnp.uint32(0xFFFFFFFF)
+    twin = jnp.zeros(B, jnp.bool_)
+    for d in range(1, slots):
+        same = held[d:] & held[:-d]
+        for k in range(4):
+            same = same & (ent[k, d:] == ent[k, :-d])
+        twin = twin | jnp.any(same, axis=0)
+    return rows, jnp.any(twin)
 
 
 @jax.named_scope("join.probe")

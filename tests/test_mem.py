@@ -162,7 +162,11 @@ def test_semaphore_limits_and_priority():
             time.sleep(hold_s)
 
     import time
-    threads = [threading.Thread(target=task, args=(i, 0.05)) for i in range(6)]
+    # two permits: holders that let go together would hand their permits to
+    # the next two waiters at once, and those race to `order`; uneven holds
+    # keep every grant 20 ms or more from the next
+    threads = [threading.Thread(target=task, args=(i, 0.05 + 0.03 * (i % 2)))
+               for i in range(6)]
     for i, t in enumerate(threads):
         t.start()
         # the next task arrives only once this one is in the queue (or in):

@@ -180,16 +180,21 @@ def test_q3_over_the_wire_equals_the_reference(seed, params):
                     "join.table_dup"]
 
 
-def test_a_fallback_is_a_span_and_a_count():
+@pytest.mark.parametrize("far,asked", [
+    ((), "join.dense_dup"), ((1 << 40,), "join.table_dup")],
+    ids=["dense", "unique"])
+def test_a_fallback_is_a_span_and_a_count(far, asked):
     """A build with duplicate keys refuses the fused probe: the partition
     re-runs unfused, the answer stays right, and the request's trace says
-    so (exec:fused-fallback, cause join-refused) beside the counter."""
+    so (exec:fused-fallback, cause join-refused) beside the counter. With
+    a key far past ``join.denseKey.maxDomain`` the build is the unique
+    rung's, and what refuses it is the row table's own twin flag."""
     import pyarrow as pa
     from spark_rapids_tpu.exprs.expr import Sum, col
     left = pa.table({"k": pa.array([1, 2, 3, 4] * 50, pa.int64()),
                      "v": pa.array(range(200), pa.int64())})
-    right = pa.table({"rk": pa.array([1, 1, 2, 3], pa.int64()),
-                      "w": pa.array([10, 20, 30, 40], pa.int64())})
+    right = pa.table({"rk": pa.array([1, 1, 2, 3, *far], pa.int64()),
+                      "w": pa.array([10, 20, 30, 40, *far], pa.int64())})
     df = (from_arrow(left, batch_rows=64, partitions=1)
           .join(from_arrow(right, partitions=1), left_on="k", right_on="rk")
           .group_by("k").agg(Sum(col("w")).alias("s")))
@@ -208,6 +213,9 @@ def test_a_fallback_is_a_span_and_a_count():
         pytest.skip("the planner did not fuse this join")
     falls = [e["args"] for e in events if e["name"] == "exec:fused-fallback"]
     assert [f["cause"] for f in falls] == ["join-refused"]
+    sites = [e["args"]["site"] for e in events
+             if e["name"] == "exec:host-sync"]
+    assert asked in sites
     assert after["fused_fallback_total"] - before[
         "fused_fallback_total"] == 1
     assert after["fused_fallback_join_refused_total"] - before[

@@ -365,6 +365,15 @@ def test_grouping_and_join_build_sort_two_operands_at_a_time(
     assert group.compile() is not None and build.compile() is not None
 
 
+def _no_loop_no_gather(table):
+    """The row table finds two rows of one hash pair by comparing the
+    slots of a bucket with each other, elementwise: no probe of the table
+    by its own build (a ``while`` over pieces of it, a gather each)."""
+    text = table.as_text()
+    assert "stablehlo.while" not in text
+    assert "stablehlo.gather" not in text and "dynamic_gather" not in text
+
+
 def test_fused_q3_stage_programs(one_chip):
     """The benchmark's Q3 (cell ``sf10_q3_join1``) as its fused `lineitem`
     stage runs it, at a capacity this suite can afford: the sizing program
@@ -412,7 +421,33 @@ def test_fused_q3_stage_programs(one_chip):
     placed = jax.eval_shape(
         lambda b: K.join_row_slots.__wrapped__(b, (0,)), build)[0]
     placed = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), placed)
-    table = _compile(lambda p: K.join_rows_table.__wrapped__(
+    table = _lower(lambda p: K.join_rows_table.__wrapped__(
         p, 4, K.join_rows_lg_b(build.capacity)), one_chip, placed)
+    _no_loop_no_gather(table)
     # no array with a short last axis: the TPU pads a last axis to 128
-    assert table.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert table.compile().memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+CELL_BUILD_CAP = 1 << 21  # Q3's `orders` build at SF10 (1.46M rows)
+CELL_BUILD_SLOTS = 16     # its widest table (seed 2147483659; 8 on most)
+# temporaries of the program this one replaced (the build probing its own
+# table in sixteen pieces), compiled here at this shape: 570.9 MiB; this
+# one's: 562.6, and 560.4 with no twin check at all (PERF.md, PR 36)
+CELL_BUILD_TEMP_BYTES = 571 << 20
+
+
+def test_unique_table_at_the_cells_shape(one_chip):
+    """``K.join_rows_table`` at the shape ``sf10_q3_join1`` runs it
+    (capacity 2^21, 16 slots: a table of 2^20 buckets of 80 words): on the
+    chip it runs beside 10.75 GB of resident tables, and a check that
+    wanted 6 GB more once ran the cell out of memory (PERF.md, PR 35)."""
+    placed = tuple(jax.ShapeDtypeStruct((CELL_BUILD_CAP,), dt) for dt in (
+        jnp.uint64, jnp.uint64, jnp.bool_, jnp.int32, jnp.int32))
+    table = _lower(lambda p: K.join_rows_table.__wrapped__(
+        p, CELL_BUILD_SLOTS, K.join_rows_lg_b(CELL_BUILD_CAP)),
+        one_chip, placed)
+    _no_loop_no_gather(table)
+    compiled = table.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < CELL_BUILD_TEMP_BYTES
+    hlo = compiled.as_text()
+    assert " while(" not in hlo and " gather(" not in hlo
