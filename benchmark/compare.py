@@ -6,6 +6,13 @@ exact column (keys, dates, strings, counts) or any DECIMAL value differ.
 ``units_off`` is the widest gap of a DECIMAL value from the reference, in
 units of that column's last place: 0 for a sound answer, and what says how
 far off a wrong one was.
+
+A query file declares the shape of its answer: ``EXACT_COLUMNS`` (names, in
+the answer's order), ``DECIMAL_COLUMNS`` ({name: scale}) and, where an exact
+column is not an int64 or a string, ``EXACT_TYPES`` ({name: the Arrow type's
+name, as ``date32``}). Either of the first two may be empty: an answer of
+strings and counts alone (Q12) is decided by its exact columns, and its
+``units_off`` is 0.
 """
 
 import pyarrow as pa
@@ -33,11 +40,11 @@ def _unscaled(col: pa.ChunkedArray, scale: int):
 
 def answer_readings(got: pa.Table, want: dict, query) -> dict:
     """``{"wrong": 0|1, "units_off": int, "why": str}`` for one answer."""
-    names = set(query.EXACT_COLUMNS) | set(query.DECIMAL_COLUMNS)
-    if set(got.schema.names) != names:
+    declared = tuple(query.EXACT_COLUMNS) + tuple(query.DECIMAL_COLUMNS)
+    if set(got.schema.names) != set(declared):
         return {"wrong": 1, "units_off": 0,
                 "why": f"columns {got.schema.names}"}
-    n = len(want[next(iter(query.DECIMAL_COLUMNS))])
+    n = len(want[declared[0]])  # every column of a reference is as long
     if got.num_rows != n:
         return {"wrong": 1, "units_off": 0,
                 "why": f"{got.num_rows} rows, want {n}"}
@@ -50,7 +57,7 @@ def answer_readings(got: pa.Table, want: dict, query) -> dict:
         if g is None:
             return {"wrong": 1, "units_off": 0,
                     "why": f"null or non-finite in {c}"}
-        off = max(abs(a - b) for a, b in zip(g, want[c]))
+        off = max((abs(a - b) for a, b in zip(g, want[c])), default=0)
         if off > worst:
             worst, why = off, f"{c} off by {off} units of 1e-{scale}"
     return {"wrong": int(worst > 0), "units_off": worst, "why": why}
@@ -60,7 +67,15 @@ def control_table(want: dict, query) -> pa.Table:
     """A reference answer (computed in float64 money, say) as the Arrow
     table the program would have returned: the control in its place."""
     from decimal import Decimal
-    cols = {c: pa.array(list(want[c])) for c in query.EXACT_COLUMNS}
+    types = getattr(query, "EXACT_TYPES", {})
+    cols = {}
+    for c in query.EXACT_COLUMNS:
+        kind = types.get(c)
+        if kind == "date32":  # the references hold dates as days since 1970
+            cols[c] = pa.array(list(want[c]), pa.int32()).cast(pa.date32())
+        else:
+            cols[c] = pa.array(list(want[c]),
+                               getattr(pa, kind)() if kind else None)
     for c, scale in query.DECIMAL_COLUMNS.items():
         cols[c] = pa.array([Decimal(v).scaleb(-scale) for v in want[c]],
                            pa.decimal128(38, scale))

@@ -238,6 +238,17 @@ def labels(column: str):
     return _CODES[column]
 
 
+def with_row(table: dict, names, at: int, times: int) -> dict:
+    """The columns ``names`` of ``table`` with row ``at`` there ``times``
+    times: 0 leaves it out, 2 repeats it. What a query's controls hand its
+    reference for a probe match that was dropped or duplicated; only the
+    columns the query reads are copied."""
+    rows = np.arange(len(table[names[0]]))
+    rows = np.delete(rows, at) if times == 0 else np.insert(
+        rows, [at] * (times - 1), at)
+    return {c: table[c][rows] for c in names}
+
+
 def _decimal(cents) -> pa.Array:
     """int64 cents -> decimal128(15, 2), through the 16-byte buffer."""
     lo = np.ascontiguousarray(cents, np.int64)

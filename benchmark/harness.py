@@ -306,6 +306,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     if missing:
         raise SystemExit(f"{cell['config']} has no table(s) {sorted(missing)}")
 
+    # a build that lacks a capability the configuration ``requires``
+    # refuses here, in seconds, and not after the data are made
+    conf = program_conf(config, mix, cache_dir(cell["config"], sf))
+
     # -- set-up: data from the seed, upload, warm-up ------------------------
     t = time.perf_counter()
     raw = datagen.make(needed, sf, seed)
@@ -314,7 +318,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     stages = {"import_s": t - t_process, "data_s": time.perf_counter() - t}
     served = tamper(tables) if tamper else tables
 
-    conf = program_conf(config, mix, cache_dir(cell["config"], sf))
     srv = QueryServer(conf)
     fe = QueryFrontend(srv, tables=served, host="127.0.0.1", port=0)
     win = Window(mix, seed, fe.host, fe.port, conf, config, queries)

@@ -107,3 +107,12 @@ def reference(raw: dict, p=PARAMS, money=int) -> dict:
         for k, v in zip(DECIMAL_COLUMNS, vals):
             out[k].append(int(v))
     return out
+
+
+# name -> (raw, p) -> a degraded answer in the reference's form; put in the
+# program's place each has to read answers_wrong >= 1 (control.py, on the chip)
+CONTROLS = {
+    # the step below DECIMAL that would tempt a later PR: sum_charge passes
+    # 2**53 units of 1e-6 from about SF0.2
+    "float64_money": lambda raw, p: reference(raw, p, money=float),
+}

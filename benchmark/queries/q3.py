@@ -27,6 +27,7 @@ COLUMNS = {
 PARAMS = {"segment": "BUILDING", "date": [1995, 3, 15]}
 DECIMAL_COLUMNS = {"revenue": 4}
 EXACT_COLUMNS = ("l_orderkey", "o_orderdate", "o_shippriority")
+EXACT_TYPES = {"o_orderdate": "date32", "o_shippriority": "int32"}
 LIMIT = 10
 
 
@@ -71,13 +72,13 @@ def least_bytes(rows: dict, width: dict) -> int:
     return read + LIMIT * (8 + 16 + 4 + 4)
 
 
-def reference(raw: dict, p=PARAMS, money=int) -> dict:
+def reference(raw: dict, p=PARAMS, money=int, limit=LIMIT) -> dict:
     """Plain numpy, exact: boolean masks, membership by ``np.isin`` and
     ``searchsorted`` on sorted keys for the two joins, revenue in whole
     units of 1e-4 as int64 (price_cents x (100 - discount_cents); an
     order's at most seven products stay far under 2**63), ``np.lexsort``
-    for the order, the first ten. ``money=float`` is the control: money as
-    float64 dollars, products and sums in float64, rounded at the end."""
+    for the order, the first ``limit``. ``money=float``: money as float64
+    dollars, products and sums in float64, rounded at the end."""
     from datagen import labels
     cu, od, li = raw["customer"], raw["orders"], raw["lineitem"]
     day = _day(p)
@@ -106,8 +107,71 @@ def reference(raw: dict, p=PARAMS, money=int) -> dict:
     groups = np.flatnonzero(np.bincount(at, minlength=len(okey)) > 0)
     # revenue desc, o_orderdate, l_orderkey (lexsort: last key is primary)
     top = groups[np.lexsort((okey[groups], odate[groups], -rev[groups]))
-                 ][:LIMIT]
+                 ][:limit]
     return {"l_orderkey": [int(v) for v in okey[top]],
             "o_orderdate": [int(v) for v in odate[top]],
             "o_shippriority": [int(v) for v in oprio[top]],
             "revenue": [int(v) for v in rev[top]]}
+
+
+def _float32_money(raw: dict, p) -> dict:
+    """Money as float32 dollars: the keys of the exact top ten with their
+    revenue summed in float32 (float32 revenue can also reorder the rows;
+    the kindest case is kept)."""
+    want = reference(raw, p)
+    li = raw["lineitem"]
+    keep = li["l_shipdate"] > _day(p)
+    out = dict(want, revenue=[])
+    for key in want["l_orderkey"]:
+        m = keep & (li["l_orderkey"] == key)
+        price = (li["l_extendedprice"][m] / 100.0).astype(np.float32)
+        disc = (li["l_discount"][m] / 100.0).astype(np.float32)
+        rev = np.sum(price * (np.float32(1.0) - disc), dtype=np.float32)
+        out["revenue"].append(int(round(float(rev) * 10 ** 4)))
+    return out
+
+
+def _match(raw: dict, p, times: int) -> dict:
+    """The reference over a lineitem in which one line that joins the fifth
+    order of the answer is there ``times`` times: a probe match dropped (0)
+    or emitted twice (2)."""
+    from datagen import with_row
+    li = raw["lineitem"]
+    key = reference(raw, p)["l_orderkey"][4]
+    at = int(np.flatnonzero((li["l_orderkey"] == key)
+                            & (li["l_shipdate"] > _day(p)))[0])
+    return reference(dict(raw, lineitem=with_row(
+        li, COLUMNS["lineitem"], at, times)), p)
+
+
+def _rows_swapped(raw: dict, p) -> dict:
+    """Rows 10 and 11 of the full order swapped: the tenth place goes to
+    the eleventh group, as a sort that misplaces two rows would leave it."""
+    full = reference(raw, p, limit=LIMIT + 1)
+    return {c: v[:LIMIT - 1] + v[LIMIT:] for c, v in full.items()}
+
+
+def _member_missed(raw: dict, p) -> dict:
+    """A true member (the fifth) missed and the eleventh let in at the end:
+    what an approximate top-k hands back."""
+    full = reference(raw, p, limit=LIMIT + 1)
+    return {c: v[:4] + v[5:] for c, v in full.items()}
+
+
+# name -> (raw, p) -> a degraded answer in the reference's form; put in the
+# program's place each has to read answers_wrong >= 1 (control.py, on the chip)
+CONTROLS = {
+    "float32_money": _float32_money,
+    "dropped_match": lambda raw, p: _match(raw, p, 0),
+    "duplicated_match": lambda raw, p: _match(raw, p, 2),
+    "rows_swapped": _rows_swapped,
+    "member_missed": _member_missed,
+}
+# name -> (the same kind of function, why it cannot fail): run and reported,
+# never counted against the comparison
+PASSES_BY_DESIGN = {
+    "float64_money": (
+        lambda raw, p: reference(raw, p, money=float),
+        "a group's sum holds at most seven products, each under 2**53 "
+        "units of 1e-4: float64 rounds every revenue to the exact one"),
+}

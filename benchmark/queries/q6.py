@@ -51,10 +51,8 @@ def least_bytes(rows: dict, width: dict) -> int:
     return read + 16
 
 
-def reference(raw: dict, p=PARAMS, money=int) -> dict:
-    """Plain numpy over whole cents, exact. ``money=float`` is the control:
-    the same query with money as float64 dollars, as an engine without
-    DECIMAL would hold it, rounded to the answer's scale at the end."""
+def _kept(raw: dict, p) -> tuple:
+    """(price, discount) in whole cents of the rows the predicates keep."""
     from datagen import date_i
     li = raw["lineitem"]
     m = ((li["l_shipdate"] >= date_i(p["year"], 1, 1))
@@ -62,9 +60,37 @@ def reference(raw: dict, p=PARAMS, money=int) -> dict:
          & (li["l_discount"] >= p["discount"] - 1)
          & (li["l_discount"] <= p["discount"] + 1)
          & (li["l_quantity"] < p["quantity"] * 100))
-    price, disc = li["l_extendedprice"][m], li["l_discount"][m]
+    return li["l_extendedprice"][m], li["l_discount"][m]
+
+
+def reference(raw: dict, p=PARAMS, money=int) -> dict:
+    """Plain numpy over whole cents, exact. ``money=float`` is the same
+    query with money as float64 dollars, as an engine without DECIMAL would
+    hold it, rounded to the answer's scale at the end."""
+    price, disc = _kept(raw, p)
     if money is float:
         total = float(np.sum((price / 100.0) * (disc / 100.0)))
         return {"revenue": [int(round(total * 10 ** 4))]}
     return {"revenue": [sum(int(c.sum()) for c in
                             np.array_split(price * disc, 16))]}
+
+
+def _float32_money(raw: dict, p) -> dict:
+    """Money as float32 dollars, products and the sum in float32; the
+    predicates stay exact, which is the kindest float engine."""
+    price, disc = ((v / 100.0).astype(np.float32) for v in _kept(raw, p))
+    total = float(np.sum(price * disc, dtype=np.float32))
+    return {"revenue": [int(round(total * 10 ** 4))]}
+
+
+# name -> (raw, p) -> a degraded answer in the reference's form; put in the
+# program's place each has to read answers_wrong >= 1 (control.py, on the chip)
+CONTROLS = {"float32_money": _float32_money}
+# name -> (the same kind of function, why it cannot fail): run and reported,
+# never counted against the comparison
+PASSES_BY_DESIGN = {
+    "float64_money": (
+        lambda raw, p: reference(raw, p, money=float),
+        "one sum of some 10^5..10^6 products of two-decimal numbers, far "
+        "under 2**53 units of 1e-4: float64 rounds to the exact answer"),
+}

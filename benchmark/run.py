@@ -21,7 +21,10 @@ and before this process touches JAX, until one of them neither compiles
 nor exports anything:
 the program's export store (jit_persist, on by default) makes a program it
 loads compile once more in the process after the one that traced it, and
-every later run has to find every program in the caches.
+every later run has to find every program in the caches. A child runs in
+a process group of its own, and the group is killed when this process is
+told to end (SIGTERM, SIGINT), when the child passes its time limit and on
+any other exit: a run cut from outside leaves nothing on the chip.
 
 ``--self-check`` reduces the recorded trace under testdata/ and compares the
 reduction with the numbers kept beside it; it needs no chip.
@@ -31,8 +34,10 @@ import time
 T_PROCESS = time.perf_counter()  # set-up counts from here
 
 import argparse  # noqa: E402
+import atexit  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import signal  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 
@@ -63,6 +68,58 @@ PRIME_CHILDREN = 3  # traced programs, loaded ones, a set-up that is quiet
 PRIME_TIMEOUT_S = 1000
 
 
+def run_child(cmd: list, timeout: float) -> tuple:
+    """(exit code, standard output) of ``cmd``, run in a process group of
+    its own that does not outlive this process: the group is killed on
+    SIGTERM and SIGINT (this process then exits with 128 + the signal), at
+    the time-out (code 124) and on every way out of this function. The
+    child asks the kernel to kill it when its parent dies (main()), which
+    covers a SIGKILL of this process too."""
+    p = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True,
+                         start_new_session=True)
+    print(f"[bench] priming child pid {p.pid}", file=sys.stderr, flush=True)
+
+    def kill_group():
+        try:
+            os.killpg(p.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass  # the group has gone already
+
+    def on_signal(signum, _frame):
+        kill_group()
+        try:  # not p.wait(): communicate() below may hold Popen's lock
+            os.waitpid(p.pid, 0)
+        except ChildProcessError:
+            pass
+        print(f"[bench] signal {signum}: priming child {p.pid} killed",
+              file=sys.stderr, flush=True)
+        os._exit(128 + signum)
+    before = {s: signal.signal(s, on_signal)
+              for s in (signal.SIGTERM, signal.SIGINT)}
+    atexit.register(kill_group)
+    try:
+        out, _ = p.communicate(timeout=timeout)
+        return p.returncode, out
+    except subprocess.TimeoutExpired:
+        print(f"[bench] priming child {p.pid} passed {timeout:.0f} s",
+              file=sys.stderr, flush=True)
+        return 124, ""
+    finally:
+        kill_group()  # the child, and whatever it left in its group
+        p.wait()
+        atexit.unregister(kill_group)
+        for s, handler in before.items():
+            signal.signal(s, handler)
+
+
+def die_with_parent():
+    """Linux: the kernel sends this process SIGKILL when its parent dies,
+    however that came about."""
+    import ctypes
+    PR_SET_PDEATHSIG = 1
+    ctypes.CDLL(None).prctl(PR_SET_PDEATHSIG, signal.SIGKILL)
+
+
 def prime(args, marker: str) -> int:
     """Fills the checkout's caches for the cell; 0, or the child's code."""
     cmd = [sys.executable, os.path.abspath(__file__), "--workload",
@@ -70,11 +127,10 @@ def prime(args, marker: str) -> int:
     if args.rehearse_sf is not None:
         cmd += ["--rehearse-sf", str(args.rehearse_sf)]
     for i in range(PRIME_CHILDREN):
-        p = subprocess.run(cmd, stdout=subprocess.PIPE, text=True,
-                           timeout=PRIME_TIMEOUT_S)
-        if p.returncode != 0:
-            return p.returncode
-        told = json.loads(p.stdout.strip().splitlines()[-1])
+        rc, out = run_child(cmd, PRIME_TIMEOUT_S)
+        if rc != 0:
+            return rc
+        told = json.loads(out.strip().splitlines()[-1])
         print(f"[bench] priming {i + 1}: {json.dumps(told)}",
               file=sys.stderr, flush=True)
         if told["compiled"] == 0 and told["exported"] == 0:
@@ -105,7 +161,9 @@ def main() -> int:
 
     import harness
     rehearsal = args.rehearse_sf is not None
-    if not args.prime_child:
+    if args.prime_child:
+        die_with_parent()
+    else:
         marker = harness.primed_marker(args.workload, args.rehearse_sf)
         if not os.path.exists(marker):
             rc = prime(args, marker)

@@ -14,9 +14,18 @@ intervals (the union, so nested and overlapping operations count once),
 averaged over the chips that ran anything; launches are the ``XLA Modules``
 events that begin inside the window.
 
+``device_ops`` ranks operations by ``<program>/<op>``: the program is the
+``XLA Modules`` event that encloses the op (``jit_step``; many programs
+have a ``fusion.1``; see ``program_labels``), and every instant of the ``XLA Ops`` line is charged
+once, to the op that began last: a ``while``'s body ops lie inside its
+interval, so they get their own time and the ``while`` keeps what is left.
+The events carry no ``op_name`` or scope (looked at, PR 38), so there is no
+ranking by ``jax.named_scope``.
+
     python benchmark/tracered.py <file.xplane.pb>      # print the reduction
 """
 
+import bisect
 import gzip
 import json
 import re
@@ -46,14 +55,70 @@ def clip(intervals, lo, hi):
 
 def op_label(name: str) -> str:
     """A device op's event name is its whole HLO line; keep the result's
-    name and the op kind, which is what stays stable between two runs."""
+    name, which is what stays stable between two runs, with the op kind
+    where the name does not say it and a custom call's target."""
     m = _OP_NAME.match(name)
     head = m.group(1) if m else name[:40]
-    kind = re.search(r"\}?\s([a-z][a-z0-9\-]*)\(", name)
     target = re.search(r'custom_call_target="([^"]+)"', name)
     if target:
         return f"{head} {target.group(1)}"
-    return f"{head} {kind.group(1)}" if kind else head
+    kind = re.search(r"\}?\s([a-z][a-z0-9\-]*)\(", name)
+    if kind and kind.group(1) not in head:
+        return f"{head} {kind.group(1)}"
+    return head
+
+
+def program_labels(modules: list) -> dict:
+    """{event name: label} for the ``XLA Modules`` events of a line:
+    ``jit_step(6844749427986326659)`` -> ``jit_step``. Where several
+    programs share a name (every program loaded from the jax.export store is
+    ``jit_call``), the first six digits of the module's own number follow
+    it: ``jit_call.571651``."""
+    ids = {}
+    for name in {n for n, _, _ in modules}:
+        head, _, rest = name.partition("(")
+        ids.setdefault(head, []).append((name, rest.rstrip(")")))
+    return {name: head if len(named) == 1 else f"{head}.{num[:6]}"
+            for head, named in ids.items() for name, num in named}
+
+
+def self_times(ops: list, modules: list, lo, hi) -> dict:
+    """{``<program>/<op>``: ns} inside [lo, hi]. One sweep over the ops of a
+    line in order of their start: an instant belongs to the op that began
+    last among those running, so the sum over the labels is the line's
+    busy time and no nanosecond is counted twice."""
+    programs = program_labels(modules)
+    mods = sorted((s, e, programs[n]) for n, s, e in modules)
+    starts = [m[0] for m in mods]
+    labels, out = {}, {}
+
+    def label(name, s):
+        i = bisect.bisect_right(starts, s) - 1
+        prog = mods[i][2] if i >= 0 and s < mods[i][1] else "?"
+        key = (prog, name)
+        if key not in labels:
+            labels[key] = f"{prog}/{op_label(name)}"
+        return labels[key]
+
+    def charge(lab, a, b):
+        a, b = max(a, lo), min(b, hi)
+        if b > a:
+            out[lab] = out.get(lab, 0) + b - a
+    stack, cursor = [], lo  # [(label, end)]; all before cursor is charged
+    for name, s, e in sorted(ops, key=lambda o: (o[1], -o[2])):
+        while stack and stack[-1][1] <= s:
+            lab, end = stack.pop()
+            charge(lab, cursor, end)
+            cursor = max(cursor, end)
+        if stack:
+            charge(stack[-1][0], cursor, s)
+        cursor = max(cursor, s)
+        stack.append((label(name, s), e))
+    while stack:
+        lab, end = stack.pop()
+        charge(lab, cursor, end)
+        cursor = max(cursor, end)
+    return out
 
 
 def read_planes(path: str) -> dict:
@@ -102,10 +167,10 @@ def reduce_planes(planes: dict, spans: list = ()) -> dict:
         merged_all.append(merged)
         launches += sum(1 for _, s, _e in lines.get("XLA Modules", [])
                         if lo <= s < hi)
-        for name, s, e in ops:
-            if e > lo and s < hi:
-                lab = op_label(name)
-                op_ns[lab] = op_ns.get(lab, 0) + min(e, hi) - max(s, lo)
+        # where a trace has no op line the programs stand in: jit_a/jit_a
+        charged = self_times(ops, lines.get("XLA Modules", []), lo, hi)
+        for lab, ns in charged.items():
+            op_ns[lab] = op_ns.get(lab, 0) + ns
     if not busy_per_chip:
         raise ValueError("no operation ran on a device inside the window")
     # idle gaps of the first chip, named by what the host was doing
