@@ -404,8 +404,9 @@ FUSION_ENABLED = conf(
         "aggregate into one jitted program per pipeline stage, paying the "
         "per-dispatch floor once per stage instead of once per operator "
         "(exec/fused.py; WholeStageCodegenExec analog). Data-dependent "
-        "runtime conditions (duplicate join build keys, aggregate carry "
-        "overflow) fall back to the unfused operator chain per partition.")
+        "runtime conditions (a join build no fused rung takes, aggregate "
+        "carry overflow) fall back to the unfused operator chain per "
+        "partition.")
 
 FUSION_MIN_OPERATORS = conf(
     "spark.rapids.tpu.sql.fusion.minOperators", default=2,
@@ -981,6 +982,11 @@ CAPABILITIES = {
         "k rounds of selection per batch, whose program does not grow with "
         "the batch as a variadic sort's does, so ORDER BY ... LIMIT over a "
         "100k-group aggregate compiles in seconds (docs/fusion.md)",
+    "join.fusedDuplicateKeys":
+        "an inner join whose build keys repeat is probed inside its fused "
+        "stage through the build's runs, one a key (exec/join.py "
+        "_prepare_runs): no host sync a probe batch, and the stage's output "
+        "capacity counts pairs (docs/fusion.md)",
 }
 
 
@@ -999,7 +1005,9 @@ REQUIRES = conf(
         "streaming aggregate are bounded per partition and compile once, "
         "so a large resident table's first start fits a deadline); "
         "sort.boundedTopN (a sort with a limit is a top-N by selection, "
-        "whose compile cost does not grow with the batch).",
+        "whose compile cost does not grow with the batch); "
+        "join.fusedDuplicateKeys (an inner join whose build keys repeat "
+        "stays in its fused stage, with no host sync a probe batch).",
     check=_check_requires)
 
 

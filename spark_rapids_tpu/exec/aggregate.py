@@ -1407,6 +1407,45 @@ def _decode_col_jit(b: ColumnarBatch, ci: int) -> ColumnarBatch:
 _decode_col_fn = jax.jit(K.decode_dictionary)
 
 
+def share_dictionaries(batches: Sequence[ColumnarBatch],
+                       site: str) -> List[ColumnarBatch]:
+    """``batches`` with each dict-coded column's dictionary made ONE device
+    object where their contents are equal, so that ``concat_jit`` keeps
+    the codes instead of decoding every row: a program's output holds the
+    dictionary its input forwarded in a buffer of its own, so the batches
+    a fused stage made from one ingest's share no object. The candidates'
+    dictionaries (a few entries each) are read in ONE host sync (``site``)
+    and compared on the host; a column whose dictionaries differ stays as
+    it was, for ``concat_jit`` to decode."""
+    import numpy as np
+    from spark_rapids_tpu.utils.sync import host_get
+    batches = list(batches)
+    cand = []
+    for ci, c in enumerate(batches[0].columns):
+        ds = [b.columns[ci] for b in batches]
+        if (c.is_dict and any(d.dictionary is not c.dictionary for d in ds)
+                and all(d.is_dict and d.dict_size == c.dict_size
+                        and d.dictionary.capacity == c.dictionary.capacity
+                        and d.dictionary.data.shape == c.dictionary.data.shape
+                        for d in ds)):
+            cand.append(ci)
+    if not cand:
+        return batches
+    got = host_get([[(b.columns[ci].dictionary.data,
+                      b.columns[ci].dictionary.offsets,
+                      b.columns[ci].dictionary.validity) for b in batches]
+                    for ci in cand], site)
+    for ci, dicts in zip(cand, got):
+        if all(all(np.array_equal(x, y) for x, y in zip(d, dicts[0]))
+               for d in dicts[1:]):
+            one = batches[0].columns[ci].dictionary
+            batches = [ColumnarBatch(
+                [dataclasses.replace(c, dictionary=one) if i == ci else c
+                 for i, c in enumerate(b.columns)], b.num_rows)
+                for b in batches]
+    return batches
+
+
 def concat_jit(batches: Sequence[ColumnarBatch],
                out_capacity: Optional[int] = None) -> ColumnarBatch:
     """Device concat with capacity bucketing (jit cached per shape combo).

@@ -15,9 +15,9 @@ Plan-time pass (``fuse_exec``, called from plan/overrides.py behind
 - narrow per-batch operators — anything implementing the ``batch_fn()``
   protocol (exec/base.py): project, filter, expand;
 - inner hash joins along their PROBE side (the build subtree executes
-  normally at stage setup; only the per-batch probe is absorbed, and only
-  for the dense / unique-table runtime paths whose probes are pure —
-  the general sorted-hash path needs a per-batch host sync and bails to
+  normally at stage setup; only the per-batch probe is absorbed, for the
+  dense, unique-table and runs (keys that repeat) paths whose probes are
+  pure — the general hash table needs a per-batch host sync and bails to
   the unfused fallback, see HashJoinExec.fused_probe);
 - a terminal partial/complete hash aggregate, absorbed in STREAMING form:
   per window of batches one dispatch runs chain -> first_pass per batch
@@ -35,8 +35,9 @@ Correctness safety valves — every data-dependent assumption is checked and
 degrades to the ORIGINAL operator chain (constituents keep their children
 links, so the unfused plan is always re-executable):
 
-- join build turns out duplicate-keyed / oversized -> fallback before any
-  output is produced;
+- join build refused by every rung (dense, unique, runs) / oversized ->
+  fallback before any output is produced; a runs probe whose pairs outgrow
+  even its batch's own capacity -> the partition re-runs unfused;
 - the streaming aggregate's carry overflows its capacity (more groups, or
   more group-key bytes, than the first batch's bucket) -> overflow flags
   are computed ON DEVICE inside the fused body and read back once at
@@ -207,32 +208,39 @@ def _carry_shape(carry: ColumnarBatch) -> Tuple[int, Tuple[int, ...]]:
         for c in carry.columns)
 
 
-def _make_seed(fns, agg):
+def _make_seed(fns, agg, cuts: bool = False):
+    """The first batch's first pass, in the carry's form; with ``cuts``
+    (the chain holds a probe that can run short) also whether it did."""
     body = _make_body(fns)
 
     def seed(batch, consts):
-        out, live, counts, _ = body(batch, consts)
-        return _as_carry(agg._first_pass(out, live)), counts
+        out, live, counts, short = body(batch, consts)
+        carry = _as_carry(agg._first_pass(out, live))
+        return (carry, counts, short) if cuts else (carry, counts)
     return seed
 
 
-def _make_step(fns, agg, carry_cap: int, bc_targets: Tuple[int, ...]):
+def _make_step(fns, agg, carry_cap: int, bc_targets: Tuple[int, ...],
+               cuts: bool = False):
     """Streaming-aggregate step over a WINDOW of equal-capacity batches:
     one dispatch runs chain -> first_pass for every batch in the window
     (unrolled: one body per batch), then a single (carry + firsts)
     concat/merge — the fused analog of the classic operator's 8-way merge
     cascade, without the per-batch first-pass dispatches or the
-    end-of-partition cascade."""
+    end-of-partition cascade. With ``cuts`` the overflow flag is followed
+    by whether a probe of the window ran short."""
     from spark_rapids_tpu.exec.aggregate import concat_jit
     body = _make_body(fns)
 
     def step(carry, batches, consts):
         firsts = []
         counts_all = []
+        short = jnp.bool_(False)
         for batch in batches:
-            out, live, counts, _ = body(batch, consts)
+            out, live, counts, cut = body(batch, consts)
             firsts.append(agg._first_pass(out, live))
             counts_all.append(counts)
+            short = short | cut
         # named scopes are HLO metadata only (docs/observability.md): the
         # window's concatenate-and-reduce reads as aggwin/* in an op profile
         with jax.named_scope("aggwin.concat"):
@@ -240,6 +248,8 @@ def _make_step(fns, agg, carry_cap: int, bc_targets: Tuple[int, ...]):
         with jax.named_scope("aggwin.merge"):
             merged = agg._merge_pass(cat)
             carry2, over = _truncate_buffers(merged, carry_cap, bc_targets)
+        if cuts:
+            return carry2, over, short, tuple(counts_all)
         return carry2, over, tuple(counts_all)
     return step
 
@@ -277,7 +287,8 @@ def _make_partial(fns, agg):
 _STEP_KEYS: set = set()  # streaming-step program keys bound in this process
 
 #: why a partition left its fused stage for the unfused operator chain
-FALLBACK_CAUSES = ("join-refused", "carry-overflow", "empty")
+FALLBACK_CAUSES = ("join-refused", "join-overflow", "carry-overflow",
+                   "empty")
 _fallback_lock = threading.Lock()
 _fallbacks = dict.fromkeys(FALLBACK_CAUSES, 0)
 
@@ -450,6 +461,8 @@ class TpuFusedStageExec(UnaryExec):
 
     def _attribute(self, segs, counts) -> None:
         for seg, n in zip(segs, counts):
+            if getattr(seg, "pairs", None) is not None:
+                seg.pairs.append(n)
             op = seg.op
             op.metrics["numOutputBatches"].add(1)
             op._pending_rows.append(n)
@@ -500,6 +513,30 @@ class TpuFusedStageExec(UnaryExec):
         for seg in segs:
             if hasattr(seg, "shrink_to"):
                 seg.shrink_to = None
+            if getattr(seg, "pairs", None) is not None:
+                seg.pairs.clear()
+
+    @staticmethod
+    def _cuts(segs) -> bool:
+        """Whether a probe of the chain can run short at its batch's own
+        capacity (the runs rung: a row emits a run's length of pairs)."""
+        return any(getattr(seg, "cuts_unshrunk", False) for seg in segs)
+
+    def _shrunk(self, partition: int) -> bool:
+        return self._learned.get(partition) is not None
+
+    @staticmethod
+    def _pair_counts(segs) -> list:
+        """The runs probes' emitted pairs, a list a probe, for the stage's
+        one sync."""
+        return [seg.pairs for seg in segs
+                if getattr(seg, "pairs", None) is not None]
+
+    @staticmethod
+    def _note_pairs(segs, read) -> None:
+        runs = [seg for seg in segs if getattr(seg, "pairs", None) is not None]
+        for seg, emitted in zip(runs, read):
+            seg.note_pairs(emitted)
 
     def do_execute(self, partition: int) -> Iterator[ColumnarBatch]:
         segs = self._runtime_segments(partition)
@@ -551,22 +588,30 @@ class TpuFusedStageExec(UnaryExec):
             return
         self._size(partition, segs, first, consts)
         batches = itertools.chain([first], it)
-        if self._learned.get(partition) is None:
-            for out, _ in run_all(batches):
-                yield out
-            return
-        # shrunk outputs (half a source batch or less each) wait for the
-        # partition's end: ONE host sync reads every probe's ``short`` flag,
-        # as the deferred aggregate does. Where a batch's hits did not fit
-        # what was learned, the partition runs again at the probes' own
-        # capacity, and the stage stops shrinking it
-        held = list(run_all(batches))
-        if any(bool(v) for v in host_get([short for _, short in held],
-                                         "fused.short")):
+        while self._shrunk(partition) or self._cuts(segs):
+            # shrunk outputs (half a source batch or less each), and those
+            # of a probe that can cut at any capacity, wait for the
+            # partition's end: ONE host sync reads every probe's ``short``
+            # flag, as the deferred aggregate does. Where a batch's hits did
+            # not fit what was learned, the partition runs again at the
+            # probes' own capacity, and the stage stops shrinking it; where
+            # they do not fit that either, the unfused operators run it
+            held = list(run_all(batches))
+            cut, pairs = host_get(([short for _, short in held],
+                                   self._pair_counts(segs)), "fused.short")
+            if not any(bool(v) for v in cut):
+                self._note_pairs(segs, pairs)
+                for out, _ in held:
+                    yield out
+                return
+            del held
+            if not self._shrunk(partition):
+                yield from self._fall_back(partition, "join-overflow")
+                return
             self._unlearn(partition, segs)
             runs.clear()
-            held = run_all(self.child.execute(partition))
-        for out, _ in held:
+            batches = self.child.execute(partition)
+        for out, _ in run_all(batches):
             yield out
 
     def _execute_agg(self, partition: int, segs):
@@ -625,14 +670,16 @@ class TpuFusedStageExec(UnaryExec):
 
         for window in self._windows(itertools.chain([first], it)):
             dispatch(window)
-        cut, rows = host_get((shorts, [p.num_rows for p in partials]),
-                             "fused.overflow_flags")
+        cut, rows, pairs = host_get(
+            (shorts, [p.num_rows for p in partials],
+             self._pair_counts(segs)), "fused.overflow_flags")
         if any(bool(v) for v in cut):
             # a batch's hits outgrew what the first batch's had promised:
             # the partition again, carried, at the probe's own capacity
             self._unlearn(partition, segs)
             yield from self._execute_agg(partition, segs)
             return
+        self._note_pairs(segs, pairs)
         total = sum(int(n) for n in rows)
         with _span.task_span("exec:agg-step",
                              attrs={"batches": 0, "rows": total}):
@@ -651,12 +698,16 @@ class TpuFusedStageExec(UnaryExec):
         # static capacity (its bucket bounds the groups a partition may
         # hold fused — more groups trip the overflow flag -> fallback)
         cap = first.capacity
-        key = self._stage_key(segs, cap) + akey + ("seed",)
+        # a chain whose probe can run short (shrunk, or the runs rung) has
+        # its programs say so, beside the overflow flags
+        cuts = self._shrunk(partition) or self._cuts(segs)
+        key = (self._stage_key(segs, cap) + akey
+               + (("seed", "cut") if cuts else ("seed",)))
         fns = self._chain_fns(segs, cap)
-        run = shared_jit(key, lambda: _make_seed(fns, agg))
+        run = shared_jit(key, lambda: _make_seed(fns, agg, cuts))
         with _span.task_span("exec:agg-step",
                              attrs={"batches": 1, "rows": cap}):
-            carry, counts = run(first, consts)
+            carry, counts, *short = run(first, consts)
         self.metrics["numFusedBatches"].add(1)
         agg.metrics["numAggBatches"].add(1)
         self._attribute(segs, counts)
@@ -667,16 +718,28 @@ class TpuFusedStageExec(UnaryExec):
         # the conf's and not tuned at run time: every length merges to the
         # same buffers, and each length is a program of that many unrolled
         # bodies to compile
-        step = _StepRunner(self, segs, agg, consts, akey, carry)
+        step = _StepRunner(self, segs, agg, consts, akey, carry, cuts)
+        step.shorts.extend(short)
         for window in self._windows(it):
             step.run(window)
         # ONE host sync per partition resolves every overflow flag; on
         # overflow the carry holds truncated garbage -> re-run unfused
-        flags = (host_get(step.flags, "fused.overflow_flags")
-                 if step.flags else ())
+        flags, shorts, pairs = (host_get(
+            (step.flags, step.shorts, self._pair_counts(segs)),
+            "fused.overflow_flags") if step.flags or cuts else ((), (), ()))
+        if any(bool(v) for v in shorts):
+            if self._shrunk(partition):
+                # hits outgrew what was learned: again at the probe's own
+                # capacity, where the runs rung may still cut
+                self._unlearn(partition, segs)
+                yield from self._execute_agg(partition, segs)
+            else:
+                yield from self._fall_back(partition, "join-overflow")
+            return
         if any(bool(v) for v in flags):
             yield from self._fall_back(partition, "carry-overflow")
             return
+        self._note_pairs(segs, pairs)
         carry = step.carry
         out = carry if agg.mode == "partial" else agg._final_project_fn(carry)
         agg.metrics["numOutputBatches"].add(1)
@@ -695,12 +758,14 @@ class _StepRunner:
     capacity bucket. Capacities that interleave cost a dispatch per run
     of equal capacity, and still one program per (capacity, length)."""
 
-    def __init__(self, stage, segs, agg, consts, akey, carry):
+    def __init__(self, stage, segs, agg, consts, akey, carry, cuts=False):
         self.stage, self.segs, self.agg = stage, segs, agg
         self.consts, self.akey = consts, akey
         self.carry = carry
         self.carry_cap, self.bc_targets = _carry_shape(carry)
+        self.cuts = cuts
         self.flags: list = []  # per step: the carry overflowed
+        self.shorts: list = []  # with ``cuts``: a probe ran short
         self._runs: dict = {}  # (capacity, window length) -> program
 
     def _program(self, cap: int, length: int):
@@ -710,12 +775,13 @@ class _StepRunner:
             # join-probe byte bounds are capacity-dependent: the chain
             # closures are those of the window's batch capacity
             fns = stage._chain_fns(segs, cap)
+            cuts = self.cuts
             key = (self.akey + ("step", self.carry_cap, self.bc_targets,
                                 cap, length)
-                   + stage._stage_key(segs, cap))
+                   + stage._stage_key(segs, cap) + (("cut",) if cuts else ()))
             carry_cap, bc_targets = self.carry_cap, self.bc_targets
             run = shared_jit(key, lambda: _make_step(
-                fns, agg, carry_cap, bc_targets))
+                fns, agg, carry_cap, bc_targets, cuts))
             _STEP_KEYS.add(key)
             self._runs[(cap, length)] = run
         return run
@@ -725,9 +791,10 @@ class _StepRunner:
         cap, n = window[0].capacity, len(window)
         with _span.task_span("exec:agg-step",
                              attrs={"batches": n, "rows": n * cap}):
-            self.carry, over, counts_all = self._program(cap, n)(
+            self.carry, over, *short, counts_all = self._program(cap, n)(
                 self.carry, tuple(window), self.consts)
         self.flags.append(over)
+        self.shorts.extend(short)
         stage.metrics["numFusedBatches"].add(n)
         agg.metrics["numAggBatches"].add(n)
         for counts in counts_all:

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 from functools import partial
 from typing import Iterator, List, Optional, Sequence, Tuple
 
@@ -38,7 +39,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch, bucket_capacity
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.base import BinaryExec, TpuExec
 from spark_rapids_tpu.exec import kernels as K
-from spark_rapids_tpu.exec.aggregate import concat_jit
+from spark_rapids_tpu.exec.aggregate import concat_jit, share_dictionaries
 from spark_rapids_tpu.exprs import expr as E
 from spark_rapids_tpu.exprs import eval as EV
 from spark_rapids_tpu.obs import span as _span
@@ -48,19 +49,22 @@ JOIN_TYPES = ("inner", "left", "right", "full", "left_semi", "left_anti")
 
 # -- the build side's span and counters --------------------------------------
 
-BUILD_PATHS = ("dense", "unique", "ht", "sorted")
+BUILD_PATHS = ("dense", "unique", "runs", "ht", "sorted")
 _build_tls = threading.local()
 _build_lock = threading.Lock()
 _build_paths = dict.fromkeys(BUILD_PATHS, 0)
+_run_pairs = [0]  # pairs the runs rung's fused probes emitted
 
 
 def counters() -> dict:
     """For obs/gauges.snapshot(): build sides by the probe structure they
-    ended in."""
+    ended in, and the pairs the runs rung emitted."""
     with _build_lock:
         out = {f"join_build_path_{p}_total": n
                for p, n in _build_paths.items()}
+        pairs = _run_pairs[0]
     out["join_build_path_total"] = sum(out.values())
+    out["join_run_pairs_total"] = pairs
     return out
 
 
@@ -373,6 +377,32 @@ class HashJoinExec(BinaryExec):
         if bool(host_get(twin, "join.table_dup")):
             return None
         return tbl, slots, lg_b
+
+    def _prepare_runs(self, build: ColumnarBatch, told: dict):
+        """The fused probe's third rung, for an inner join whose build keys
+        repeat: the build sorted by its hash pair into one run a key
+        (``K.join_runs``), the runs' heads in a row table as the unique
+        rung's rows (``K.join_runs_table``): (table, (order, count), slots,
+        lg_b), or None where a bucket of heads outgrows
+        ``join.uniqueTable.maxSlots`` or a run holds two keys of one hash
+        pair (the general path's cases). ONE sync a build side
+        (``join.runs_stats``): the largest bucket, the longest run and the
+        keyed rows together. A run may be of any length: what one probe
+        batch emits is bounded by the stage's output capacity alone, and a
+        batch that outgrows it says so (``short``)."""
+        if build.capacity > (1 << 24):
+            return None  # 256 B a row of capacity, as _prepare_rows
+        runs, placed, stats = K.join_runs(build, tuple(self._rkeys))
+        mb, longest, rows, mixed = host_get(stats, "join.runs_stats")
+        told.update(rows=int(rows), longest=int(longest))
+        slots = 1
+        while slots < max(int(mb), 1):
+            slots *= 2
+        lg_b = K.join_rows_lg_b(build.capacity)
+        if (bool(mixed) or slots > self._max_unique_slots
+                or (K.ROW_WORDS * slots) << lg_b >= 1 << 31):
+            return None
+        return K.join_runs_table(placed, slots, lg_b), runs, slots, lg_b
 
     def _join_batch_unique(self, probe: ColumnarBatch, build: ColumnarBatch,
                            table, build_matched, partition: int):
@@ -714,12 +744,19 @@ class HashJoinExec(BinaryExec):
                     kind, tbl = "dense", dense
                 else:
                     prep = self._prepare_rows(build, battrs)
-                    if prep is None:
-                        # duplicate keys: per-batch host sync path; the
-                        # unfused operator builds again and counts there
-                        return None
-                    kind, (tbl, slots, lg_b) = "unique", prep
-                    tbl = (tbl,) + self._presence(build, battrs)
+                    if prep is not None:
+                        kind, (tbl, slots, lg_b) = "unique", prep
+                        tbl = (tbl,)
+                    else:
+                        # the keys repeat (or a bucket is too wide)
+                        prep = self._prepare_runs(build, battrs)
+                        if prep is None:
+                            # the unfused operator builds again and
+                            # counts there
+                            return None
+                        kind, (tbl, runs, slots, lg_b) = "runs", prep
+                        tbl = (tbl, runs)
+                    tbl = tbl + self._presence(build, battrs)
             battrs["path"] = kind
             battrs.pop("kmin", None)
             battrs.pop("kmax", None)
@@ -766,7 +803,8 @@ class HashJoinExec(BinaryExec):
         if not build_batches:
             return None
         return (build_batches[0] if len(build_batches) == 1
-                else concat_jit(build_batches))
+                else concat_jit(share_dictionaries(build_batches,
+                                                   "join.build_dicts")))
 
     def _gather_pairs(self, probe, build, pi, bi, bi_valid, n_out, out_cap):
         row_valid = jnp.arange(out_cap, dtype=jnp.int32) < n_out
@@ -832,16 +870,34 @@ class _FusedJoinProbe:
         self._mls = mls  # string col -> longest build row in bytes
         self._bcaps = {}
         self.shrink_to: Optional[int] = None  # the stage's (exec/fused.py)
+        # a probe of the runs rung emits a run's length of pairs a row, so
+        # it can run short at the batch's own capacity too; the stage reads
+        # what it emitted (``pairs``, one count a batch) in its one sync
+        self.cuts_unshrunk = kind == "runs"
+        self.pairs: Optional[list] = [] if kind == "runs" else None
+        self._t0 = time.perf_counter_ns()
 
     @property
     def consts(self):
         return (self.build, self.tbl)
 
     def out_cap(self, in_cap: int) -> int:
-        # dense/unique probes emit at most one row per row
+        # dense/unique probes emit at most one row per row; a runs probe
+        # emits pairs, as many as the capacity holds, and says when more
         if self.shrink_to is not None and self.shrink_to < in_cap:
             return self.shrink_to
         return in_cap
+
+    def note_pairs(self, emitted) -> None:
+        """What the stage read of ``pairs``: added to
+        ``join_run_pairs_total`` and recorded as an ``exec:join-pairs``
+        span from the build's end to now, in the request's trace."""
+        total = sum(int(n) for n in emitted)
+        with _build_lock:
+            _run_pairs[0] += total
+        now = time.perf_counter_ns()
+        _span.record_span("exec:join-pairs", self._t0, now - self._t0,
+                          attrs={"pairs": total, "batches": len(emitted)})
 
     def _bcaps_t(self, out_cap: int) -> tuple:
         t = self._bcaps.get(out_cap)
@@ -860,12 +916,14 @@ class _FusedJoinProbe:
                 else None,
                 self.slots, self.lg_b, in_cap, out_cap,
                 self._bcaps_t(out_cap),
-                # the unique kind's presence table, or that it has none
+                # the presence table, or that there is none
+                None if self.kind == "dense" else
                 tuple(None if a is None else a.shape
-                      for a in self.tbl[1:2]) if self.kind == "unique"
-                else None)
+                      for a in self.tbl[-2:-1]))
 
     def probe_fn(self, in_cap: int):
+        if self.kind == "runs":
+            return self._runs_fn(in_cap)
         join, kind = self.op, self.kind
         out_cap = self.out_cap(in_cap)
         bt = self._bcaps_t(out_cap)
@@ -944,6 +1002,69 @@ class _FusedJoinProbe:
                 idx2, n2 = K.compact_indices(ok, out_cap)
                 out = K.gather_batch(out, idx2, n2)
             return out, None, short  # front-packed: no mask to hand on
+        return run
+
+    def _runs_fn(self, in_cap: int):
+        """The runs rung's probe: (the presence table first, where there
+        is one, and the rows it keeps compacted), one gather a row for its
+        run's head in the heads' table (``K.probe_join_rows``), the exact
+        key against the head's (a run holds one key: ``K.join_runs``
+        refused a build whose run held two), then every hit row's run
+        expanded into pairs (``K.expand_runs``) in an output of ``out_cap``
+        pairs. ``short`` where the hits or the pairs (or a probe string
+        column's bytes, each row repeated its run's length of times)
+        outgrow what was given them."""
+        join = self.op
+        out_cap = self.out_cap(in_cap)
+        bt = self._bcaps_t(out_cap)
+        lkeys, rkeys = tuple(join._lkeys), tuple(join._rkeys)
+        cond, lg_b = join._cond_bound, self.lg_b
+
+        def run(probe, live, consts):
+            build, (tbl, (order, count), present, first) = consts
+            pvalid = probe.active_mask() if live is None else live
+            for i in lkeys:
+                pvalid = pvalid & probe.columns[i].validity
+            short = jnp.bool_(False)
+            if present is not None:
+                hcap = min(out_cap, probe.capacity)
+                off = probe.columns[lkeys[0]].data.astype(jnp.int64) - first
+                inb = pvalid & (off >= 0) & (off < present.shape[0])
+                found = inb & present[
+                    jnp.where(inb, off, 0).astype(jnp.int32)]
+                idx, n = K.compact_indices(found, hcap)
+                short = n > hcap
+                probe = K.gather_batch(probe, idx, jnp.minimum(n, hcap))
+                pvalid = probe.active_mask()
+            rows = jnp.arange(probe.capacity, dtype=jnp.int32)
+            head, hit = K.probe_join_rows(
+                tbl, lg_b, K.hash_keys(probe, list(lkeys)),
+                K.hash_keys(probe, list(lkeys), variant=1), pvalid)
+            head = jnp.where(hit, head, 0)
+            hit = hit & K.keys_equal(probe, rows, list(lkeys), build,
+                                     order[head], list(rkeys))
+            cnt = jnp.where(hit, count[head], 0)
+            pi, pos, total = K.expand_runs(head, cnt, out_cap)
+            short = short | (total > out_cap)
+            n = jnp.minimum(total, out_cap).astype(jnp.int32)
+            live_out = jnp.arange(out_cap, dtype=jnp.int32) < n
+            join._pcaps = {}
+            for i, c in enumerate(probe.columns):
+                if c.offsets is not None:
+                    cap_i = bucket_capacity(max(c.byte_capacity * max(
+                        1, out_cap // probe.capacity), 8), 8)
+                    lens = (c.offsets[1:] - c.offsets[:-1]).astype(jnp.int64)
+                    short = short | (jnp.sum(lens * cnt) > cap_i)
+                    join._pcaps[i] = cap_i
+            join._bcaps = dict(bt)
+            out = join._gather_pairs(probe, build, pi, order[pos],
+                                     live_out, n, out_cap)
+            if cond is not None:
+                cv = EV.eval_expr(cond, EV.EvalContext(out))
+                idx2, n2 = K.compact_indices(
+                    live_out & cv.data & cv.validity, out_cap)
+                out = K.gather_batch(out, idx2, n2)
+            return out, None, short
         return run
 
 

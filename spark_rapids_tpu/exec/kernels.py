@@ -1684,13 +1684,19 @@ def join_row_slots(batch: ColumnarBatch, key_cols: Tuple[int, ...]):
     id, rank), brings the ranks back into row order: no gather, no scatter
     (``lsd_order``'s signature both times). The caller reads the largest
     bucket, sizes the table's rows by it, and calls ``join_rows_table``."""
-    cap = batch.capacity
-    lg_b = join_rows_lg_b(cap)
     h1 = hash_keys(batch, list(key_cols))
     h2 = hash_keys(batch, list(key_cols), variant=1)
     valid = batch.active_mask()
     for i in key_cols:
         valid = valid & batch.columns[i].validity
+    return _place_rows(h1, h2, valid)
+
+
+def _place_rows(h1: jax.Array, h2: jax.Array, valid: jax.Array):
+    """``join_row_slots`` over hashes already made: the rows of ``valid``
+    placed in their buckets (a row's id is its index here)."""
+    cap = h1.shape[0]
+    lg_b = join_rows_lg_b(cap)
     B = 1 << lg_b
     bucket = jnp.where(valid, (h1 >> jnp.uint64(64 - lg_b)).astype(jnp.int32),
                        B)  # rows without a key sort past every bucket
@@ -1727,6 +1733,24 @@ def join_rows_table(placed, slots: int, lg_b: int):
     probe (``probe_join_table_unique``) makes two gathers a probe row for
     the bucket's range and five a slot. Every array here is long in its
     last axis: the TPU pads a last axis of 5 to 128."""
+    rows = _fill_rows(placed, slots, lg_b)
+    B = 1 << lg_b
+    # word-major a slot's word is a lane over the buckets: slots s and s + d
+    # of every bucket compare elementwise (probing for it: 260 ms, PERF.md)
+    ent = jnp.transpose(rows).reshape(ROW_WORDS, slots, B)
+    held = ent[4] != jnp.uint32(0xFFFFFFFF)
+    twin = jnp.zeros(B, jnp.bool_)
+    for d in range(1, slots):
+        same = held[d:] & held[:-d]
+        for k in range(4):
+            same = same & (ent[k, d:] == ent[k, :-d])
+        twin = twin | jnp.any(same, axis=0)
+    return rows, jnp.any(twin)
+
+
+def _fill_rows(placed, slots: int, lg_b: int) -> jax.Array:
+    """The table of ``join_rows_table``: every placed row's entry in its
+    bucket's row, the row word its index in ``placed``."""
     h1, h2, valid, bucket, rank = placed
     B = 1 << lg_b
     n = h1.shape[0]
@@ -1741,18 +1765,117 @@ def join_rows_table(placed, slots: int, lg_b: int):
     for k, w in enumerate(words):
         flat = flat.at[jnp.where(at < B * width, at + k * slots,
                                  B * width)].set(w, mode="drop")
-    rows = flat.reshape(B, width)
-    # word-major a slot's word is a lane over the buckets: slots s and s + d
-    # of every bucket compare elementwise (probing for it: 260 ms, PERF.md)
-    ent = jnp.transpose(rows).reshape(ROW_WORDS, slots, B)
-    held = ent[4] != jnp.uint32(0xFFFFFFFF)
-    twin = jnp.zeros(B, jnp.bool_)
-    for d in range(1, slots):
-        same = held[d:] & held[:-d]
-        for k in range(4):
-            same = same & (ent[k, d:] == ent[k, :-d])
-        twin = twin | jnp.any(same, axis=0)
-    return rows, jnp.any(twin)
+    return flat.reshape(B, width)
+
+
+# -- runs: the build of a join whose keys repeat ------------------------------
+#
+# The fused probe's third rung (exec/join.py, after the dense and the unique
+# tables have refused). The build's rows sorted by their 128-bit hash pair
+# lie in runs, one a key; a run's first row (its head) stands for the run in
+# a row table of heads (``join_rows_table``'s layout, so one gather a probe
+# row finds it), and the probe expands each row that found its run into as
+# many (probe row, build row) pairs as the run is long (``expand_runs``).
+
+
+@partial(jax.jit, static_argnums=(1,))
+@jax.named_scope("join.build")
+def join_runs(batch: ColumnarBatch, key_cols: Tuple[int, ...]):
+    """First program of the runs build: the rows sorted so that each key's
+    rows lie side by side (two passes of ``lsd_order``'s one signature),
+    each run's head and length, and the heads placed in the buckets of a
+    row table (``_place_rows``, a head's id its sorted position).
+
+    One integer key (Q12's ``l_orderkey``) is sorted by its own value:
+    runs are exact, and the heads' hashes are made from the sorted keys,
+    so the sort's order is the one gather of a 64-bit lane (a gather costs
+    about 9 ns an index on the v5e, PERF.md). Other keys are sorted by h1:
+    where two rows of one h1 hold different keys (or hash pairs) the rung
+    is refused (``mixed``), as the unique table refuses its twins: their
+    rows could interleave. Returns (runs, placed, stats): runs = (order,
+    count), the build row at each sorted position and at a head its run's
+    length (0 elsewhere); stats = (largest bucket of heads, longest run,
+    keyed rows, mixed), read by the caller in the build's one sync. Plain
+    tuples: a program's arguments are exported (exec/jit_persist.py)."""
+    cap = batch.capacity
+    valid = batch.active_mask()
+    for i in key_cols:
+        valid = valid & batch.columns[i].validity
+    c = batch.columns[key_cols[0]]
+    if (len(key_cols) == 1 and c.offsets is None and not c.is_dict
+            and not c.is_wide_decimal and c.dtype not in T.FRACTIONAL_TYPES):
+        sk = jnp.where(valid, _int_sortable(c.data),
+                       jnp.uint64(0xFFFFFFFFFFFFFFFF))
+        order = lsd_order(_sort_words([sk]))
+        sv = valid[order]
+        ranked = ColumnarBatch([DeviceColumn(c.dtype, c.data[order], sv)],
+                               batch.num_rows)
+        sh1 = hash_keys(ranked, [0])
+        sh2 = hash_keys(ranked, [0], variant=1)
+        same = sv[1:] & sv[:-1] & (ranked.columns[0].data[1:]
+                                   == ranked.columns[0].data[:-1])
+        mixed = jnp.bool_(False)
+    else:
+        h1m = jnp.where(valid, hash_keys(batch, list(key_cols)),
+                        jnp.uint64(0xFFFFFFFFFFFFFFFF))
+        order = lsd_order(_sort_words([h1m]))
+        sh1 = h1m[order]
+        sh2 = hash_keys(batch, list(key_cols), variant=1)[order]
+        sv = valid[order]
+        one_h1 = sv[1:] & sv[:-1] & (sh1[1:] == sh1[:-1])
+        same = (one_h1 & (sh2[1:] == sh2[:-1])
+                & keys_equal(batch, order[1:], list(key_cols),
+                             batch, order[:-1], list(key_cols)))
+        mixed = jnp.any(one_h1 & ~same)
+    head = sv & jnp.concatenate([jnp.ones(1, jnp.bool_), ~same])
+    n_valid = jnp.sum(sv.astype(jnp.int32))
+    i = jnp.arange(cap, dtype=jnp.int32)
+    # a run's last row (the next is a head, or has no key) writes its
+    # length at its head, which a running maximum carried forward: a
+    # reversed running minimum from the next head costs the TPU compiler
+    # 50 s more (sandbox compile for a described v5e, 2^16 rows)
+    last = jax.lax.cummax(jnp.where(head, i, 0))
+    end = sv & jnp.concatenate([head[1:] | ~sv[1:], jnp.ones(1, jnp.bool_)])
+    count = jnp.zeros(cap, jnp.int32).at[jnp.where(end, last, cap)].set(
+        i - last + 1, mode="drop")
+    placed, largest = _place_rows(sh1, sh2, head)
+    return ((order, count), placed,
+            (largest, jnp.max(count), n_valid, mixed))
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+@jax.named_scope("join.build")
+def join_runs_table(placed, slots: int, lg_b: int) -> jax.Array:
+    """Second program of the runs build: the heads' row table, sized by
+    the largest bucket the caller read. Heads are distinct hash pairs by
+    construction: no twin check."""
+    return _fill_rows(placed, slots, lg_b)
+
+
+@jax.named_scope("join.expand")
+def expand_runs(start: jax.Array, cnt: jax.Array, out_cap: int
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Each row's run as pairs: row ``r`` with ``cnt[r]`` build rows from
+    sorted position ``start[r]`` fills ``cnt[r]`` consecutive slots of an
+    output of ``out_cap``. Returns (row, position, pairs) per slot: the row
+    that owns it (the last row whose first slot is at or before it: one
+    scatter of the rows that have a run to their first slots and a running
+    maximum, no search), the build's sorted position, and how many pairs
+    there are in all (int64; more than ``out_cap`` means some were cut:
+    the caller's to flag). Slots past the pairs repeat the last one."""
+    n = cnt.shape[0]
+    ends = jnp.cumsum(cnt)
+    first = ends - cnt  # wraps only past 2^31 pairs, where ``pairs`` flags
+    total = jnp.sum(cnt.astype(jnp.int64))
+    owns = (cnt > 0) & (first >= 0) & (first < out_cap)
+    owner = jnp.zeros(out_cap, jnp.int32).at[
+        jnp.where(owns, first, out_cap)].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
+    row = jax.lax.cummax(owner)
+    j = jnp.arange(out_cap, dtype=jnp.int32)
+    pos = start[row] + jnp.minimum(j - first[row], jnp.maximum(cnt[row] - 1,
+                                                               0))
+    return row, pos, total
 
 
 @jax.named_scope("join.probe")

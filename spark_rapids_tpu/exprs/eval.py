@@ -281,7 +281,12 @@ def _string_select(take: jax.Array, t: "StringVal", f: "StringVal") -> "StringVa
 
 
 def _string_eq(a: StringVal, b: StringVal, capacity: int) -> jax.Array:
-    """Byte-exact string equality (vectorized over the byte buffers)."""
+    """Byte-exact string equality (vectorized over the byte buffers).
+
+    Every byte position of ``a``'s buffer is owned by the last row that
+    starts at or before it, so the padding past the last row's end belongs
+    to the last row; only a row's own bytes may say "differs" (a full
+    batch's last row read as unequal to its equal, PERF.md PR 38)."""
     len_a = a.offsets[1:] - a.offsets[:-1]
     len_b = b.offsets[1:] - b.offsets[:-1]
     # compare byte-by-byte up to the shorter buffer via gather per row
@@ -291,7 +296,7 @@ def _string_eq(a: StringVal, b: StringVal, capacity: int) -> jax.Array:
     rel = j - a.offsets[rows]
     b_idx = jnp.clip(b.offsets[rows] + rel, 0, b.data.shape[0] - 1)
     within = rel < len_b[rows]
-    byte_neq = (a.data != b.data[b_idx]) | ~within
+    byte_neq = (rel < len_a[rows]) & ((a.data != b.data[b_idx]) | ~within)
     neq_any = jax.ops.segment_max(
         byte_neq.astype(jnp.int32), rows, num_segments=capacity,
         indices_are_sorted=True,
@@ -739,6 +744,9 @@ def eval_expr(expr: E.Expression, ctx: EvalContext) -> Val:
         return ColVal(data, valid)
 
     if isinstance(expr, E.In):
+        on_codes = _dict_in_literals(expr, ctx)
+        if on_codes is not None:
+            return on_codes
         v = eval_expr(expr.value, ctx)
         hit = jnp.zeros((cap,), jnp.bool_)
         any_null = jnp.zeros((cap,), jnp.bool_)
@@ -1788,11 +1796,8 @@ def _dict_equals_literal(expr, ctx: EvalContext) -> Optional[ColVal]:
     its code's entry does. Evaluating the column as a string first
     (``_column_to_val``) decodes every row into bytes, a byte-space gather
     of the whole batch: a second a 2^20-row batch on the v5e (PR 35: the
-    `customer` filter of `sf10_q3_join1`). Up to ``DICT_COMPARE_ENTRIES``
-    entries the codes are compared with each entry in turn, in registers;
-    past that one gather a row reads its entry's answer (9 ms a 2^20-row
-    batch there, whatever the dictionary's size). None where the
-    expression's shape is another."""
+    `customer` filter of `sf10_q3_join1`). None where the expression's
+    shape is another."""
     if not isinstance(expr, (E.EqualTo, E.EqualNullSafe)):
         return None
     for ref, lit in ((expr.left, expr.right), (expr.right, expr.left)):
@@ -1802,22 +1807,49 @@ def _dict_equals_literal(expr, ctx: EvalContext) -> Optional[ColVal]:
         c = ctx.batch.columns[ref.index]
         if not (c.is_dict and c.dict_size > 0):
             return None
-        entries = ColumnarBatch([c.dictionary],
-                                jnp.int32(c.dictionary.capacity))
-        ectx = EvalContext(entries)
-        same = _string_eq(ectx.column(0), eval_expr(lit, ectx),
-                          ectx.capacity)
-        code = c.data.astype(jnp.int32)
-        if c.dict_size <= DICT_COMPARE_ENTRIES:
-            eq = jnp.zeros(ctx.capacity, jnp.bool_)
-            for d in range(c.dict_size):
-                eq = eq | ((code == d) & same[d])
-        else:
-            eq = same[jnp.clip(code, 0, c.dict_size - 1)]
+        eq = _dict_codes_match(c, [lit], ctx.capacity)
         if isinstance(expr, E.EqualTo):
             return ColVal(eq, c.validity)
         return ColVal(eq & c.validity, _all_valid(ctx.capacity))
     return None
+
+
+def _dict_in_literals(expr: E.In, ctx: EvalContext) -> Optional[ColVal]:
+    """``dict-coded column IN ('a', 'b', ...)`` on the codes, as
+    ``_dict_equals_literal`` decides one literal (Q12's ``l_shipmode``
+    over 60M rows). None where an item is not a string literal (a NULL
+    item makes a miss NULL: the general form)."""
+    ref = expr.value
+    if not (isinstance(ref, E.ColumnRef) and all(
+            isinstance(i, E.Literal) and isinstance(i.value, str)
+            for i in expr.items)):
+        return None
+    c = ctx.batch.columns[ref.index]
+    if not (c.is_dict and c.dict_size > 0):
+        return None
+    return ColVal(_dict_codes_match(c, expr.items, ctx.capacity), c.validity)
+
+
+def _dict_codes_match(c, lits, capacity: int) -> jax.Array:
+    """Rows of the dict-coded column ``c`` whose entry equals one of the
+    string literals ``lits``: each literal is compared with the
+    dictionary's entries; up to ``DICT_COMPARE_ENTRIES`` entries the codes
+    are then compared with each entry in turn, in registers; past that one
+    gather a row reads its entry's answer (9 ms a 2^20-row batch on the
+    v5e, whatever the dictionary's size)."""
+    entries = ColumnarBatch([c.dictionary], jnp.int32(c.dictionary.capacity))
+    ectx = EvalContext(entries)
+    same = jnp.zeros(ectx.capacity, jnp.bool_)
+    for lit in lits:
+        same = same | _string_eq(ectx.column(0), eval_expr(lit, ectx),
+                                 ectx.capacity)
+    code = c.data.astype(jnp.int32)
+    if c.dict_size <= DICT_COMPARE_ENTRIES:
+        eq = jnp.zeros(capacity, jnp.bool_)
+        for d in range(c.dict_size):
+            eq = eq | ((code == d) & same[d])
+        return eq
+    return same[jnp.clip(code, 0, c.dict_size - 1)]
 
 
 def _eval_compare(expr: E.BinaryComparison, ctx: EvalContext) -> ColVal:
@@ -2037,9 +2069,18 @@ def output_schema(exprs: Sequence[E.Expression]) -> T.Schema:
 def project_batch(
     batch: ColumnarBatch, bound: Sequence[E.Expression], ansi: bool = False
 ) -> ColumnarBatch:
-    """Evaluate a bound projection over a batch (trace-time: called under jit)."""
+    """Evaluate a bound projection over a batch (trace-time: called under jit).
+    A column taken as it is stays as it is: a dictionary-coded column keeps
+    its codes (evaluated, every row would be decoded into bytes: a second a
+    2^20-row batch on the v5e, PERF.md PR 35)."""
     ctx = EvalContext(batch, ansi)
-    cols = [_val_to_column(eval_expr(e, ctx), e.dtype) for e in bound]
+    cols = []
+    for e in bound:
+        ref = e.child if isinstance(e, E.Alias) else e
+        if isinstance(ref, E.ColumnRef) and batch.columns[ref.index].is_dict:
+            cols.append(batch.columns[ref.index])
+        else:
+            cols.append(_val_to_column(eval_expr(e, ctx), e.dtype))
     # padding rows keep validity False
     active = batch.active_mask()
     cols = [

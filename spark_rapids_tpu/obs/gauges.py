@@ -114,10 +114,13 @@ CATALOG: "List[Tuple[str, str, str]]" = [
     ("fused_fallback_total", "counter",
      "Partitions a fused stage re-ran through the unfused operator chain "
      "(exec/fused.py _fall_back; one exec:fused-fallback span each); the "
-     "three counters below split it by cause"),
+     "four counters below split it by cause"),
     ("fused_fallback_join_refused_total", "counter",
      "Fallbacks because an absorbed join's build took a path whose probe "
      "is not traceable (duplicate keys, an empty build, an outer join)"),
+    ("fused_fallback_join_overflow_total", "counter",
+     "Fallbacks because a probe of the runs rung (build keys that repeat) "
+     "emitted more pairs than its batch's own capacity holds"),
     ("fused_fallback_carry_overflow_total", "counter",
      "Fallbacks because the streaming aggregate's groups (or their key "
      "bytes) outgrew the carry's capacity, the first batch's bucket"),
@@ -126,13 +129,19 @@ CATALOG: "List[Tuple[str, str, str]]" = [
      "semantics)"),
     ("join_build_path_total", "counter",
      "Hash-join build sides constructed (exec/join.py build_span; one "
-     "exec:join-build span each); the four counters below split it by the "
+     "exec:join-build span each); the five counters below split it by the "
      "probe structure the build ended in"),
     ("join_build_path_dense_total", "counter",
      "Build sides probed through the direct-address table (integer key "
      "domain under join.denseKey.maxDomain, unique keys)"),
     ("join_build_path_unique_total", "counter",
      "Build sides probed through the bucketed unique-key table"),
+    ("join_build_path_runs_total", "counter",
+     "Build sides whose keys repeat, probed fused through their runs: "
+     "the build sorted by key, one run a key (exec/join.py _prepare_runs)"),
+    ("join_run_pairs_total", "counter",
+     "(probe row, build row) pairs the runs rung's fused probes emitted "
+     "(read in their stages' one sync; exec:join-pairs spans)"),
     ("join_build_path_ht_total", "counter",
      "Build sides probed through the open-addressing hash table"),
     ("join_build_path_sorted_total", "counter",

@@ -76,16 +76,23 @@ CATALOG: "List[Tuple[str, str]]" = [
      "capacity)"),
     ("exec:join-build", "One hash join's build side: executing it and "
      "constructing the probe structure, the construction's host syncs "
-     "(join.dense_key_stats, join.dense_dup, join.table_stats, join.table_dup) as children; "
+     "(join.dense_key_stats, join.dense_dup, join.table_stats, join.table_dup, "
+     "join.runs_stats) as children; "
      "the unfused operator's also holds the peek of its first probe batch "
-     "(attrs: path = dense | unique | ht | sorted, rows = live build rows "
-     "where a sync already read them, capacity)"),
+     "(attrs: path = dense | unique | runs | ht | sorted, rows = live build "
+     "rows where a sync already read them, capacity, longest = the longest "
+     "run of one key where the runs rung read it)"),
+    ("exec:join-pairs", "The pairs a fused probe of the runs rung (exec/join.py: "
+     "a build whose keys repeat) emitted over one partition, read in its "
+     "stage's one sync: stamped from the build's end to that sync, so it "
+     "holds the probe's dispatches (attrs: pairs, batches)"),
     ("exec:topn", "One dispatch of a top-N (exec/sort.py TopNExec): a "
      "batch's k best rows by selection, or the partials' (attrs: k, rows = "
      "slots scanned, the batch's capacity, capacity = the output's)"),
     ("exec:fused-fallback", "A partition re-run through the unfused "
      "operator chain after its fused stage gave up, until the chain is "
-     "drained (attrs: cause = join-refused | carry-overflow | empty)"),
+     "drained (attrs: cause = join-refused | join-overflow | "
+     "carry-overflow | empty)"),
     ("cluster:map", "Map task executed by a cluster executor process"),
     ("cluster:reduce", "Reduce task executed by a cluster executor process"),
     ("shuffle:fetch", "One shuffle block fetch round-trip (client side)"),

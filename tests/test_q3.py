@@ -181,20 +181,23 @@ def test_q3_over_the_wire_equals_the_reference(seed, params):
 
 
 @pytest.mark.parametrize("far,asked", [
-    ((), "join.dense_dup"), ((1 << 40,), "join.table_dup")],
+    ((), "join.dense_dup"), ((1 << 40,), "join.table_stats")],
     ids=["dense", "unique"])
 def test_a_fallback_is_a_span_and_a_count(far, asked):
-    """A build with duplicate keys refuses the fused probe: the partition
-    re-runs unfused, the answer stays right, and the request's trace says
-    so (exec:fused-fallback, cause join-refused) beside the counter. With
-    a key far past ``join.denseKey.maxDomain`` the build is the unique
-    rung's, and what refuses it is the row table's own twin flag."""
+    """A build with duplicate keys is refused by the dense table (or, with
+    a key far past ``join.denseKey.maxDomain``, by the unique rung, whose
+    bucket of 100 rows passes its slots) and taken by the runs rung; a probe batch whose pairs
+    outgrow even its own capacity (16 probe rows of one key a batch of 64,
+    each meeting 100 build rows) re-runs the partition unfused: the answer
+    stays right, and the request's trace says so (exec:fused-fallback,
+    cause join-overflow) beside the counter."""
     import pyarrow as pa
     from spark_rapids_tpu.exprs.expr import Sum, col
     left = pa.table({"k": pa.array([1, 2, 3, 4] * 50, pa.int64()),
                      "v": pa.array(range(200), pa.int64())})
-    right = pa.table({"rk": pa.array([1, 1, 2, 3, *far], pa.int64()),
-                      "w": pa.array([10, 20, 30, 40, *far], pa.int64())})
+    right = pa.table({"rk": pa.array([1] * 100 + [2, 3, *far], pa.int64()),
+                      "w": pa.array([10] * 100 + [30, 40, *far],
+                                    pa.int64())})
     df = (from_arrow(left, batch_rows=64, partitions=1)
           .join(from_arrow(right, partitions=1), left_on="k", right_on="rk")
           .group_by("k").agg(Sum(col("w")).alias("s")))
@@ -208,18 +211,18 @@ def test_a_fallback_is_a_span_and_a_count(far, asked):
         tracing.set_capture(False, clear=True)
     after = gauges.snapshot()
     assert sorted(zip(*out.to_pydict().values())) == [
-        (1, 1500), (2, 1500), (3, 2000)]
+        (1, 50 * 1000), (2, 1500), (3, 2000)]
     if "TpuFusedStage" not in df.physical_plan().explain():
         pytest.skip("the planner did not fuse this join")
     falls = [e["args"] for e in events if e["name"] == "exec:fused-fallback"]
-    assert [f["cause"] for f in falls] == ["join-refused"]
+    assert [f["cause"] for f in falls] == ["join-overflow"]
     sites = [e["args"]["site"] for e in events
              if e["name"] == "exec:host-sync"]
-    assert asked in sites
+    assert asked in sites and "join.runs_stats" in sites
     assert after["fused_fallback_total"] - before[
         "fused_fallback_total"] == 1
-    assert after["fused_fallback_join_refused_total"] - before[
-        "fused_fallback_join_refused_total"] == 1
+    assert after["fused_fallback_join_overflow_total"] - before[
+        "fused_fallback_join_overflow_total"] == 1
 
 
 @pytest.mark.parametrize("value,error", [
@@ -301,6 +304,32 @@ def test_hits_that_outgrow_the_learned_capacity_run_again_unshrunk(agg):
     again = df.to_arrow()  # straight to the unshrunk programs
     assert again.num_rows == out.num_rows
     assert stage._learned == {0: None}
+
+
+def test_a_carried_aggregate_reads_the_short_flags_too():
+    """A learned capacity past an eighth of the batch (2,048 of 8,192: the
+    first batch's 600 hits) leaves the aggregate carried, not deferred; a
+    later batch of 5,000 hits outgrows it, and the carried steps' ``short``
+    flags, once ignored there (the hits past 2,048 were dropped: 2,648 of
+    5,600), run the partition again unshrunk."""
+    import numpy as np
+    import pyarrow as pa
+    from spark_rapids_tpu.exprs.expr import GreaterThan, Sum, col, lit
+    k = np.arange(2 * BATCH, dtype=np.int64) % 1000 + 10_000   # misses...
+    k[:600] = np.arange(600)                                   # ...600 hits
+    k[BATCH:BATCH + 5000] = np.arange(5000) % 1000             # 5,000 hits
+    left = pa.table({"k": pa.array(k),
+                     "v": pa.array(np.ones(2 * BATCH, np.int64))})
+    right = pa.table({"rk": pa.array(np.arange(1000, dtype=np.int64)),
+                      "w": pa.array(np.ones(1000, np.int64))})
+    df = (from_arrow(left, batch_rows=BATCH, partitions=1)
+          .filter(GreaterThan(col("v"), lit(0)))
+          .join(from_arrow(right, partitions=1), left_on="k", right_on="rk")
+          .group_by(lit(1).alias("g")).agg(Sum(col("w")).alias("s")))
+    before = gauges.snapshot()["fused_fallback_total"]
+    assert df.to_arrow()["s"].to_pylist() == [5600]
+    assert _stages(df.physical_plan(), [])[0]._learned == {0: None}
+    assert gauges.snapshot()["fused_fallback_total"] == before
 
 
 def test_hits_that_fit_keep_the_learned_capacity():

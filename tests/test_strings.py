@@ -279,3 +279,44 @@ def test_unsupported_regex_falls_back_in_plan():
     reasons = check_expr(E.RLike(col("s"), r"\bword\b"), schema)
     assert any("regex" in r for r in reasons)
     assert check_expr(E.RLike(col("s"), r"^ab+c$"), schema) == []
+
+
+# ---------------------------------------------------------------------------
+# IN over a string column (exprs/eval.py _string_eq, _dict_in_literals)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("rows", [1024, 8192])
+@pytest.mark.parametrize("encoded", [False, True], ids=["plain", "dict"])
+def test_in_over_strings_keeps_a_full_batch_s_last_row(rows, encoded):
+    """A batch that its rows fill: the last row matches an item of the
+    list, as the rows before it do. The byte space past the last row's
+    end belongs to the last row, and was read as a mismatch (PERF.md
+    PR 38: Q12's ``l_shipmode in ('MAIL', 'SHIP')`` lost row 8,191 of
+    every full batch of 8,192)."""
+    from spark_rapids_tpu.columnar.batch import dictionary_encode_table
+    from spark_rapids_tpu.exprs import eval as EV
+    modes = ["AIR", "FOB", "MAIL", "RAIL", "REG AIR", "SHIP", "TRUCK"]
+    rng = np.random.default_rng(rows)
+    vals = [modes[i] for i in rng.integers(0, len(modes), rows)]
+    vals[-1], vals[-2] = "TRUCK", "AIR"
+    t = pa.table({"m": pa.array(vals, pa.string())})
+    batch = batch_from_arrow(dictionary_encode_table(t) if encoded else t)
+    assert batch.capacity == rows and batch.columns[0].is_dict == encoded
+    bound = E.resolve(E.In(col("m"), [lit("AIR"), lit("TRUCK")]),
+                      T.Schema.from_arrow(t.schema))
+    got = EV.eval_expr(bound, EV.EvalContext(batch))
+    want = np.isin(vals, ["AIR", "TRUCK"])
+    assert np.asarray(got.validity).all()
+    assert np.array_equal(np.asarray(got.data), want)
+    assert bool(got.data[-1]) and bool(got.data[-2])
+
+
+def test_string_equality_ignores_the_bytes_past_the_last_row():
+    """``=`` of two plain string columns over a full batch, the last rows
+    equal: the comparison the IN fix lives in."""
+    a = ["x" * (i % 5) for i in range(1023)] + ["MAIL"]
+    t = pa.table({"a": pa.array(a, pa.string()),
+                  "b": pa.array(list(a), pa.string())})
+    (eq,) = pylist(t, [E.EqualTo(col("a"), col("b")).alias("eq")])
+    assert eq == [True] * 1024

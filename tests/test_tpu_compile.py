@@ -451,3 +451,70 @@ def test_unique_table_at_the_cells_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < CELL_BUILD_TEMP_BYTES
     hlo = compiled.as_text()
     assert " while(" not in hlo and " gather(" not in hlo
+
+
+def test_fused_q12_stage_programs(one_chip):
+    """The benchmark's Q12 (cell ``sf10_q12_dupjoin1``) as its fused
+    `orders` stage runs it, at a capacity this suite can afford: the
+    sizing program and a deferred window of two batches (presence byte ->
+    compaction by sort -> one-gather probe of the runs' heads -> the runs
+    expanded into pairs -> the dense first pass over the dict-coded ship
+    mode), and the runs rung's two build programs. The order keys are
+    shifted past ``join.denseKey.maxDomain`` as SF10's are. Every sort in
+    them has two operands (``K.lsd_order``'s signature), and the build's
+    runs need no gather-loop or search."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import datagen
+    import harness
+    from spark_rapids_tpu.columnar.batch import dictionary_encode_table
+    from spark_rapids_tpu.exec import fused as F
+    from spark_rapids_tpu.plan import from_arrow
+    import numpy as np
+    q12 = harness.load_by_path("queries", "q12")
+    raw = datagen.make(list(q12.TABLES), 0.001, 7)
+    li = raw["lineitem"]
+    # an order whose two lines both qualify: the build's keys repeat
+    two = int(np.flatnonzero(li["l_orderkey"][1:] == li["l_orderkey"][:-1])[0])
+    lo, _ = q12._year(q12.PARAMS)
+    for row in (two, two + 1):
+        li["l_shipmode"][row] = datagen.labels("l_shipmode").index("MAIL")
+        li["l_shipdate"][row], li["l_commitdate"][row] = lo + 20, lo + 30
+        li["l_receiptdate"][row] = lo + 40
+    for t, c in (("lineitem", "l_orderkey"), ("orders", "o_orderkey")):
+        raw[t][c] = raw[t][c] + (1 << 26)
+    d = {k: from_arrow(dictionary_encode_table(datagen.arrow(v)),
+                       batch_rows=8192, partitions=1)
+         for k, v in raw.items()}
+    plan = q12.build(d).physical_plan()
+    stage = plan.children[0]
+    assert isinstance(stage, F.TpuFusedStageExec) and stage.agg is not None
+    batch = next(iter(stage.child.execute(0)))
+    segs = stage._runtime_segments(0)
+    consts = tuple(seg.consts for seg in segs)
+    assert [getattr(s, "kind", None) for s in segs] == [None, "runs"]
+    stage._size(0, segs, batch, consts)
+    assert stage._learned == {0: 1024}
+    fns = stage._chain_fns(segs, batch.capacity)
+    sizing = _lower(F._make_sizing(fns), one_chip, batch, consts)
+    window = _lower(F._make_partial(fns, stage.agg), one_chip,
+                    (batch, batch), consts)
+    for lowered in (sizing, window):
+        assert set(_sort_operands(lowered.as_text())) <= {2}
+        assert lowered.compile() is not None
+    build = segs[1].build
+    runs = _lower(lambda b: K.join_runs.__wrapped__(b, (0,)), one_chip,
+                  build)
+    assert set(_sort_operands(runs.as_text())) == {2}
+    assert "stablehlo.while" not in runs.as_text()
+    assert runs.compile() is not None
+    placed = jax.eval_shape(
+        lambda b: K.join_runs.__wrapped__(b, (0,)), build)[1]
+    placed = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), placed)
+    table = _lower(lambda p: K.join_runs_table.__wrapped__(
+        p, 4, K.join_rows_lg_b(build.capacity)), one_chip, placed)
+    _no_loop_no_gather(table)
+    assert table.compile() is not None
