@@ -48,6 +48,9 @@ links, so the unfused plan is always re-executable):
 intermediates never materialize at operator granularity, so only the
 stage output is re-bucketed (base.execute applies it when
 ``shrink_output`` is set, which the stage derives from its constituents).
+A stage without a join whose chain ends in a filter's mask packs its
+output to a capacity learned from its first batch instead (``_size``), so
+that re-bucketing finds nothing to do and syncs on no batch.
 
 Metrics: constituents are not structural children but still get per-batch
 ``numOutputRows``/``numOutputBatches`` attribution — the fused body
@@ -157,21 +160,30 @@ def _make_body(fns):
     return body
 
 
-def _packed(batch: ColumnarBatch, live) -> ColumnarBatch:
-    """The batch front-packed: the rows of ``live``, in order."""
+def _packed(batch: ColumnarBatch, live,
+            cap: Optional[int] = None) -> ColumnarBatch:
+    """The batch front-packed: the rows of ``live``, in order, in ``cap``
+    slots (the batch's own by default; rows past ``cap`` are cut, which
+    is the caller's to flag and discard)."""
     if live is None:
         return batch
     from spark_rapids_tpu.exec import kernels as K
-    idx, n = K.compact_indices(live, batch.capacity)
+    idx, n = K.compact_indices(live, cap or batch.capacity)
     return K.gather_batch(batch, idx, n)
 
 
-def _make_plain(fns):
+def _make_plain(fns, pack_to: Optional[int] = None):
+    """The chain, its last batch front-packed. With ``pack_to`` (a plain
+    stage's learned capacity, under the batch's own) the rows its mask
+    keeps are packed into that many slots, and ``short`` says whether
+    they outgrew them."""
     body = _make_body(fns)
 
     def plain(batch, consts):
         out, live, counts, short = body(batch, consts)
-        return _packed(out, live), counts, short
+        if pack_to is not None:
+            short = short | (counts[-1] > pack_to)
+        return _packed(out, live, pack_to), counts, short
     return plain
 
 
@@ -291,6 +303,9 @@ FALLBACK_CAUSES = ("join-refused", "join-overflow", "carry-overflow",
                    "empty")
 _fallback_lock = threading.Lock()
 _fallbacks = dict.fromkeys(FALLBACK_CAUSES, 0)
+# partitions of a stage without a join that ran at their learned output
+# capacity, and those that outgrew it and ran again at the batches' own
+_plain = {"learned": 0, "unlearned": 0}
 
 
 def counters() -> dict:
@@ -298,8 +313,11 @@ def counters() -> dict:
     with _fallback_lock:
         out = {f"fused_fallback_{c.replace('-', '_')}_total": n
                for c, n in _fallbacks.items()}
+        plain = dict(_plain)
     out["fused_fallback_total"] = sum(out.values())
     out["fused_step_programs_total"] = len(_STEP_KEYS)
+    for k, n in plain.items():
+        out[f"fused_plain_{k}_total"] = n
     return out
 
 
@@ -319,7 +337,7 @@ class _OpSeg:
     mode, which may not raise for a row a filter dropped). Any other
     operator is given its input front-packed."""
 
-    __slots__ = ("op", "_fn", "_key")
+    __slots__ = ("op", "_fn", "_key", "live")
 
     def __init__(self, op: TpuExec):
         self.op = op
@@ -328,16 +346,19 @@ class _OpSeg:
             def fn(batch, live, _cst):
                 was = batch.active_mask() if live is None else live
                 return batch, was & keep(batch), None
+            self.live = "masks"
         elif getattr(op, "row_preserving", False):
             apply = op.batch_fn()
 
             def fn(batch, live, _cst):
                 return apply(batch), live, None
+            self.live = "keeps"
         else:
             apply = op.batch_fn()
 
             def fn(batch, live, _cst):
                 return apply(_packed(batch, live)), None, None
+            self.live = "packs"
         self._fn = fn
         self._key = op.batch_fn_key()
 
@@ -490,23 +511,50 @@ class TpuFusedStageExec(UnaryExec):
         bucket. A later batch that does not fit says so (the programs'
         ``short`` flag): what was cut is run again unshrunk and the stage
         stops shrinking that partition. The learned capacity stays with
-        the plan (``_learned``), which a repeated query reuses."""
+        the plan (``_learned``), which a repeated query reuses.
+
+        A stage without a join whose chain ends in a filter's mask
+        (``_packs_masked``) learns the capacity its output is packed to the
+        same way, from the rows the mask keeps (``_make_plain``)."""
         at = next((i for i, seg in enumerate(segs)
                    if hasattr(seg, "shrink_to")), None)
-        if at is None:
-            return
-        if partition not in self._learned:
+        if partition not in self._learned and (
+                at is not None or self._packs_masked(segs, first, consts)):
             cap = first.capacity
             fns = self._chain_fns(segs, cap)
             run = shared_jit(self._stage_key(segs, cap) + ("sizing",),
                              lambda: _make_sizing(fns))
-            hits = int(host_get(run(first, consts)[at], "fused.sizing"))
+            hits = int(host_get(run(first, consts)[-1 if at is None else at],
+                                "fused.sizing"))
             in_cap = cap
             for seg in segs[:at]:
                 in_cap = seg.out_cap(in_cap)
             want = bucket_capacity(max(self.SHRINK_HEADROOM * hits, 1024))
             self._learned[partition] = want if want * 2 <= in_cap else None
-        segs[at].shrink_to = self._learned[partition]
+        if at is not None:
+            segs[at].shrink_to = self._learned[partition]
+
+    def _packs_masked(self, segs, first: ColumnarBatch, consts) -> bool:
+        """Whether a stage without a join leaves its output to
+        ``shrink_to_live``'s sync a batch: the chain ends in a filter's
+        mask (no segment after the last one that masks packs), the conf
+        shrinks outputs and the first batch is large enough for it to.
+        Where the output carries a byte buffer (a plain string) it keeps
+        that path, which re-buckets the bytes too."""
+        from spark_rapids_tpu.config import conf as _C
+        masked = False
+        for seg in segs:
+            masked = seg.live == "masks" or (masked and seg.live == "keeps")
+        if not masked:
+            return False
+        cfg = _C.get_active()
+        if (not _C.SHRINK_TO_LIVE_ENABLED.get(cfg)
+                or first.capacity < _C.SHRINK_TO_LIVE_MIN_CAPACITY.get(cfg)):
+            return False
+        body = _make_body(self._chain_fns(segs, first.capacity))
+        out = jax.eval_shape(lambda b: body(b, consts)[0], first)
+        return all(c.offsets is None and c.children is None
+                   for c in out.columns)
 
     def _unlearn(self, partition: int, segs) -> None:
         self._learned[partition] = None
@@ -566,13 +614,21 @@ class TpuFusedStageExec(UnaryExec):
     def _execute_plain(self, partition: int, segs):
         consts = tuple(seg.consts for seg in segs)
         runs = {}
+        # no join: what was learned is the capacity the output is packed to
+        plain = not any(hasattr(seg, "shrink_to") for seg in segs)
 
         def program(cap: int):
             run = runs.get(cap)
             if run is None:
                 fns = self._chain_fns(segs, cap)
-                run = runs[cap] = shared_jit(self._stage_key(segs, cap),
-                                             lambda: _make_plain(fns))
+                key = self._stage_key(segs, cap)
+                to = self._learned.get(partition) if plain else None
+                if to is not None and to < cap:
+                    key += ("packed", to)
+                else:
+                    to = None
+                run = runs[cap] = shared_jit(key,
+                                             lambda: _make_plain(fns, to))
             return run
 
         def run_all(batches):
@@ -595,12 +651,17 @@ class TpuFusedStageExec(UnaryExec):
             # flag, as the deferred aggregate does. Where a batch's hits did
             # not fit what was learned, the partition runs again at the
             # probes' own capacity, and the stage stops shrinking it; where
-            # they do not fit that either, the unfused operators run it
+            # they do not fit that either, the unfused operators run it. A
+            # stage without a join packs its output to what it learned, and
+            # runs again at the batches' own capacity where that was short
             held = list(run_all(batches))
             cut, pairs = host_get(([short for _, short in held],
                                    self._pair_counts(segs)), "fused.short")
             if not any(bool(v) for v in cut):
                 self._note_pairs(segs, pairs)
+                if plain:
+                    with _fallback_lock:
+                        _plain["learned"] += 1
                 for out, _ in held:
                     yield out
                 return
@@ -608,6 +669,9 @@ class TpuFusedStageExec(UnaryExec):
             if not self._shrunk(partition):
                 yield from self._fall_back(partition, "join-overflow")
                 return
+            if plain:
+                with _fallback_lock:
+                    _plain["unlearned"] += 1
             self._unlearn(partition, segs)
             runs.clear()
             batches = self.child.execute(partition)

@@ -127,6 +127,13 @@ CATALOG: "List[Tuple[str, str, str]]" = [
     ("fused_fallback_empty_total", "counter",
      "Fallbacks of partitions without a batch (classic empty-input "
      "semantics)"),
+    ("fused_plain_learned_total", "counter",
+     "Partitions of a fused stage without a join that packed their output "
+     "to the capacity learned from their first batch (exec/fused.py; one "
+     "fused.short sync each, no shrink_to_live sync a batch)"),
+    ("fused_plain_unlearned_total", "counter",
+     "Partitions of such a stage whose rows outgrew what was learned and "
+     "ran again at the batches' own capacity (not a fallback)"),
     ("join_build_path_total", "counter",
      "Hash-join build sides constructed (exec/join.py build_span; one "
      "exec:join-build span each); the five counters below split it by the "

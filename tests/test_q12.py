@@ -102,15 +102,19 @@ def _assert_runs(before, after, events, requests):
 def test_q12_through_the_planner_equals_the_reference(seed, params):
     """Overrides.apply (DataFrame.to_arrow) over plain string columns (the
     ``In`` of ``_string_eq``): the dense table refuses the repeated keys,
-    the runs rung takes them."""
+    the runs rung takes them. ``shrinkToLive.minCapacity`` is the batch's,
+    so the build stage learns the capacity its output is packed to."""
     q12 = _q12()
     p = params or q12.PARAMS
     raw = _data(seed, p)
-    d = {t: from_arrow(datagen.arrow(raw[t]), batch_rows=BATCH, partitions=1)
+    conf = C.RapidsConf({C.SHRINK_TO_LIVE_MIN_CAPACITY.key: BATCH})
+    d = {t: from_arrow(datagen.arrow(raw[t]), conf, batch_rows=BATCH,
+                       partitions=1)
          for t in q12.TABLES}
     df = q12.build(d, params) if params else q12.build(d)
     assert "TpuFusedStage" in df.physical_plan().explain()
     before = gauges.snapshot()
+    was = C._ACTIVE
     tracing.set_capture(True, clear=True)
     try:
         with span.activate(span.new_trace()):
@@ -118,8 +122,19 @@ def test_q12_through_the_planner_equals_the_reference(seed, params):
         events = tracing.trace_events(clear=True)
     finally:
         tracing.set_capture(False, clear=True)
+        C._ACTIVE = was
     _assert_equals_reference([out], raw, p)
-    _assert_runs(before, gauges.snapshot(), events, 1)
+    after = gauges.snapshot()
+    _assert_runs(before, after, events, 1)
+    # the build stage (the filtered lineitem) packs its 22 batches to what
+    # its first one kept: no shrink_to_live sync, one fused.short
+    assert after["fused_plain_learned_total"] - before[
+        "fused_plain_learned_total"] == 1
+    assert after["fused_plain_unlearned_total"] == before[
+        "fused_plain_unlearned_total"]
+    sites = [e["args"]["site"] for e in events
+             if e["name"] == "exec:host-sync"]
+    assert "shrink_to_live" not in sites, sites
 
 
 @pytest.mark.parametrize("params", PARAMS, ids=["validation", "other"])

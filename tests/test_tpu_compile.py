@@ -462,7 +462,9 @@ def test_fused_q12_stage_programs(one_chip):
     mode), and the runs rung's two build programs. The order keys are
     shifted past ``join.denseKey.maxDomain`` as SF10's are. Every sort in
     them has two operands (``K.lsd_order``'s signature), and the build's
-    runs need no gather-loop or search."""
+    runs need no gather-loop or search. The build side's own stage (the
+    filtered `lineitem`, no join) compiles packed to a learned capacity
+    (PR 40)."""
     import sys
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
@@ -505,6 +507,15 @@ def test_fused_q12_stage_programs(one_chip):
     for lowered in (sizing, window):
         assert set(_sort_operands(lowered.as_text())) <= {2}
         assert lowered.compile() is not None
+    bstage = segs[1].op.children[1]
+    assert isinstance(bstage, F.TpuFusedStageExec) and bstage.agg is None
+    bsegs = bstage._runtime_segments(0)
+    first = next(iter(bstage.child.execute(0)))
+    packed = _lower(F._make_plain(bstage._chain_fns(bsegs, first.capacity),
+                                  1024), one_chip, first,
+                    tuple(seg.consts for seg in bsegs))
+    assert set(_sort_operands(packed.as_text())) == {2}
+    assert packed.compile() is not None
     build = segs[1].build
     runs = _lower(lambda b: K.join_runs.__wrapped__(b, (0,)), one_chip,
                   build)

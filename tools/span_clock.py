@@ -24,7 +24,8 @@ The last line of standard output is one JSON object: ``clock``, ``tiles``,
 ``sync_sites``, ``await_wakes`` (the front-end's submit counters and, on a
 program that has them, its counters of what ended each wait for a result,
 over the whole run), ``operators`` (the joins' build paths and the fused
-stages' fallbacks by their counters, and the exec:* spans per request) and
+stages' fallbacks and learned output capacities by their counters, and the
+exec:* spans per request) and
 the run's ordinary ``result``. Needs the chip, as benchmark/run.py
 does (``--rehearse-sf`` runs every step on the CPU and prints no device
 number). It wraps one private function of the harness to see the offset
@@ -224,13 +225,15 @@ def main() -> int:
         k: v for k, v in net_metrics.counters().items()
         if k.startswith(("net_await_wake_", "net_submit_"))}
     # over the whole run (set-up and warm rounds too): which probe
-    # structure the joins' build sides ended in, and every fallback of a
-    # fused stage by cause; per request of the window: each operator
-    # span's count and milliseconds
+    # structure the joins' build sides ended in, every fallback of a fused
+    # stage by cause, and the partitions of stages without a join packed
+    # to a learned capacity (or that outgrew it); per request of the
+    # window: each operator span's count and milliseconds
     from spark_rapids_tpu.obs import gauges
     report["operators"] = {
         "counters": {k: v for k, v in gauges.snapshot().items()
                      if k.startswith(("join_build_path_", "fused_fallback_",
+                                      "fused_plain_",
                                       "fused_step_programs_"))},
         "spans": operator_spans(seen["spans"], seen["requests"])}
     if rehearsal:  # no device number from a rehearsal
