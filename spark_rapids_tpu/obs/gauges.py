@@ -315,6 +315,16 @@ CATALOG: "List[Tuple[str, str, str]]" = [
      "Bytes freed by materialization-cache eviction"),
     ("reuse_evict_skipped_active_total", "counter",
      "Eviction candidates skipped because a reader was replaying them"),
+    ("host_gc_total", "counter",
+     "Python garbage collections in this process, every generation "
+     "(obs/span.py's gc.callbacks hook; one host:gc event each under "
+     "capture)"),
+    ("host_gc_gen2_total", "counter",
+     "Full (generation 2) collections: the long pauses that stop every "
+     "thread"),
+    ("host_gc_ns_total", "counter",
+     "Nanoseconds collections stopped the process; its rate over "
+     "sched_completed_total is the pause per served query"),
 ]
 
 
@@ -397,6 +407,8 @@ def snapshot() -> Dict[str, int]:
     out.update(_at.counters())
     from spark_rapids_tpu.net import metrics as _net_m
     out.update(_net_m.counters())
+    from spark_rapids_tpu.obs import span as _span
+    out.update(_span.gc_counters())
     return out
 
 

@@ -20,6 +20,16 @@ per-process random prefix (drawn once, again in a forked child) plus a
 counter, so they are unique across the processes of a trace without a
 system call per span.
 
+Host time, read only while a capture window is open: a live span records
+its thread's CPU time between open and finish as attr ``cpu_ns``
+(``time.thread_time_ns``), so its wall splits into work and waiting;
+``query:execute`` also records ``nivcsw`` (the executor thread's
+involuntary context switches inside it) and ``proc_cpu_ns`` (the whole
+process's CPU time at its close, a running total). Every garbage
+collection is timed on the spans' clock by a ``gc.callbacks`` hook: always
+into the ``host_gc_*`` counters, and under capture as one ``host:gc``
+event (attrs: generation, collected) that belongs to no trace.
+
 Cross-process propagation uses ``TraceContext``: a two-field value
 (``trace_id``, ``span_id`` of the would-be parent) whose ``to_wire()``
 tuple rides the cluster ctrl pipe (shuffle/cluster.py), is installed on
@@ -41,9 +51,12 @@ name.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import os
+import resource
 import threading
+import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
@@ -63,7 +76,10 @@ CATALOG: "List[Tuple[str, str]]" = [
      "lookup or the full Overrides.apply (attrs: query, cache_hit)"),
     ("query:compile", "First call of a newly bound jitted program: trace + "
      "compile or cache load (attrs: program); absent when nothing compiled"),
-    ("query:execute", "Execute window on the serving executor thread"),
+    ("query:execute", "Execute window on the serving executor thread "
+     "(attrs under capture: nivcsw = the thread's involuntary context "
+     "switches inside it, proc_cpu_ns = the process's CPU time at its "
+     "close)"),
     ("query:readback", "One batch_to_arrow of the result: device->host copy "
      "(its exec:host-sync child) plus host-side Arrow assembly (attrs: rows)"),
     ("query:finish", "Query close-out on the executor thread: profile "
@@ -112,6 +128,9 @@ CATALOG: "List[Tuple[str, str]]" = [
      "so it has no profiler annotation"),
     ("net:stream", "Result streaming window: Arrow IPC batches over the "
      "wire, RESULT_START through RESULT_END"),
+    ("host:gc", "One garbage collection, which stops every thread of the "
+     "process: stamped by the gc.callbacks hook at its stop, in no trace, "
+     "so it has no profiler annotation (attrs: generation, collected)"),
 ]
 
 _NAMES = frozenset(name for name, _ in CATALOG)
@@ -205,10 +224,11 @@ class Span:
     in the in-process log at finish, args carrying the ids + attrs).
     ``attrs`` may be filled in until ``finish()``. Parentage comes from the
     explicit ``ctx`` or the thread's current context; with neither, the
-    span is the root of a new trace. Open and finish on one thread."""
+    span is the root of a new trace. Open and finish on one thread (the
+    CPU time under capture is that thread's)."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "attrs",
-                 "_range")
+                 "_range", "_host0")
 
     def __init__(self, name: str, ctx: Optional[TraceContext] = None,
                  attrs: Optional[Dict] = None):
@@ -224,6 +244,7 @@ class Span:
         self.name = name
         self.attrs = dict(attrs) if attrs else {}
         self._range = tracing.TraceRange(name).open()
+        self._host0 = _host_open(name) if tracing.capturing() else None
 
     @property
     def start_ns(self) -> int:
@@ -244,9 +265,34 @@ class Span:
         rng, self._range = self._range, None
         if rng is None:
             return
-        args = (_args(self.trace_id, self.span_id, self.parent_id,
-                      self.attrs) if tracing.capturing() else None)
+        args = None
+        if tracing.capturing():
+            if self._host0 is not None:
+                _host_close(self._host0, self.attrs)
+            args = _args(self.trace_id, self.span_id, self.parent_id,
+                         self.attrs)
         rng.close(args=args, end_ns=end_ns, record=args is not None)
+
+
+# the spans that also read the thread's context switches and the process's
+# CPU time (one getrusage system call at open and close, under capture)
+_RUSAGE_SPANS = frozenset({"query:execute"})
+
+
+def _host_open(name: str) -> Tuple:
+    """(thread CPU ns, involuntary switches or None) at a span's open."""
+    csw = (resource.getrusage(resource.RUSAGE_THREAD).ru_nivcsw
+           if name in _RUSAGE_SPANS else None)
+    return time.thread_time_ns(), csw
+
+
+def _host_close(host0: Tuple, attrs: Dict) -> None:
+    cpu0, csw0 = host0
+    attrs["cpu_ns"] = time.thread_time_ns() - cpu0
+    if csw0 is not None:
+        attrs["nivcsw"] = (resource.getrusage(resource.RUSAGE_THREAD)
+                           .ru_nivcsw - csw0)
+        attrs["proc_cpu_ns"] = time.process_time_ns()
 
 
 def _args(trace_id: str, span_id: str, parent_id: Optional[str],
@@ -320,6 +366,46 @@ def task_span(name: str, ctx: Optional[TraceContext] = None,
         return
     with span(name, ctx=ctx, attrs=attrs) as s:
         yield s
+
+
+# -- garbage-collection pauses ------------------------------------------------
+# A collection stops every thread (it holds the GIL), so a pause shows in
+# whatever spans it falls inside. The hook runs on every collection; only the
+# collecting thread runs it, one collection at a time, so plain globals do.
+
+_gc_start_ns = 0
+_gc_total = 0
+_gc_gen2_total = 0
+_gc_ns_total = 0
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    global _gc_start_ns, _gc_total, _gc_gen2_total, _gc_ns_total
+    now = time.perf_counter_ns()
+    if phase == "start":
+        _gc_start_ns = now
+        return
+    dur = now - _gc_start_ns
+    _gc_total += 1
+    _gc_ns_total += dur
+    if info["generation"] == 2:
+        _gc_gen2_total += 1
+    if tracing.capturing():
+        tracing.record_event("host:gc", _gc_start_ns, dur,
+                             args={"generation": info["generation"],
+                                   "collected": info["collected"]})
+
+
+if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)
+
+
+def gc_counters() -> Dict[str, int]:
+    """For obs/gauges.snapshot(): collections, those of generation 2, and
+    the nanoseconds they stopped the process."""
+    return {"host_gc_total": _gc_total,
+            "host_gc_gen2_total": _gc_gen2_total,
+            "host_gc_ns_total": _gc_ns_total}
 
 
 # -- trace reassembly --------------------------------------------------------

@@ -21,8 +21,10 @@ import jax
 # One lock guards BOTH the event list and the capture flag: a range that
 # observes the flag appends under the same critical section, so a capture
 # window can never tear (flag off, event still appended) and back-to-back
-# windows cannot interleave stale events.
-_events_lock = threading.Lock()
+# windows cannot interleave stale events. Reentrant: a garbage collection
+# runs its callbacks between two bytecodes of whatever thread it stopped,
+# so obs/span's host:gc event can be recorded inside this critical section.
+_events_lock = threading.RLock()
 _events: List[Dict] = []
 _capture_events = False
 # Worker/process identity stamped onto every recorded event ("exec-0",
@@ -122,8 +124,9 @@ class TraceRange:
     ``jax.profiler.TraceAnnotation`` of the same name for the range's life
     (so a running jax profiler writes it to the ``.xplane.pb`` host plane,
     on the device trace's own clock) and, at close, one event in the
-    in-process log when a capture window is open. ``obs/span.Span`` and the
-    per-batch operator event of ``exec/base.py`` are both built on it.
+    in-process log when a capture window is open. ``obs/span.Span`` (which
+    adds the thread's CPU time under capture) and the per-batch operator
+    event of ``exec/base.py`` are both built on it.
     With no profiler running and capture off a range costs the native
     TraceMe's flag check and two clock reads: no lock."""
 

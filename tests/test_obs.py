@@ -4,6 +4,7 @@ metrics-level filtering, task-metrics registry bounds, and trace-window
 hygiene (docs/observability.md).
 """
 
+import gc
 import json
 import pathlib
 import sys
@@ -497,6 +498,8 @@ def test_tracing_process_label_stamps_events():
         tracing.record_event("labeled2", 0, 10, args={"x": 1})
         evs = tracing.trace_events(clear=True)
         assert all(e["args"]["worker"] == "worker-7" for e in evs)
+        # a collection in between is logged too, and labelled the same
+        evs = [e for e in evs if e["name"] != "host:gc"]
         assert evs[1]["args"]["x"] == 1
     finally:
         tracing.set_capture(False)
@@ -669,10 +672,11 @@ def test_span_catalog_lint_shape():
 # -- one span model: event + profiler annotation, real intervals -----------
 
 class _CountingLock:
-    """Stands in for a threading.Lock and counts how often it is taken."""
+    """Stands in for the event log's lock and counts how often it is
+    taken."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self.acquired = 0
 
     def __enter__(self):
@@ -716,6 +720,7 @@ def test_span_with_capture_off_takes_no_lock_and_draws_no_ids(monkeypatch):
     assert sp.TraceContext.from_wire(outer.context().to_wire()).span_id \
         == outer.span_id
     tracing.set_capture(True, clear=True)
+    gc.disable()  # a collection's host:gc event would take the lock too
     try:
         base = lock.acquired
         work()
@@ -723,6 +728,7 @@ def test_span_with_capture_off_takes_no_lock_and_draws_no_ids(monkeypatch):
         assert drawn == []
         assert len(sp.span_events(tracing.trace_events(clear=True))) == 4
     finally:
+        gc.enable()
         tracing.set_capture(False)
         tracing.trace_events(clear=True)
 
@@ -755,7 +761,8 @@ def test_jax_profiler_trace_holds_an_annotation_per_live_span(tmp_path):
     """Every live span (and the per-batch operator range) is also a
     jax.profiler.TraceAnnotation of the same name: a profiler trace taken
     on the CPU carries them on its own clock, each as long as the event the
-    program logged. The two spans stamped after the fact carry none."""
+    program logged. The spans stamped after the fact, and the collections'
+    host:gc events, carry none."""
     import glob
 
     import jax
@@ -797,7 +804,7 @@ def test_jax_profiler_trace_holds_an_annotation_per_live_span(tmp_path):
     logged = {}
     for e in events:
         logged.setdefault(e["name"], []).append(e["dur_ns"])
-    stamped = {"query:queue-wait", "net:wake-lag"}
+    stamped = {"query:queue-wait", "net:wake-lag", "host:gc"}
     live = set(logged) - stamped
     assert {"net:request", "net:client-send", "query:submit", "query:admit",
             "query:execute", "query:plan", "query:readback", "query:finish",
@@ -896,12 +903,195 @@ def test_trace_range_is_the_one_primitive():
         assert s.start_ns == s._range.start_ns > rng.start_ns
         s.finish()
         s.finish()  # inert after the first
-        events = tracing.trace_events(clear=True)
+        events = [e for e in tracing.trace_events(clear=True)
+                  if e["name"] != "host:gc"]  # a collection in between
     finally:
         tracing.set_capture(False)
         tracing.trace_events(clear=True)
     assert [e["name"] for e in events] == ["SomeExec", "query:plan"]
     assert events[0]["args"] == {"partition": 3}
+
+
+# -- host time: CPU per span, collections on the spans' clock ---------------
+
+def _captured(fn):
+    """Runs ``fn`` inside a capture window; returns its logged events."""
+    tracing.set_capture(True, clear=True)
+    try:
+        fn()
+        return tracing.trace_events(clear=True)
+    finally:
+        tracing.set_capture(False)
+        tracing.trace_events(clear=True)
+
+
+def _one_span(name, body):
+    from spark_rapids_tpu.obs import span as sp
+
+    def run():
+        with sp.span(name):
+            body()
+    [ev] = [e for e in sp.span_events(_captured(run)) if e["name"] == name]
+    return ev
+
+
+def test_span_cpu_ns_follows_a_busy_loop():
+    """A span over work its own thread does records a CPU time close to its
+    wall (the best of five tries: a loaded machine may take the core away
+    for a while, which is what off-CPU time is)."""
+    import time
+
+    def spin():
+        end = time.perf_counter() + 0.05
+        while time.perf_counter() < end:
+            pass
+    ratios = []
+    for _ in range(5):
+        ev = _one_span("query:plan", spin)
+        ratios.append(ev["args"]["cpu_ns"] / ev["dur_ns"])
+        if abs(ratios[-1] - 1) <= 0.2:
+            break
+    assert abs(ratios[-1] - 1) <= 0.2, ratios
+
+
+def test_span_cpu_ns_leaves_out_a_sleep():
+    import time
+    ev = _one_span("query:plan", lambda: time.sleep(0.05))
+    assert ev["dur_ns"] >= 50_000_000
+    assert 0 <= ev["args"]["cpu_ns"] < 0.1 * ev["dur_ns"]
+
+
+def test_host_attrs_only_where_declared():
+    """query:execute alone also carries the thread's involuntary switches
+    and the process's CPU time; a stamped span covers no work of the thread
+    that stamps it and carries no cpu_ns."""
+    import time
+
+    from spark_rapids_tpu.obs import span as sp
+
+    def run():
+        with sp.activate(sp.new_trace()):
+            with sp.span("query:execute"):
+                with sp.span("query:plan"):
+                    pass
+                sp.record_span("query:queue-wait", 0, 10)
+    t0 = time.process_time_ns()
+    events = {e["name"]: e["args"] for e in sp.span_events(_captured(run))}
+    ex = events["query:execute"]
+    assert ex["nivcsw"] >= 0 and ex["proc_cpu_ns"] >= t0
+    assert ex["cpu_ns"] >= events["query:plan"]["cpu_ns"] >= 0
+    assert "nivcsw" not in events["query:plan"]
+    assert "proc_cpu_ns" not in events["query:plan"]
+    assert "cpu_ns" not in events["query:queue-wait"]
+
+
+def test_capture_off_reads_no_host_clock(monkeypatch):
+    """With capture off a span reads neither the thread's CPU clock nor
+    getrusage: what it costs stays one flag read."""
+    import resource
+    import time
+
+    from spark_rapids_tpu.obs import span as sp
+    calls = []
+    real_cpu, real_ru = time.thread_time_ns, resource.getrusage
+    monkeypatch.setattr(time, "thread_time_ns",
+                        lambda: calls.append("cpu") or real_cpu())
+    monkeypatch.setattr(resource, "getrusage",
+                        lambda who: calls.append("ru") or real_ru(who))
+    tracing.set_capture(False)
+    with sp.activate(sp.new_trace()):
+        with sp.span("query:execute"):
+            sp.Span("exec:host-sync").finish()
+    assert calls == []
+    _captured(lambda: sp.Span("query:execute").finish())
+    assert calls.count("cpu") == 2 and calls.count("ru") == 2
+
+
+def test_a_collection_is_one_host_gc_event_and_counted():
+    """gc.collect() under capture logs exactly one host:gc event (no trace
+    id: it stops every thread, so it is nobody's span), generation 2, on the
+    spans' clock, and the always-on host_gc_* counters rise by it."""
+    import time
+
+    from spark_rapids_tpu.obs import span as sp
+    was = gc.isenabled()
+    gc.disable()  # no collection but the one asked for
+    try:
+        before = sp.gc_counters()
+        t0 = time.perf_counter_ns()
+        events = _captured(gc.collect)
+        t1 = time.perf_counter_ns()
+        after = sp.gc_counters()
+    finally:
+        if was:
+            gc.enable()
+    [ev] = [e for e in events if e["name"] == "host:gc"]
+    assert ev["args"]["generation"] == 2 and ev["args"]["collected"] >= 0
+    assert "trace_id" not in ev["args"]
+    assert t0 <= ev["start_ns"] <= ev["start_ns"] + ev["dur_ns"] <= t1
+    assert after["host_gc_total"] - before["host_gc_total"] == 1
+    assert after["host_gc_gen2_total"] - before["host_gc_gen2_total"] == 1
+    assert after["host_gc_ns_total"] - before["host_gc_ns_total"] \
+        == ev["dur_ns"]
+    snap = gauge_snapshot()
+    assert snap["host_gc_total"] >= after["host_gc_total"]
+    # with capture off a collection is counted and logs nothing
+    gc.collect()
+    assert not [e for e in tracing.trace_events() if e["name"] == "host:gc"]
+    assert sp.gc_counters()["host_gc_total"] > after["host_gc_total"]
+
+
+def test_a_collection_inside_the_event_lock_is_logged():
+    """A collection can stop a thread inside the event log's critical
+    section; its host:gc event is then logged by that same thread, so the
+    lock has to be reentrant (a plain Lock deadlocks here)."""
+    def collect_holding_the_lock():
+        with tracing._events_lock:
+            gc.collect()
+    events = _captured(collect_holding_the_lock)
+    assert [e for e in events if e["name"] == "host:gc"]
+
+
+def test_spans_and_collections_on_many_threads_lose_no_event():
+    """Eight threads open spans and collect while capture is on, with a
+    short switch interval: every span and every collection is logged once
+    and no thread is left waiting on the event log's lock."""
+    import sys
+
+    from spark_rapids_tpu.obs import span as sp
+    n_threads, per_thread = 8, 200
+
+    def work():
+        for i in range(per_thread):
+            with sp.span("query:plan", ctx=sp.new_trace()):
+                if i % 50 == 0:
+                    gc.collect(0)
+
+    def run():
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    before = sp.gc_counters()["host_gc_total"]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        events = _captured(run)
+    finally:
+        sys.setswitchinterval(interval)
+    collected = sp.gc_counters()["host_gc_total"] - before
+    assert sum(e["name"] == "query:plan" for e in events) \
+        == n_threads * per_thread
+    # a collect() that meets another thread's collection returns at once
+    assert sum(e["name"] == "host:gc" for e in events) == collected > 0
+
+
+def test_span_catalog_lint_accepts_host_gc():
+    from tools.lint import span_catalog as L
+    assert "host:gc" in L.catalog_names(str(_ROOT))
+    assert L.run_pass(str(_ROOT)) == []
 
 
 def test_span_clock_matches_each_event_to_its_nearest_annotation():
@@ -926,6 +1116,62 @@ def test_span_clock_matches_each_event_to_its_nearest_annotation():
     assert (rep["n"], rep["median_ns"], rep["max_abs_ns"]) == (3, -100, 500)
     assert rep["names"]["query:plan"] == {"n": 2, "median_ns": -70.0,
                                           "max_abs_ns": 100}
+
+
+def test_span_clock_host_report_places_the_slowest_request():
+    """tools/span_clock.py's host report on made-up spans: two requests,
+    the slow one holding a 20 ms collection and 5 ms more off the CPU; the
+    report ranks it first and says what grew in it."""
+    from tools import span_clock
+    bench = str(_ROOT / "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    ms = 1_000_000
+
+    def ev(name, t, dur, trace, sid, parent=None, **attrs):
+        return {"name": name, "start_ns": int(t * ms),
+                "dur_ns": int(dur * ms), "thread": 1,
+                "args": dict({"trace_id": trace, "span_id": sid,
+                              "parent_id": parent}, **attrs)}
+
+    def request(trace, t0, extra):
+        r, ex = trace + ":r", trace + ":ex"
+        return [
+            ev("net:request", t0, 30 + extra, trace, r, query=trace,
+               cpu_ns=2 * ms),
+            ev("net:accept", t0 + 1, 1, trace, trace + ":ac", r,
+               query=trace, cpu_ns=ms),
+            ev("query:execute", t0 + 2, 20 + extra, trace, ex, r,
+               query=trace, cpu_ns=10 * ms, nivcsw=extra // 5,
+               proc_cpu_ns=0),
+            ev("exec:host-sync", t0 + 5, 8, trace, trace + ":s", ex,
+               site="fused.overflow_flags", cpu_ns=ms // 2),
+            ev("net:stream", t0 + 23 + extra, 2, trace, trace + ":st", r,
+               query=trace, cpu_ns=ms)]
+    spans = (request("fast", 0, 0) + request("slow", 100, 25)
+             + [{"name": "host:gc", "start_ns": 110 * ms, "dur_ns": 20 * ms,
+                 "thread": 2, "args": {"generation": 2, "collected": 9}}])
+    rep = span_clock.host_time(spans, [{"name": "fast"}, {"name": "slow"}],
+                               profiler=(50 * ms, 150 * ms))
+    assert rep["requests"] == 2 and rep["profiler_s"] == [0.05, 0.15]
+    slow, fast = rep["slowest"]
+    assert (slow["request"], fast["request"]) == ("slow", "fast")
+    assert (slow["at_s"], fast["at_s"]) == (0.1, 0)
+    assert slow["children"] == {"exec:host-sync": [8, 0.5]}
+    assert slow["gc_ms"] == 20 and fast["gc_ms"] == 0
+    # off-CPU: wall - CPU - syncs + the syncs' own CPU
+    assert fast["execute_offcpu_ms"] == 20 - 10 - 8 + 0.5
+    assert slow["execute_offcpu_ms"] == 45 - 10 - 8 + 0.5
+    assert (slow["frontend_cpu_ms"], slow["client_cpu_ms"]) == (2, 2)
+    assert slow["sync_cpu_ms"] == 0.5 and slow["nivcsw"] == 5
+    assert slow["live_spans"] == 5 and slow["tiles"]["net:request"] == 55
+    assert rep["gc_by_generation"] == {"2": {
+        "per_request": 0.5, "ms_per_request": 10.0, "longest_ms": 20.0}}
+    assert rep["mean"]["execute_cpu_ms"] == 10
+    # a program whose spans carry no cpu_ns gets no report
+    for e in spans:
+        (e.get("args") or {}).pop("cpu_ns", None)
+    assert span_clock.host_time(spans, [{"name": "fast"}]) == {}
 
 
 def test_named_scopes_are_metadata_only():

@@ -787,6 +787,8 @@ def test_query_plan_is_a_real_interval_before_the_first_operator():
     [execute] = [s for s in spans if s["name"] == "query:execute"]
     assert _inside(plan, execute) and plan["dur_ns"] > 0
     assert plan["parent_id"] == execute["span_id"]
+    cpu_ns = plan["attrs"].pop("cpu_ns")  # the thread's CPU, under capture
+    assert 0 <= cpu_ns <= plan["dur_ns"] + 1_000_000
     assert plan["attrs"] == {"query": "plan-warm", "cache_hit": True}
     [cold] = [s for s in first if s["name"] == "query:plan"]
     assert cold["attrs"]["cache_hit"] is False

@@ -17,15 +17,21 @@ program's spans against the run's own ``.xplane.pb``:
    net:request's self time) summed, beside ``net.overhead_ms``, which times
    the same thing from outside; and the host syncs by site (how many and
    how long per request, and under which span).
+3. per request of the whole window, the host's own time: the CPU of each
+   thread's top-level spans (client net:request, front-end net:accept +
+   net:stream, executor query:execute), the executor's off-CPU time and
+   involuntary context switches, the syncs' own CPU, the garbage-collection
+   pauses by generation, and the five slowest requests with what each
+   spent: tiles, syncs, CPU, off-CPU, switches, pauses.
 
     python3 tools/span_clock.py --workload sf1_q1_agg1 --seed 7 --seconds 30
 
 The last line of standard output is one JSON object: ``clock``, ``tiles``,
-``sync_sites``, ``await_wakes`` (the front-end's submit counters and, on a
-program that has them, its counters of what ended each wait for a result,
-over the whole run), ``operators`` (the joins' build paths and the fused
-stages' fallbacks and learned output capacities by their counters, and the
-exec:* spans per request) and
+``sync_sites``, ``host``, ``await_wakes`` (the front-end's submit counters
+and, on a program that has them, its counters of what ended each wait for a
+result, over the whole run), ``operators`` (the joins' build paths, the
+pairs of the runs rung, and the fused stages' fallbacks and learned output
+capacities by their counters, and the exec:* spans per request) and
 the run's ordinary ``result``. Needs the chip, as benchmark/run.py
 does (``--rehearse-sf`` runs every step on the CPU and prints no device
 number). It wraps one private function of the harness to see the offset
@@ -45,7 +51,9 @@ import sys  # noqa: E402
 import tempfile  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-STAMPED = ("query:queue-wait", "net:wake-lag")  # record_span: no annotation
+# stamped afterwards (record_span, the gc hook): no annotation
+STAMPED = ("query:queue-wait", "net:wake-lag", "exec:join-pairs", "host:gc")
+SLOWEST = 5
 
 
 def match(annotations: dict, events: list, shift: int) -> dict:
@@ -85,38 +93,124 @@ def summarize(diffs: dict) -> dict:
             "max_abs_ns": max((abs(x) for x in every), default=None)}
 
 
+def tile_row(tree: list):
+    """One request's wire tiles (ms), or None where it has no net:request."""
+    import spantree
+    from tracered import union
+    roots = spantree.named(tree, "net:request")
+    if not roots:
+        return None
+    root = roots[0]
+    tail = union([(s["start"], s["end"]) for s in tree
+                  if s["name"] in ("net:stream", "net:client-recv")])
+    row = {n: spantree.total_ms(tree, n) for n in
+           ("net:client-send", "net:accept", "query:submit",
+            "net:wake-lag", "query:queue-wait", "query:execute")}
+    row["net:stream+client-recv"] = sum(e - s for s, e in tail) / 1e6
+    row["net:request self"] = spantree.self_ms(
+        root, [s for s in tree if s is not root])
+    row["net:request"] = (root["end"] - root["start"]) / 1e6
+    row["tiles_sum"] = (row["net:client-send"] + row["net:accept"]
+                        + row["net:wake-lag"]
+                        + row["net:stream+client-recv"]
+                        + row["net:request self"])
+    row["request_minus_wait_and_execute"] = (
+        row["net:request"] - row["query:queue-wait"]
+        - row["query:execute"])
+    return row
+
+
 def tiles(spans: list, requests: list) -> dict:
     """Medians over the window's requests of each tile of the wire, their
     sum, and the same quantity taken as net.overhead_ms takes it."""
     import spantree
-    from tracered import union
-    rows = []
-    for tree in spantree.by_request(spans, requests).values():
-        roots = spantree.named(tree, "net:request")
-        if not roots:
-            continue
-        root = roots[0]
-        tail = union([(s["start"], s["end"]) for s in tree
-                      if s["name"] in ("net:stream", "net:client-recv")])
-        row = {n: spantree.total_ms(tree, n) for n in
-               ("net:client-send", "net:accept", "query:submit",
-                "net:wake-lag", "query:queue-wait", "query:execute")}
-        row["net:stream+client-recv"] = sum(e - s for s, e in tail) / 1e6
-        row["net:request self"] = spantree.self_ms(
-            root, [s for s in tree if s is not root])
-        row["net:request"] = (root["end"] - root["start"]) / 1e6
-        row["tiles_sum"] = (row["net:client-send"] + row["net:accept"]
-                            + row["net:wake-lag"]
-                            + row["net:stream+client-recv"]
-                            + row["net:request self"])
-        row["request_minus_wait_and_execute"] = (
-            row["net:request"] - row["query:queue-wait"]
-            - row["query:execute"])
-        rows.append(row)
+    rows = [r for r in map(tile_row, spantree.by_request(
+        spans, requests).values()) if r is not None]
     if not rows:
         return {}
     return dict({k: statistics.median(r[k] for r in rows) for k in rows[0]},
                 requests=len(rows))
+
+
+def host_time(spans: list, requests: list, profiler=None) -> dict:
+    """Means over the window's requests of each thread's CPU (ms) in its
+    top-level spans, the executor's off-CPU time (as the benchmark's
+    host.execute_offcpu_ms_per_query takes it) and involuntary switches,
+    the syncs' wall and own CPU, and the live spans (those that read the
+    CPU clock) of a request; the window's garbage-collection pauses
+    per request by generation; and the SLOWEST requests by net:request,
+    each with its tiles, what it spent, its start (s after the window's
+    first request) and the wall and CPU ms of query:execute's children by
+    name. ``profiler``, (start, end) ns of the profiler's trace through
+    its stop_trace, is reported on the same scale. Empty on a program
+    whose spans carry no cpu_ns."""
+    import harness
+    import spantree
+    from tracered import union
+    offcpu = harness.load_by_path("layer_metrics",
+                                  "host.execute_offcpu_ms_per_query")
+    pauses = [e for e in spans if e["name"] == "host:gc"]
+
+    def cpu_ms(tree, *names):
+        return sum(s["attrs"].get("cpu_ns", 0) for n in names
+                   for s in spantree.named(tree, n)) / 1e6
+
+    rows = []
+    for name, tree in spantree.by_request(spans, requests).items():
+        roots = spantree.named(tree, "net:request")
+        execs = spantree.named(tree, "query:execute")
+        if not roots or not execs or "cpu_ns" not in execs[0]["attrs"]:
+            continue
+        root, ex = roots[0], execs[0]
+        below = offcpu._below(tree, ex)
+        syncs = [s for s in below if s["name"] == "exec:host-sync"]
+        lo, hi = root["start"], root["end"]
+        gc_ms = sum(min(e["start_ns"] + e["dur_ns"], hi)
+                    - max(e["start_ns"], lo) for e in pauses
+                    if e["start_ns"] < hi and e["start_ns"] + e["dur_ns"] > lo)
+        children = {}
+        for c in tree:
+            if c["parent"] == ex["id"]:
+                w = children.setdefault(c["name"], [0.0, 0.0])
+                w[0] += (c["end"] - c["start"]) / 1e6
+                w[1] += c["attrs"].get("cpu_ns", 0) / 1e6
+        rows.append({
+            "request": name, "start_ns": lo, "wall_ms": (hi - lo) / 1e6,
+            "client_cpu_ms": cpu_ms(tree, "net:request"),
+            "frontend_cpu_ms": cpu_ms(tree, "net:accept", "net:stream"),
+            "execute_wall_ms": (ex["end"] - ex["start"]) / 1e6,
+            "execute_cpu_ms": ex["attrs"]["cpu_ns"] / 1e6,
+            "execute_offcpu_ms": offcpu._offcpu(tree),
+            "nivcsw": ex["attrs"].get("nivcsw"),
+            "sync_ms": sum(e - s for s, e in union(
+                [(s["start"], s["end"]) for s in syncs])) / 1e6,
+            "sync_cpu_ms": sum(s["attrs"].get("cpu_ns", 0)
+                               for s in syncs) / 1e6,
+            "gc_ms": gc_ms / 1e6,
+            "live_spans": sum(1 for s in tree if "cpu_ns" in s["attrs"]),
+            "tiles": tile_row(tree), "children": children})
+    if not rows:
+        return {}
+    t0 = min(r["start_ns"] for r in rows)
+    for r in rows:
+        r["at_s"] = (r.pop("start_ns") - t0) / 1e9
+    keys = [k for k in rows[0]
+            if k not in ("request", "tiles", "children", "at_s")]
+    mean = {k: statistics.fmean(r[k] for r in rows if r[k] is not None)
+            for k in keys if any(r[k] is not None for r in rows)}
+    by_gen = {}
+    for e in pauses:
+        g = by_gen.setdefault(str(e["args"]["generation"]), [0, 0.0, 0.0])
+        g[0] += 1
+        g[1] += e["dur_ns"] / 1e6
+        g[2] = max(g[2], e["dur_ns"] / 1e6)
+    return {"requests": len(rows), "mean": mean,
+            "profiler_s": profiler and [(t - t0) / 1e9 for t in profiler],
+            "gc_by_generation": {
+                gen: {"per_request": c / len(rows),
+                      "ms_per_request": ms / len(rows), "longest_ms": top}
+                for gen, (c, ms, top) in sorted(by_gen.items())},
+            "slowest": sorted(rows, key=lambda r: -r["wall_ms"])[:SLOWEST]}
 
 
 def sync_sites(spans: list, requests: list) -> dict:
@@ -193,6 +287,13 @@ def main() -> int:
         shutil.copy(found, xplane)
         return reduce_trace(info, spans, requests, log, keep_dir)
     harness._reduce_trace = spy
+    trace_window = harness._trace_window
+
+    def timed_window(*a):  # when the profiler's stop_trace returned
+        info = trace_window(*a)
+        seen["stopped_ns"] = time.perf_counter_ns()
+        return info
+    harness._trace_window = timed_window
     rehearsal = args.rehearse_sf is not None
     try:
         out = harness.run_cell(args.workload, args.seed, args.seconds, True,
@@ -219,21 +320,27 @@ def main() -> int:
               and e["start_ns"] + e["dur_ns"] <= info["exit_ns"]]
     report = {"clock": summarize(match(annotations, inside, shift)),
               "tiles": tiles(seen["spans"], seen["requests"]),
-              "sync_sites": sync_sites(seen["spans"], seen["requests"])}
+              "sync_sites": sync_sites(seen["spans"], seen["requests"]),
+              "host": host_time(seen["spans"], seen["requests"],
+                                (info["enter_ns"], seen["stopped_ns"]))}
     from spark_rapids_tpu.net import metrics as net_metrics
     report["await_wakes"] = {
         k: v for k, v in net_metrics.counters().items()
         if k.startswith(("net_await_wake_", "net_submit_"))}
     # over the whole run (set-up and warm rounds too): which probe
-    # structure the joins' build sides ended in, every fallback of a fused
-    # stage by cause, and the partitions of stages without a join packed
-    # to a learned capacity (or that outgrew it); per request of the
-    # window: each operator span's count and milliseconds
+    # structure the joins' build sides ended in, the pairs the runs rung
+    # emitted, every fallback of a fused stage by cause, and the partitions
+    # of stages without a join packed to a learned capacity (or that
+    # outgrew it); per request of the window: each operator span's count
+    # and milliseconds
     from spark_rapids_tpu.obs import gauges
+    snap = gauges.snapshot()
+    report["host"]["gc_counters"] = {k: v for k, v in snap.items()
+                                     if k.startswith("host_gc_")}
     report["operators"] = {
-        "counters": {k: v for k, v in gauges.snapshot().items()
-                     if k.startswith(("join_build_path_", "fused_fallback_",
-                                      "fused_plain_",
+        "counters": {k: v for k, v in snap.items()
+                     if k.startswith(("join_build_path_", "join_run_pairs_",
+                                      "fused_fallback_", "fused_plain_",
                                       "fused_step_programs_"))},
         "spans": operator_spans(seen["spans"], seen["requests"])}
     if rehearsal:  # no device number from a rehearsal
