@@ -376,6 +376,51 @@ class _OpSeg:
         return ()
 
 
+class _Source:
+    """A partition's input batches, executed at the first look: an
+    absorbed join's build may ask the first batch's capacity (the runs
+    rung chooses its probe's form by it), and the stage then starts from
+    that same batch. A stage whose joins do not ask executes its input
+    after the builds, as it always did."""
+
+    def __init__(self, child: TpuExec, partition: int):
+        self._child, self._partition = child, partition
+        self._it = None
+        self._first = None
+
+    def _open(self) -> None:
+        if self._it is None:
+            self._it = self._child.execute(self._partition)
+            self._first = next(self._it, None)
+
+    def capacity(self) -> Optional[int]:
+        self._open()
+        return None if self._first is None else self._first.capacity
+
+    def take(self):
+        """(first batch or None, the batches after it)."""
+        self._open()
+        first, it = self._first, self._it
+        self._it = self._first = None
+        return first, it
+
+
+def _cap_after(source_cap, segs):
+    """``source_cap`` carried through ``segs``: the capacity the segment
+    after them is given, at the first look."""
+    if source_cap is None:
+        return None
+
+    def cap() -> Optional[int]:
+        c = source_cap()
+        for seg in segs:
+            if c is None:
+                break
+            c = seg.out_cap(c)
+        return c
+    return cap
+
+
 class TpuFusedStageExec(UnaryExec):
     """One jitted program per pipeline stage (see module docstring).
 
@@ -427,13 +472,17 @@ class TpuFusedStageExec(UnaryExec):
         return "\n".join(lines)
 
     # -- execution ---------------------------------------------------------
-    def _runtime_segments(self, partition: int):
+    def _runtime_segments(self, partition: int, source_cap=None):
         """Resolve segments for one partition; joins build their build side
-        here and may refuse (general path) -> None means fall back."""
+        here and may refuse (general path) -> None means fall back.
+        ``source_cap`` returns the capacity of the partition's first batch
+        (``_Source.capacity``); a join is given the capacity that reaches
+        it through the segments before it."""
         segs = []
         for op in self.segments:
             if len(op.children) == 2:  # absorbed hash join
-                seg = op.fused_probe(partition)
+                seg = op.fused_probe(partition,
+                                     _cap_after(source_cap, tuple(segs)))
                 if seg is None:
                     return None
                 segs.append(seg)
@@ -587,14 +636,15 @@ class TpuFusedStageExec(UnaryExec):
             seg.note_pairs(emitted)
 
     def do_execute(self, partition: int) -> Iterator[ColumnarBatch]:
-        segs = self._runtime_segments(partition)
+        source = _Source(self.child, partition)
+        segs = self._runtime_segments(partition, source.capacity)
         if segs is None:
             yield from self._fall_back(partition, "join-refused")
             return
         if self.agg is not None:
-            yield from self._execute_agg(partition, segs)
+            yield from self._execute_agg(partition, segs, source)
         else:
-            yield from self._execute_plain(partition, segs)
+            yield from self._execute_plain(partition, segs, source)
 
     def _windows(self, batches) -> Iterator[List[ColumnarBatch]]:
         """Runs of up to ``agg_window`` batches of ONE capacity: what one
@@ -611,7 +661,7 @@ class TpuFusedStageExec(UnaryExec):
         if window:
             yield window
 
-    def _execute_plain(self, partition: int, segs):
+    def _execute_plain(self, partition: int, segs, source):
         consts = tuple(seg.consts for seg in segs)
         runs = {}
         # no join: what was learned is the capacity the output is packed to
@@ -638,8 +688,7 @@ class TpuFusedStageExec(UnaryExec):
                 self._attribute(segs, counts)
                 yield out, short
 
-        it = self.child.execute(partition)
-        first = next(it, None)
+        first, it = source.take()
         if first is None:
             return
         self._size(partition, segs, first, consts)
@@ -678,12 +727,11 @@ class TpuFusedStageExec(UnaryExec):
         for out, _ in run_all(batches):
             yield out
 
-    def _execute_agg(self, partition: int, segs):
+    def _execute_agg(self, partition: int, segs, source=None):
         agg = self.agg
         agg._prepare()
         consts = tuple(seg.consts for seg in segs)
-        it = self.child.execute(partition)
-        first = next(it, None)
+        first, it = (source or _Source(self.child, partition)).take()
         if first is None:
             yield from self._fall_back(partition, "empty")
             return

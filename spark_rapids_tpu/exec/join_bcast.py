@@ -198,12 +198,13 @@ class BroadcastHashJoinExec(HashJoinExec):
             return None
         return build
 
-    def fused_probe(self, partition: int):
+    def fused_probe(self, partition: int, probe_cap=None):
         # build prep (dense table / bucketed table + the byte-bound syncs)
         # is partition-independent for a broadcast build: do it once
         seg = getattr(self, "_fused_seg", None)
         if seg is None:
-            seg = self._fused_seg = (super().fused_probe(partition), )
+            seg = self._fused_seg = (super().fused_probe(partition,
+                                                         probe_cap), )
         return seg[0]
 
     def node_description(self) -> str:

@@ -146,6 +146,14 @@ CATALOG: "List[Tuple[str, str, str]]" = [
     ("join_build_path_runs_total", "counter",
      "Build sides whose keys repeat, probed fused through their runs: "
      "the build sorted by key, one run a key (exec/join.py _prepare_runs)"),
+    ("join_run_probe_merge_total", "counter",
+     "Runs-rung build sides whose probes merge u32 key offsets with the "
+     "heads' in one sort (one ranged integer key, heads no more than a "
+     "probe batch; exec:join-build attr probe=merge)"),
+    ("join_run_probe_table_total", "counter",
+     "Runs-rung build sides whose probes gather from the presence and "
+     "heads' row tables (other keys, or more heads than a probe batch; "
+     "exec:join-build attr probe=table)"),
     ("join_run_pairs_total", "counter",
      "(probe row, build row) pairs the runs rung's fused probes emitted "
      "(read in their stages' one sync; exec:join-pairs spans)"),

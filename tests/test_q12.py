@@ -9,7 +9,7 @@ parameter sets, with ``lineitem`` in FULL batches of 8,192 rows whose last
 row qualifies (``In`` over a string column once lost it, PERF.md PR 38),
 an order planted with two qualifying lines and ``orders`` probing in
 several batches; the orders stage stays fused on the runs rung: one
-``exec:join-build`` span with path runs a request, no
+``exec:join-build`` span with path runs and probe merge a request, no
 ``exec:fused-fallback`` span, no ``join.candidate_stats`` sync (the
 general hash table's sync a probe batch). Over the wire the keys are
 shifted past ``join.denseKey.maxDomain`` as SF10's are, so the unique
@@ -80,14 +80,21 @@ def _assert_equals_reference(tables, raw, p):
 
 
 def _assert_runs(before, after, events, requests):
-    """One runs build a request, and nothing the fused probe avoids."""
+    """One runs build a request, its probes merging offsets (the key is
+    one ranged integer and the heads fit a probe batch), and nothing the
+    fused probe avoids."""
     assert after["join_build_path_runs_total"] - before[
         "join_build_path_runs_total"] == requests
     assert after["join_build_path_total"] - before[
         "join_build_path_total"] == requests
+    assert after["join_run_probe_merge_total"] - before[
+        "join_run_probe_merge_total"] == requests
+    assert after["join_run_probe_table_total"] == before[
+        "join_run_probe_table_total"]
     assert after["fused_fallback_total"] == before["fused_fallback_total"]
     builds = [e["args"] for e in events if e["name"] == "exec:join-build"]
-    assert [b["path"] for b in builds] == ["runs"] * requests
+    assert [(b["path"], b["probe"]) for b in builds] == [
+        ("runs", "merge")] * requests
     assert all(b["longest"] >= 2 for b in builds)
     names = [e["name"] for e in events]
     assert "exec:fused-fallback" not in names

@@ -91,6 +91,9 @@ def test_q3_through_the_planner_equals_the_reference(seed, params):
                                      "sorted": 0}
     assert after["join_build_path_total"] - before[
         "join_build_path_total"] == 2
+    for form in ("merge", "table"):  # no build reaches the runs rung
+        key = f"join_run_probe_{form}_total"
+        assert after[key] == before[key]
 
 
 def test_small_order_keys_take_the_dense_table_twice():

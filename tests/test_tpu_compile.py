@@ -456,15 +456,16 @@ def test_unique_table_at_the_cells_shape(one_chip):
 def test_fused_q12_stage_programs(one_chip):
     """The benchmark's Q12 (cell ``sf10_q12_dupjoin1``) as its fused
     `orders` stage runs it, at a capacity this suite can afford: the
-    sizing program and a deferred window of two batches (presence byte ->
-    compaction by sort -> one-gather probe of the runs' heads -> the runs
-    expanded into pairs -> the dense first pass over the dict-coded ship
-    mode), and the runs rung's two build programs. The order keys are
-    shifted past ``join.denseKey.maxDomain`` as SF10's are. Every sort in
-    them has two operands (``K.lsd_order``'s signature), and the build's
-    runs need no gather-loop or search. The build side's own stage (the
-    filtered `lineitem`, no join) compiles packed to a learned capacity
-    (PR 40)."""
+    sizing program and a deferred window of two batches (the probe's u32
+    offsets merged with the runs' heads by two sorts -> the runs expanded
+    into pairs -> the dense first pass over the dict-coded ship mode), the
+    ranged runs build's two programs, and the table form's two (the form
+    a build takes where its heads outnumber a probe batch). The order keys
+    are shifted past ``join.denseKey.maxDomain`` as SF10's are. Every sort
+    in them has two operands (``K.lsd_order``'s signature), and the
+    build's runs need no gather-loop or search. The build side's own
+    stage (the filtered `lineitem`, no join) compiles packed to a learned
+    capacity."""
     import sys
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
@@ -495,9 +496,10 @@ def test_fused_q12_stage_programs(one_chip):
     stage = plan.children[0]
     assert isinstance(stage, F.TpuFusedStageExec) and stage.agg is not None
     batch = next(iter(stage.child.execute(0)))
-    segs = stage._runtime_segments(0)
+    segs = stage._runtime_segments(0, lambda: batch.capacity)
     consts = tuple(seg.consts for seg in segs)
     assert [getattr(s, "kind", None) for s in segs] == [None, "runs"]
+    assert segs[1].form == "merge"
     stage._size(0, segs, batch, consts)
     assert stage._learned == {0: 1024}
     fns = stage._chain_fns(segs, batch.capacity)
@@ -517,6 +519,17 @@ def test_fused_q12_stage_programs(one_chip):
     assert set(_sort_operands(packed.as_text())) == {2}
     assert packed.compile() is not None
     build = segs[1].build
+    first = segs[1].tbl[-1]
+    ranged = _lower(lambda b, f: K.join_runs_ranged.__wrapped__(b, 0, f),
+                    one_chip, build, first)
+    offsets = jax.eval_shape(lambda b, f: K.join_runs_ranged.__wrapped__(
+        b, 0, f), build, first)[0][0]
+    heads = _lower(lambda o: K.join_run_heads.__wrapped__(o, 1024),
+                   one_chip, jnp.zeros(offsets.shape, offsets.dtype))
+    for lowered in (ranged, heads):
+        assert set(_sort_operands(lowered.as_text())) == {2}
+        _no_loop_no_gather(lowered)
+        assert lowered.compile() is not None
     runs = _lower(lambda b: K.join_runs.__wrapped__(b, (0,)), one_chip,
                   build)
     assert set(_sort_operands(runs.as_text())) == {2}

@@ -30,7 +30,7 @@ The last line of standard output is one JSON object: ``clock``, ``tiles``,
 ``sync_sites``, ``host``, ``await_wakes`` (the front-end's submit counters
 and, on a program that has them, its counters of what ended each wait for a
 result, over the whole run), ``operators`` (the joins' build paths, the
-pairs of the runs rung, and the fused stages' fallbacks and learned output
+runs rung's probe forms and pairs, and the fused stages' fallbacks and learned output
 capacities by their counters, and the exec:* spans per request) and
 the run's ordinary ``result``. Needs the chip, as benchmark/run.py
 does (``--rehearse-sf`` runs every step on the CPU and prints no device
@@ -340,6 +340,7 @@ def main() -> int:
     report["operators"] = {
         "counters": {k: v for k, v in snap.items()
                      if k.startswith(("join_build_path_", "join_run_pairs_",
+                                      "join_run_probe_",
                                       "fused_fallback_", "fused_plain_",
                                       "fused_step_programs_"))},
         "spans": operator_spans(seen["spans"], seen["requests"])}
