@@ -987,6 +987,13 @@ CAPABILITIES = {
         "stage through the build's runs, one a key (exec/join.py "
         "_prepare_runs): no host sync a probe batch, and the stage's output "
         "capacity counts pairs (docs/fusion.md)",
+    "join.fusedSemi":
+        "a semi or anti join with no residual condition, on one integer key "
+        "whose build range spans at most 2^28, is a filter inside its fused "
+        "stage: the build marks its keys in a presence map of a byte a "
+        "possible key in one sync, and a probe batch gathers a byte a row "
+        "into its live mask, with no host sync and no pairs (exec/join.py "
+        "_fused_exists; docs/fusion.md)",
 }
 
 
@@ -1007,7 +1014,10 @@ REQUIRES = conf(
         "sort.boundedTopN (a sort with a limit is a top-N by selection, "
         "whose compile cost does not grow with the batch); "
         "join.fusedDuplicateKeys (an inner join whose build keys repeat "
-        "stays in its fused stage, with no host sync a probe batch).",
+        "stays in its fused stage, with no host sync a probe batch); "
+        "join.fusedSemi (a semi or anti join on one ranged integer key is "
+        "a filter inside its fused stage, built in one sync into a "
+        "presence map).",
     check=_check_requires)
 
 

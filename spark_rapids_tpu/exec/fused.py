@@ -18,7 +18,9 @@ Plan-time pass (``fuse_exec``, called from plan/overrides.py behind
   normally at stage setup; only the per-batch probe is absorbed, for the
   dense, unique-table and runs (keys that repeat) paths whose probes are
   pure — the general hash table needs a per-batch host sync and bails to
-  the unfused fallback, see HashJoinExec.fused_probe);
+  the unfused fallback, see HashJoinExec.fused_probe), and semi and anti
+  joins on one integer key, whose probe is a filter of the batch through
+  the build's presence map (the exists form);
 - a terminal partial/complete hash aggregate, absorbed in STREAMING form:
   per window of batches one dispatch runs chain -> first_pass per batch
   -> concat(carry, firsts) -> merge_pass -> truncate-to-carry-capacity,
@@ -35,9 +37,10 @@ Correctness safety valves — every data-dependent assumption is checked and
 degrades to the ORIGINAL operator chain (constituents keep their children
 links, so the unfused plan is always re-executable):
 
-- join build refused by every rung (dense, unique, runs) / oversized ->
-  fallback before any output is produced; a runs probe whose pairs outgrow
-  even its batch's own capacity -> the partition re-runs unfused;
+- join build refused by every rung (dense, unique, runs, exists) /
+  oversized -> fallback before any output is produced; a runs probe whose
+  pairs outgrow even its batch's own capacity -> the partition re-runs
+  unfused;
 - the streaming aggregate's carry overflows its capacity (more groups, or
   more group-key bytes, than the first batch's bucket) -> overflow flags
   are computed ON DEVICE inside the fused body and read back once at
@@ -529,10 +532,17 @@ class TpuFusedStageExec(UnaryExec):
             cap = seg.out_cap(cap)
         return fns
 
-    def _attribute(self, segs, counts) -> None:
+    def _attribute(self, segs, counts, rows_in) -> None:
+        """Each segment's live rows out of one batch (``rows_in`` came in)
+        to its operator's metrics, still as traced scalars; a runs probe
+        keeps its pairs, a semi or anti probe notes what it tested."""
+        tested = rows_in
         for seg, n in zip(segs, counts):
             if getattr(seg, "pairs", None) is not None:
                 seg.pairs.append(n)
+            if hasattr(seg, "note_rows"):
+                seg.note_rows(tested, n)
+            tested = n
             op = seg.op
             op.metrics["numOutputBatches"].add(1)
             op._pending_rows.append(n)
@@ -635,6 +645,35 @@ class TpuFusedStageExec(UnaryExec):
         for seg, emitted in zip(runs, read):
             seg.note_pairs(emitted)
 
+    def execute_live(self, partition: int):
+        """The partition's batches as the chain leaves them, each with the
+        mask of the rows its filters kept (None: the batch is front-packed),
+        at the source batches' capacity: no compaction, no learned capacity
+        and no sync a batch. For a consumer that reads each batch through
+        its mask (a semi join's exists build, ``_exists_batches`` in
+        exec/join.py). None where the stage holds a join or an aggregate,
+        whose outputs only ``execute`` gives."""
+        if self.agg is not None or any(len(op.children) == 2
+                                       for op in self.segments):
+            return None
+        return self._live_batches(partition)
+
+    def _live_batches(self, partition: int):
+        segs = [_OpSeg(op) for op in self.segments]
+        consts = tuple(seg.consts for seg in segs)
+        runs = {}
+        for batch in self.child.execute(partition):
+            run = runs.get(batch.capacity)
+            if run is None:
+                body = _make_body(self._chain_fns(segs, batch.capacity))
+                run = runs[batch.capacity] = shared_jit(
+                    self._stage_key(segs, batch.capacity) + ("masked",),
+                    lambda body=body: lambda b, c: body(b, c)[:3])
+            out, live, counts = run(batch, consts)
+            self.metrics["numFusedBatches"].add(1)
+            self._attribute(segs, counts, batch.num_rows)
+            yield out, live
+
     def do_execute(self, partition: int) -> Iterator[ColumnarBatch]:
         source = _Source(self.child, partition)
         segs = self._runtime_segments(partition, source.capacity)
@@ -685,7 +724,7 @@ class TpuFusedStageExec(UnaryExec):
             for batch in batches:
                 out, counts, short = program(batch.capacity)(batch, consts)
                 self.metrics["numFusedBatches"].add(1)
-                self._attribute(segs, counts)
+                self._attribute(segs, counts, batch.num_rows)
                 yield out, short
 
         first, it = source.take()
@@ -777,8 +816,8 @@ class TpuFusedStageExec(UnaryExec):
             shorts.append(short)
             self.metrics["numFusedBatches"].add(n)
             agg.metrics["numAggBatches"].add(n)
-            for counts in counts_all:
-                self._attribute(segs, counts)
+            for batch, counts in zip(window, counts_all):
+                self._attribute(segs, counts, batch.num_rows)
 
         for window in self._windows(itertools.chain([first], it)):
             dispatch(window)
@@ -822,7 +861,7 @@ class TpuFusedStageExec(UnaryExec):
             carry, counts, *short = run(first, consts)
         self.metrics["numFusedBatches"].add(1)
         agg.metrics["numAggBatches"].add(1)
-        self._attribute(segs, counts)
+        self._attribute(segs, counts, first.num_rows)
         # steps: windows of up to agg_window batches of ONE capacity, one
         # dispatch each — chain+first_pass per batch then a single
         # (carry+firsts) concat/merge (the classic operator pays a dispatch
@@ -909,8 +948,8 @@ class _StepRunner:
         self.shorts.extend(short)
         stage.metrics["numFusedBatches"].add(n)
         agg.metrics["numAggBatches"].add(n)
-        for counts in counts_all:
-            stage._attribute(self.segs, counts)
+        for batch, counts in zip(window, counts_all):
+            stage._attribute(self.segs, counts, batch.num_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -932,8 +971,11 @@ def _agg_absorbable(op) -> bool:
 
 
 def _join_absorbable(op) -> bool:
+    """An inner hash join, or a semi or anti one that the plan shows can
+    take the exists form (``HashJoinExec.fuses_as_exists``)."""
     from spark_rapids_tpu.exec.join import HashJoinExec
-    return isinstance(op, HashJoinExec) and op.join_type == "inner"
+    return isinstance(op, HashJoinExec) and (
+        op.join_type == "inner" or op.fuses_as_exists())
 
 
 def fuse_exec(root: TpuExec, min_ops: int = 2,

@@ -49,29 +49,60 @@ JOIN_TYPES = ("inner", "left", "right", "full", "left_semi", "left_anti")
 
 # -- the build side's span and counters --------------------------------------
 
-BUILD_PATHS = ("dense", "unique", "runs", "ht", "sorted")
+BUILD_PATHS = ("dense", "unique", "runs", "exists", "ht", "sorted")
 RUN_PROBES = ("merge", "table")  # the runs rung's two probe forms
 _build_tls = threading.local()
 _build_lock = threading.Lock()
 _build_paths = dict.fromkeys(BUILD_PATHS, 0)
 _run_probes = dict.fromkeys(RUN_PROBES, 0)
 _run_pairs = [0]  # pairs the runs rung's fused probes emitted
+# probe rows the fused semi and anti probes tested, and the rows they kept;
+# what a stage attributed is kept as its device scalars and read at a
+# scrape, or once 4,096 batches wait (long done by then)
+_semi_rows = [0, 0]
+_semi_pending: list = []
+_SEMI_FOLD = 4096
 
 
 def counters() -> dict:
     """For obs/gauges.snapshot(): build sides by the probe structure they
-    ended in, the runs rung's by the form its probes take, and the pairs
-    the runs rung emitted."""
+    ended in, the runs rung's by the form its probes take, the pairs the
+    runs rung emitted, and the rows the fused semi and anti probes tested
+    and kept."""
+    _fold_semi(0)
     with _build_lock:
         out = {f"join_build_path_{p}_total": n
                for p, n in _build_paths.items()}
         probes = {f"join_run_probe_{f}_total": n
                   for f, n in _run_probes.items()}
         pairs = _run_pairs[0]
+        tested, kept = _semi_rows
     out["join_build_path_total"] = sum(out.values())
     out.update(probes)
     out["join_run_pairs_total"] = pairs
+    out["join_semi_probe_rows_total"] = tested
+    out["join_semi_probe_hits_total"] = kept
     return out
+
+
+def note_semi_rows(tested, kept) -> None:
+    """A fused semi or anti probe's batch: the rows it tested and kept, as
+    the stage's traced row counts (``TpuFusedStageExec._attribute``)."""
+    with _build_lock:
+        _semi_pending.append((tested, kept))
+    _fold_semi(_SEMI_FOLD)
+
+
+def _fold_semi(at_least: int) -> None:
+    with _build_lock:
+        if not _semi_pending or len(_semi_pending) < at_least:
+            return
+        pending = list(_semi_pending)
+        _semi_pending.clear()
+    got = host_get(pending, "metrics.rows")
+    with _build_lock:
+        _semi_rows[0] += sum(int(t) for t, _ in got)
+        _semi_rows[1] += sum(int(k) for _, k in got)
 
 
 @contextlib.contextmanager
@@ -79,9 +110,9 @@ def build_span(join):
     """One ``exec:join-build`` span around ``join``'s build side: executing
     it and constructing the probe structure, the construction's host syncs
     as its children. Yields the dict the span's attrs are taken from
-    (``path``, ``rows``, ``capacity``; on the runs rung ``probe``, the
-    form its probes take, counted in ``join_run_probe_*_total`` too). The
-    same join's build inside its
+    (``join``, the join type; ``path``, ``rows``, ``capacity``; on the
+    runs rung ``probe``, the form its probes take, counted in
+    ``join_run_probe_*_total`` too). The same join's build inside its
     own build (a broadcast build re-prepared for a fused probe) fills the
     outer one's, so a build side is one span and one count of
     ``join_build_path_total``, under the path it is probed by; another
@@ -91,7 +122,7 @@ def build_span(join):
     if outer is not None and outer[0] is join:
         yield outer[1]
         return
-    attrs = {}
+    attrs = {"join": join.join_type}
     _build_tls.open = (join, attrs)
     try:
         with _span.task_span("exec:join-build") as sp:
@@ -762,9 +793,12 @@ class HashJoinExec(BinaryExec):
     def fused_probe(self, partition: int, probe_cap=None):
         """Build this join's build side now and return a stage segment whose
         per-batch probe is PURE and traceable, or None when the runtime
-        path can't be traced (non-inner joins need build/probe matched-flag
-        bookkeeping across batches; the general sorted-hash path sizes its
-        output from a per-batch host sync of the candidate total).
+        path can't be traced (the general sorted-hash path sizes its output
+        from a per-batch host sync of the candidate total). An inner join
+        emits pairs; a semi or anti join (``fuses_as_exists``) is a filter
+        of the probe batch (``_fused_exists``). Left, right and full outer
+        joins are not absorbed: their unmatched rows are known only once
+        every probe batch has run.
 
         The returned segment's fn takes ``(probe_batch, (build, tbl))`` —
         the build arrays ride as jit ARGUMENTS, so the traced program (and
@@ -773,6 +807,8 @@ class HashJoinExec(BinaryExec):
         gives it, returns the capacity of the first probe batch (None for
         none); the runs rung chooses its probe's form by it.
         """
+        if self.join_type in ("left_semi", "left_anti"):
+            return self._fused_exists(partition)
         if self.join_type != "inner":
             return None
         self._prepare()
@@ -819,6 +855,85 @@ class HashJoinExec(BinaryExec):
                                battrs.get("probe"))
 
     PRESENCE_MAX_DOMAIN = 1 << 28  # one byte a possible key
+
+    # -- the semi and anti joins' form: exists -----------------------------
+    # A semi join asks of a probe row only whether its key has a build row,
+    # so over one integer key whose range spans at most PRESENCE_MAX_DOMAIN
+    # the presence map (a byte a possible key) is the whole probe structure:
+    # no row table, no runs, no concatenated build.
+
+    def fuses_as_exists(self) -> bool:
+        """What the plan shows of the exists form: a semi or anti join with
+        no residual condition on one integer key a side. The build's range
+        is seen only when it runs (``_fused_exists``)."""
+        if (self.join_type not in ("left_semi", "left_anti")
+                or self.condition is not None or len(self.left_keys) != 1):
+            return False
+        try:
+            self._prepare()
+        except AssertionError:  # a key that is not a column: not this form
+            return False
+        ints = (T.INT, T.LONG)
+        return (self.left.output_schema[self._lkeys[0]].dtype in ints
+                and self.right.output_schema[self._rkeys[0]].dtype in ints)
+
+    def _exists_batches(self, partition: int):
+        """The build side's batches, each with the mask of its live rows
+        (None: the batch's own). A fused stage without a join or an
+        aggregate hands its batches unpacked, with the mask its filters
+        left (``TpuFusedStageExec.execute_live``): no compaction and no
+        ``shrink_to_live`` sync a batch."""
+        from spark_rapids_tpu.exec.fused import TpuFusedStageExec
+        if isinstance(self.right, TpuFusedStageExec):
+            live = self.right.execute_live(partition)
+            if live is not None:
+                return live
+        return ((b, None) for b in self.right.execute(partition))
+
+    def _fused_exists(self, partition: int):
+        """The exists build and its probe segment, or None where the unfused
+        operator runs: the build has no batch (its empty-build semantics),
+        its key is not a plain integer column, or its range spans more than
+        ``PRESENCE_MAX_DOMAIN``. Each build batch's least and greatest live
+        key and live rows are reduced on the device; ONE sync reads them
+        all (``join.exists_stats``); then each batch marks its keys in the
+        map, a program a batch, with no sync."""
+        if not self.fuses_as_exists():
+            return None
+        key = self._rkeys[0]
+        with build_span(self) as battrs:
+            with self.timer("buildTimeNs"):
+                parts, stats = [], []
+                for batch, live in self._exists_batches(partition):
+                    c = batch.columns[key]
+                    if (c.offsets is not None or c.is_dict
+                            or c.is_wide_decimal):
+                        return None
+                    parts.append((batch, live))
+                    stats.append(_exists_stats(batch, key, live))
+                if not parts:
+                    return None
+                got = host_get(stats, "join.exists_stats")
+                rows = sum(int(s[2]) for s in got)
+                kmin = min(int(s[0]) for s in got) if rows else 0
+                kmax = max(int(s[1]) for s in got) if rows else 0
+                battrs.update(rows=rows,
+                              capacity=sum(b.capacity for b, _ in parts))
+                span = kmax - kmin + 1
+                if span > self.PRESENCE_MAX_DOMAIN:
+                    return None
+                first = jnp.int64(kmin)
+                present = jnp.zeros(bucket_capacity(span, 16), jnp.bool_)
+                for batch, live in parts:
+                    present = _exists_mark(present, batch, key, live, first)
+            battrs["path"] = "exists"
+        from spark_rapids_tpu.plan import autotune as AT
+        ls = self.left.output_schema
+        AT.record_decision(
+            self, f"join:{self.join_type}", "exists", "default",
+            AT.shape_class(present.shape[0], 1,
+                           AT.family_of([str(ls[self._lkeys[0]].dtype)])))
+        return _FusedExistsProbe(self, present, first)
 
     def _key_span(self, told: dict) -> Optional[int]:
         """How many keys the build's one integer key spans, where
@@ -1161,6 +1276,54 @@ class _FusedJoinProbe:
         return run
 
 
+class _FusedExistsProbe:
+    """Stage segment for an absorbed semi or anti join in the exists form
+    (``HashJoinExec._fused_exists``): a filter. A probe row's key is its
+    offset from the build's least key; one gather of a byte a row reads the
+    presence map, and the batch's live mask is ANDed with the answer (semi)
+    or its negation (anti). No row is moved and no pair made, so it syncs
+    on no batch, and a stage that ends in it learns the capacity its output
+    is packed to as a stage that ends in a filter does (``fused._size``).
+
+    A probe row whose key is null has no match: semi drops it, anti keeps
+    it, as Spark's NOT EXISTS does (a null-aware NOT IN is another join
+    type and not this path)."""
+
+    live = "masks"  # the chain's protocol: it hands a mask on (fused._OpSeg)
+
+    def __init__(self, join: HashJoinExec, present, first):
+        self.op = join
+        self.anti = join.join_type == "left_anti"
+        self.tbl = (present, first)
+
+    @property
+    def consts(self):
+        return self.tbl
+
+    def out_cap(self, in_cap: int) -> int:
+        return in_cap
+
+    def key_part(self, in_cap: int) -> tuple:
+        return ("exists", self.anti, self.op._lkeys[0],
+                self.tbl[0].shape[0])
+
+    def note_rows(self, tested, kept) -> None:
+        note_semi_rows(tested, kept)
+
+    def probe_fn(self, in_cap: int):
+        key, anti = self.op._lkeys[0], self.anti
+
+        def run(probe, live, consts):
+            present, first = consts
+            was = probe.active_mask() if live is None else live
+            c = probe.columns[key]
+            off = c.data.astype(jnp.int64) - first
+            inb = c.validity & (off >= 0) & (off < present.shape[0])
+            found = inb & present[jnp.where(inb, off, 0).astype(jnp.int32)]
+            return probe, was & (~found if anti else found), None
+        return run
+
+
 def _max_row_bytes(c: DeviceColumn) -> int:
     """Longest row of a string column, in bytes (one host sync)."""
     return int(host_get(jnp.max(c.offsets[1:] - c.offsets[:-1]),
@@ -1182,6 +1345,26 @@ def _presence_table(build: ColumnarBatch, key: int, first, size: int):
     at = jnp.where(live, c.data.astype(jnp.int64) - first, size)
     return jnp.zeros(size, jnp.bool_).at[at.astype(jnp.int32)].set(
         True, mode="drop")
+
+
+@partial(jax.jit, static_argnums=(1,))
+def _exists_stats(batch: ColumnarBatch, key: int, live):
+    """(least, greatest, count) of a build batch's live, valid keys."""
+    c = batch.columns[key]
+    ok = c.validity & (batch.active_mask() if live is None else live)
+    k = c.data.astype(jnp.int64)
+    return jnp.stack([jnp.min(jnp.where(ok, k, jnp.iinfo(jnp.int64).max)),
+                      jnp.max(jnp.where(ok, k, jnp.iinfo(jnp.int64).min)),
+                      jnp.sum(ok.astype(jnp.int64))])
+
+
+@partial(jax.jit, static_argnums=(2,), donate_argnums=(0,))
+def _exists_mark(present, batch: ColumnarBatch, key: int, live, first):
+    """``present`` with a build batch's live, valid keys marked."""
+    c = batch.columns[key]
+    ok = c.validity & (batch.active_mask() if live is None else live)
+    at = jnp.where(ok, c.data.astype(jnp.int64) - first, present.shape[0])
+    return present.at[at.astype(jnp.int32)].set(True, mode="drop")
 
 
 @partial(jax.jit, static_argnums=(1,))

@@ -198,6 +198,11 @@ class BroadcastHashJoinExec(HashJoinExec):
             return None
         return build
 
+    def _exists_batches(self, partition):
+        # the exists form reads the same whole build, never the slice
+        build = self._fused_build_side(partition)
+        return [] if build is None else [(build, None)]
+
     def fused_probe(self, partition: int, probe_cap=None):
         # build prep (dense table / bucketed table + the byte-bound syncs)
         # is partition-independent for a broadcast build: do it once

@@ -136,7 +136,7 @@ CATALOG: "List[Tuple[str, str, str]]" = [
      "ran again at the batches' own capacity (not a fallback)"),
     ("join_build_path_total", "counter",
      "Hash-join build sides constructed (exec/join.py build_span; one "
-     "exec:join-build span each); the five counters below split it by the "
+     "exec:join-build span each); the six counters below split it by the "
      "probe structure the build ended in"),
     ("join_build_path_dense_total", "counter",
      "Build sides probed through the direct-address table (integer key "
@@ -157,6 +157,17 @@ CATALOG: "List[Tuple[str, str, str]]" = [
     ("join_run_pairs_total", "counter",
      "(probe row, build row) pairs the runs rung's fused probes emitted "
      "(read in their stages' one sync; exec:join-pairs spans)"),
+    ("join_build_path_exists_total", "counter",
+     "Build sides of semi and anti joins probed fused through a presence "
+     "map, a byte a possible key: one integer key spanning at most 2^28 "
+     "(exec/join.py _fused_exists)"),
+    ("join_semi_probe_rows_total", "counter",
+     "Probe rows the fused semi and anti probes tested (the stage's row "
+     "counts, read at a scrape, or in one sync once 4,096 batches "
+     "wait)"),
+    ("join_semi_probe_hits_total", "counter",
+     "Probe rows the fused semi and anti probes kept (semi: a match; "
+     "anti: none)"),
     ("join_build_path_ht_total", "counter",
      "Build sides probed through the open-addressing hash table"),
     ("join_build_path_sorted_total", "counter",

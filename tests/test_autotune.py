@@ -212,6 +212,12 @@ def test_rejects_degenerate_samples(at_dir):
 # -- end-to-end: feedback populates the store, dispatch is visible ------
 
 
+# the semi join's dispatch that autotune ranks (ht <-> sorted) is the
+# unfused operator's: fused, a semi join on one integer key takes the
+# exists form (exec/join.py), which has no other path to rank
+UNFUSED = {"spark.rapids.tpu.sql.fusion.enabled": False}
+
+
 def _join_agg_query(conf):
     t1 = pa.table({"k": pa.array([i % 200 for i in range(2000)], pa.int64()),
                    "v": pa.array([i % 7 for i in range(2000)], pa.int64())})
@@ -224,7 +230,8 @@ def _join_agg_query(conf):
 
 def test_feedback_populates_store_and_explain(at_dir):
     conf = C.RapidsConf({"spark.rapids.tpu.autotune.dir": str(at_dir),
-                         "spark.rapids.tpu.profile.enabled": True})
+                         "spark.rapids.tpu.profile.enabled": True,
+                         **UNFUSED})
     q = _join_agg_query(conf)
     q.to_arrow()
     p = AT.store_path()
@@ -242,7 +249,8 @@ def test_feedback_populates_store_and_explain(at_dir):
 
 def test_warm_dispatch_measured_and_differential(at_dir):
     conf_on = C.RapidsConf({"spark.rapids.tpu.autotune.dir": str(at_dir),
-                            "spark.rapids.tpu.profile.enabled": True})
+                            "spark.rapids.tpu.profile.enabled": True,
+                            **UNFUSED})
     base = _join_agg_query(conf_on).to_arrow()
     # second run: the semi-join + agg-window candidates explore/rank from
     # the persisted measurements
@@ -252,7 +260,7 @@ def test_warm_dispatch_measured_and_differential(at_dir):
     assert G.snapshot()["autotune_hit_total"] > 0
     conf_off = C.RapidsConf({
         "spark.rapids.tpu.autotune.enabled": False,
-        "spark.rapids.tpu.profile.enabled": True})
+        "spark.rapids.tpu.profile.enabled": True, **UNFUSED})
     off = _join_agg_query(conf_off).to_arrow()
     # measurements re-rank among order-equivalent paths only: results are
     # bit-identical to the static dispatch, in the same order

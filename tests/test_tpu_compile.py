@@ -542,3 +542,59 @@ def test_fused_q12_stage_programs(one_chip):
         p, 4, K.join_rows_lg_b(build.capacity)), one_chip, placed)
     _no_loop_no_gather(table)
     assert table.compile() is not None
+
+
+def test_fused_q4_stage_programs(one_chip):
+    """The benchmark's Q4 (cell ``sf10_q4_semi1``) as its two fused stages
+    run it: the `lineitem` build stage's masked program (its batches with
+    their masks, no compaction), the exists build's range reduction and
+    its mark of a presence map at the cell's own shapes (a 2^20 batch into
+    SF10's 2^26 bytes), and the `orders` stage's seed, whose semi probe is
+    one byte gather into the live mask before the dense first pass over
+    the dict-coded priority. None of the exists form's programs sorts."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import datagen
+    import harness
+    from spark_rapids_tpu.columnar.batch import dictionary_encode_table
+    from spark_rapids_tpu.exec import fused as F
+    from spark_rapids_tpu.exec import join as J
+    from spark_rapids_tpu.plan import from_arrow
+    q4 = harness.load_by_path("queries", "q4")
+    raw = datagen.make(list(q4.TABLES), 0.001, 7)
+    for t, c in (("lineitem", "l_orderkey"), ("orders", "o_orderkey")):
+        raw[t][c] = raw[t][c] + (1 << 26)
+    d = {k: from_arrow(dictionary_encode_table(datagen.arrow(v)),
+                       batch_rows=8192, partitions=1)
+         for k, v in raw.items()}
+    stage = q4.build(d).physical_plan().children[0]
+    assert isinstance(stage, F.TpuFusedStageExec) and stage.agg is not None
+    batch = next(iter(stage.child.execute(0)))
+    segs = stage._runtime_segments(0, lambda: batch.capacity)
+    assert isinstance(segs[-1], J._FusedExistsProbe) and not segs[-1].anti
+    consts = tuple(seg.consts for seg in segs)
+    seed = _lower(F._make_seed(stage._chain_fns(segs, batch.capacity),
+                               stage.agg), one_chip, batch, consts)
+    assert _sort_operands(seed.as_text()) == []
+    assert seed.compile() is not None
+    bstage = segs[-1].op.children[1]
+    assert isinstance(bstage, F.TpuFusedStageExec) and bstage.agg is None
+    bsegs = [F._OpSeg(op) for op in bstage.segments]
+    first = next(iter(bstage.child.execute(0)))
+    body = F._make_body(bstage._chain_fns(bsegs, first.capacity))
+    masked = _lower(lambda b, c: body(b, c)[:3], one_chip, first, ())
+    out = jax.eval_shape(lambda b: body(b, ())[0], first)
+    big = jax.tree.map(lambda a: jnp.zeros((1 << 20,) + a.shape[1:],
+                                           a.dtype), out)
+    live = jnp.zeros(1 << 20, jnp.bool_)
+    stats = _lower(lambda b, m: J._exists_stats.__wrapped__(b, 0, m),
+                   one_chip, big, live)
+    mark = _lower(lambda p, b, m, f: J._exists_mark.__wrapped__(
+        p, b, 0, m, f), one_chip, jnp.zeros(1 << 26, jnp.bool_), big, live,
+        jnp.int64(0))
+    for lowered in (masked, stats, mark):
+        assert _sort_operands(lowered.as_text()) == []
+        assert lowered.compile() is not None
