@@ -56,6 +56,8 @@ _build_lock = threading.Lock()
 _build_paths = dict.fromkeys(BUILD_PATHS, 0)
 _run_probes = dict.fromkeys(RUN_PROBES, 0)
 _run_pairs = [0]  # pairs the runs rung's fused probes emitted
+_exists_marks = [0]  # exists build batches marked by their run heads
+_exists_mark_indices = [0]  # the indices those marks scattered
 # probe rows the fused semi and anti probes tested, and the rows they kept;
 # what a stage attributed is kept as its device scalars and read at a
 # scrape, or once 4,096 batches wait (long done by then)
@@ -67,14 +69,17 @@ _SEMI_FOLD = 4096
 def counters() -> dict:
     """For obs/gauges.snapshot(): build sides by the probe structure they
     ended in, the runs rung's by the form its probes take, the pairs the
-    runs rung emitted, and the rows the fused semi and anti probes tested
-    and kept."""
+    runs rung emitted, the exists builds' batches marked and the indices
+    those scattered, and the rows the fused semi and anti probes tested and
+    kept."""
     _fold_semi(0)
     with _build_lock:
         out = {f"join_build_path_{p}_total": n
                for p, n in _build_paths.items()}
         probes = {f"join_run_probe_{f}_total": n
                   for f, n in _run_probes.items()}
+        probes["join_exists_mark_compact_total"] = _exists_marks[0]
+        probes["join_exists_mark_indices_total"] = _exists_mark_indices[0]
         pairs = _run_pairs[0]
         tested, kept = _semi_rows
     out["join_build_path_total"] = sum(out.values())
@@ -91,6 +96,16 @@ def note_semi_rows(tested, kept) -> None:
     with _build_lock:
         _semi_pending.append((tested, kept))
     _fold_semi(_SEMI_FOLD)
+
+
+def note_exists_marks(caps: Sequence[int]) -> dict:
+    """An exists build's marks, the indices each batch scattered: counted,
+    and returned as its span's attrs (``mark_cap``: the most of one
+    batch)."""
+    with _build_lock:
+        _exists_marks[0] += len(caps)
+        _exists_mark_indices[0] += sum(caps)
+    return {"mark_cap": max(caps, default=0)}
 
 
 def _fold_semi(at_least: int) -> None:
@@ -895,9 +910,10 @@ class HashJoinExec(BinaryExec):
         operator runs: the build has no batch (its empty-build semantics),
         its key is not a plain integer column, or its range spans more than
         ``PRESENCE_MAX_DOMAIN``. Each build batch's least and greatest live
-        key and live rows are reduced on the device; ONE sync reads them
-        all (``join.exists_stats``); then each batch marks its keys in the
-        map, a program a batch, with no sync."""
+        key, live rows and run heads are reduced on the device, beside the
+        mask of its heads; ONE sync reads the numbers (``join.exists_stats``);
+        then each batch marks its heads in the map, a program a batch, with
+        no sync, packed into ``_exists_mark_caps``'s C."""
         if not self.fuses_as_exists():
             return None
         key = self._rkeys[0]
@@ -909,8 +925,9 @@ class HashJoinExec(BinaryExec):
                     if (c.offsets is not None or c.is_dict
                             or c.is_wide_decimal):
                         return None
-                    parts.append((batch, live))
-                    stats.append(_exists_stats(batch, key, live))
+                    nums, heads = _exists_stats(batch, key, live)
+                    parts.append((batch, heads))
+                    stats.append(nums)
                 if not parts:
                     return None
                 got = host_get(stats, "join.exists_stats")
@@ -924,8 +941,12 @@ class HashJoinExec(BinaryExec):
                     return None
                 first = jnp.int64(kmin)
                 present = jnp.zeros(bucket_capacity(span, 16), jnp.bool_)
-                for batch, live in parts:
-                    present = _exists_mark(present, batch, key, live, first)
+                caps = self._exists_mark_caps(parts, got)
+                for batch, heads in parts:
+                    present = _exists_mark(present, batch, key, heads, first,
+                                           caps[batch.capacity])
+                battrs.update(note_exists_marks(
+                    [caps[b.capacity] for b, _ in parts]))
             battrs["path"] = "exists"
         from spark_rapids_tpu.plan import autotune as AT
         ls = self.left.output_schema
@@ -934,6 +955,20 @@ class HashJoinExec(BinaryExec):
             AT.shape_class(present.shape[0], 1,
                            AT.family_of([str(ls[self._lkeys[0]].dtype)])))
         return _FusedExistsProbe(self, present, first)
+
+    def _exists_mark_caps(self, parts, got) -> dict:
+        """{batch capacity: C}, the bucket of the most run heads a batch of
+        that capacity holds, at most the capacity: one mark program a
+        capacity, the same for every request over the same data. A build
+        whose keys repeat next to each other (a table stored in key order)
+        scatters a fraction of its rows; one that is shuffled or distinct
+        has about a head a live row and scatters its capacity."""
+        heads = {}
+        for (batch, _), s in zip(parts, got):
+            heads[batch.capacity] = max(heads.get(batch.capacity, 0),
+                                        int(s[3]))
+        return {capacity: min(bucket_capacity(n), capacity)
+                for capacity, n in heads.items()}
 
     def _key_span(self, told: dict) -> Optional[int]:
         """How many keys the build's one integer key spans, where
@@ -1347,23 +1382,45 @@ def _presence_table(build: ColumnarBatch, key: int, first, size: int):
         True, mode="drop")
 
 
+def _run_heads(k, ok):
+    """The first live row of each run of equal adjacent keys. A key with a
+    live row has a head in any row order; one a run where its rows lie
+    together, as a table stored in key order keeps them. ``n - before``
+    counts a run's live rows up to the row (``n`` never falls, so the
+    running max of its value before each run start is that run's)."""
+    start = jnp.concatenate([jnp.ones(1, jnp.bool_), k[1:] != k[:-1]])
+    n = jnp.cumsum(ok, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(start, n - ok.astype(jnp.int32), 0))
+    return ok & (n - before == 1)
+
+
 @partial(jax.jit, static_argnums=(1,))
 def _exists_stats(batch: ColumnarBatch, key: int, live):
-    """(least, greatest, count) of a build batch's live, valid keys."""
+    """((least, greatest, count, run heads) of a build batch's live, valid
+    keys, the mask of its run heads)."""
     c = batch.columns[key]
     ok = c.validity & (batch.active_mask() if live is None else live)
     k = c.data.astype(jnp.int64)
+    heads = _run_heads(k, ok)
     return jnp.stack([jnp.min(jnp.where(ok, k, jnp.iinfo(jnp.int64).max)),
                       jnp.max(jnp.where(ok, k, jnp.iinfo(jnp.int64).min)),
-                      jnp.sum(ok.astype(jnp.int64))])
+                      jnp.sum(ok.astype(jnp.int64)),
+                      jnp.sum(heads.astype(jnp.int64))]), heads
 
 
-@partial(jax.jit, static_argnums=(2,), donate_argnums=(0,))
-def _exists_mark(present, batch: ColumnarBatch, key: int, live, first):
-    """``present`` with a build batch's live, valid keys marked."""
-    c = batch.columns[key]
-    ok = c.validity & (batch.active_mask() if live is None else live)
-    at = jnp.where(ok, c.data.astype(jnp.int64) - first, present.shape[0])
+@partial(jax.jit, static_argnums=(2, 5), donate_argnums=(0,))
+def _exists_mark(present, batch: ColumnarBatch, key: int, heads, first,
+                 cap: int):
+    """``present`` with a build batch's run heads marked: each head's u32
+    offset from ``first``, every other row's the map's size (dropped),
+    sorted, and the first ``cap`` scattered; ``cap`` holds the batch's
+    heads. No sorted or unique indices are promised: both cost more on the
+    v5e, and a key split by a batch boundary is a head in both."""
+    size = present.shape[0]
+    off = jnp.where(heads,
+                    (batch.columns[key].data.astype(jnp.int64) - first
+                     ).astype(jnp.uint32), jnp.uint32(size))
+    at = jax.lax.sort(off, is_stable=False)[:cap]  # no iota payload
     return present.at[at.astype(jnp.int32)].set(True, mode="drop")
 
 

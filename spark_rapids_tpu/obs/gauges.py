@@ -161,6 +161,14 @@ CATALOG: "List[Tuple[str, str, str]]" = [
      "Build sides of semi and anti joins probed fused through a presence "
      "map, a byte a possible key: one integer key spanning at most 2^28 "
      "(exec/join.py _fused_exists)"),
+    ("join_exists_mark_compact_total", "counter",
+     "Exists build batches marked by their run heads (first live row of "
+     "each run of equal adjacent keys), sorted and cut to the bucket of "
+     "the most heads a batch of their capacity holds, at most the capacity "
+     "(exec/join.py _exists_mark)"),
+    ("join_exists_mark_indices_total", "counter",
+     "Indices the exists build's marks scattered, a batch's cut each "
+     "(exec:join-build attr mark_cap: the most of one batch)"),
     ("join_semi_probe_rows_total", "counter",
      "Probe rows the fused semi and anti probes tested (the stage's row "
      "counts, read at a scrape, or in one sync once 4,096 batches "

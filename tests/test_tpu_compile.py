@@ -24,6 +24,7 @@ is here if and only if ``auto`` selects it on a TPU (exec/kernels.py).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -547,11 +548,13 @@ def test_fused_q12_stage_programs(one_chip):
 def test_fused_q4_stage_programs(one_chip):
     """The benchmark's Q4 (cell ``sf10_q4_semi1``) as its two fused stages
     run it: the `lineitem` build stage's masked program (its batches with
-    their masks, no compaction), the exists build's range reduction and
-    its mark of a presence map at the cell's own shapes (a 2^20 batch into
-    SF10's 2^26 bytes), and the `orders` stage's seed, whose semi probe is
-    one byte gather into the live mask before the dense first pass over
-    the dict-coded priority. None of the exists form's programs sorts."""
+    their masks, no compaction), the exists build's range reduction with
+    its run heads, and its mark of a presence map at the cell's own shapes
+    (a 2^20 batch into SF10's 2^26 bytes, the run heads cut to C = 2^18),
+    and the `orders` stage's seed, whose semi probe is one byte gather into
+    the live mask before the dense first pass over the dict-coded
+    priority. The mark sorts once, one u32 operand (its heads' offsets); no
+    other program of the exists form sorts."""
     import sys
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
@@ -592,9 +595,15 @@ def test_fused_q4_stage_programs(one_chip):
     live = jnp.zeros(1 << 20, jnp.bool_)
     stats = _lower(lambda b, m: J._exists_stats.__wrapped__(b, 0, m),
                    one_chip, big, live)
-    mark = _lower(lambda p, b, m, f: J._exists_mark.__wrapped__(
-        p, b, 0, m, f), one_chip, jnp.zeros(1 << 26, jnp.bool_), big, live,
-        jnp.int64(0))
-    for lowered in (masked, stats, mark):
+    for lowered in (masked, stats):
         assert _sort_operands(lowered.as_text()) == []
         assert lowered.compile() is not None
+    mark = _lower(lambda p, b, h, f: J._exists_mark.__wrapped__(
+        p, b, 0, h, f, 1 << 18), one_chip, jnp.zeros(1 << 26, jnp.bool_),
+        big, live, jnp.int64(0))
+    text = mark.as_text()
+    assert _sort_operands(text) == [1]
+    assert re.search(r'"stablehlo\.sort"\(%[^)]*\) <\{[^}]*\}> \(\{\s*'
+                     r'\^bb0\(%\w+: tensor<ui32>, %\w+: tensor<ui32>\)',
+                     text)
+    assert mark.compile() is not None

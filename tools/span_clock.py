@@ -30,9 +30,10 @@ The last line of standard output is one JSON object: ``clock``, ``tiles``,
 ``sync_sites``, ``host``, ``await_wakes`` (the front-end's submit counters
 and, on a program that has them, its counters of what ended each wait for a
 result, over the whole run), ``operators`` (the joins' build paths, the
-runs rung's probe forms and pairs, the semi probes' rows tested and kept,
-and the fused stages' fallbacks and learned output capacities by their
-counters, and the exec:* spans per request) and
+runs rung's probe forms and pairs, the exists builds' marked batches and
+the indices they scattered, the semi probes' rows tested and kept, and the
+fused stages' fallbacks and learned output capacities by their counters,
+and the exec:* spans per request) and
 the run's ordinary ``result``. Needs the chip, as benchmark/run.py
 does (``--rehearse-sf`` runs every step on the CPU and prints no device
 number). It wraps one private function of the harness to see the offset
@@ -342,6 +343,7 @@ def main() -> int:
         "counters": {k: v for k, v in snap.items()
                      if k.startswith(("join_build_path_", "join_run_pairs_",
                                       "join_run_probe_", "join_semi_probe_",
+                                      "join_exists_mark_",
                                       "fused_fallback_", "fused_plain_",
                                       "fused_step_programs_"))},
         "spans": operator_spans(seen["spans"], seen["requests"])}
